@@ -14,11 +14,15 @@ Interpretation of the numbers (recorded in the JSON):
     so its events/s is the headline claim (target ≥2× the XLA executor
     events/s of BENCH_sweep.json / BENCH_market.json at equal total
     events);
-  * on CPU-only hosts the kernel necessarily runs through the Pallas
-    *interpreter* (``interpret=True``) — those numbers measure dispatch
-    overhead + bitwise parity, NOT kernel speed, and are reported
-    separately under ``"interpret": true`` so they are never compared
-    against the compiled target.
+  * with ``set_interpret(True)`` (``benchmarks/run.py --interpret``, the
+    CPU-host mode) the kernel runs through the Pallas *interpreter* —
+    those numbers measure dispatch overhead + bitwise parity, NOT kernel
+    speed, and are reported under ``"interpret": true`` so they are never
+    compared against the compiled target.  Timing the compiled kernel
+    (the default) without a TPU raises.
+
+The compiled kernel runs the slab stream only (``rng="slab"``); the
+interpreted mode keeps the frozen split stream its CPU baselines use.
 
 Compile time is recorded separately from the steady-state numbers
 (``benchmarks/_timing.py``).
@@ -47,6 +51,7 @@ LAM, MU, K = 1 / 12, 1 / 24, 10.0
 _REPO_ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 _SCALE = 1.0
+_INTERPRET = False
 
 #: kernel-launch geometry recorded in the JSON (see EXPERIMENTS.md)
 TILE = 256
@@ -55,6 +60,12 @@ TILE = 256
 def set_scale(scale: float) -> None:
     global _SCALE
     _SCALE = scale
+
+
+def set_interpret(interpret: bool) -> None:
+    """Time the Pallas interpreter (True) or the compiled kernel (False)."""
+    global _INTERPRET
+    _INTERPRET = interpret
 
 
 def _bench_json_path() -> str:
@@ -98,12 +109,18 @@ def measure_engine_kernel(n_r: int = 16, n_seeds: int = 4,
                           rmax: int = 64) -> dict:
     if n_events is None:
         n_events = max(2_000, int(50_000 * _SCALE))
-    interpret = jax.default_backend() != "tpu"
+    interpret = _INTERPRET
+    if not interpret and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"engine_kernel bench: the compiled kernel needs a TPU, the "
+            f"backend is {jax.default_backend()!r}; set_interpret(True) "
+            f"(run.py --interpret) times the interpreter instead")
+    rng = "split" if interpret else "slab"
     job, spot = Exponential(LAM), Exponential(MU)
     rs = jnp.linspace(0.25, 4.0, n_r)
     key = jax.random.key(0)
     common = dict(k=K, n_events=n_events, key=key, n_seeds=n_seeds,
-                  rmax=rmax)
+                  rmax=rmax, rng=rng)
     grid_points = n_r * n_seeds
     total_events = grid_points * n_events
 
@@ -122,7 +139,7 @@ def measure_engine_kernel(n_r: int = 16, n_seeds: int = 4,
         "n_events_per_point": n_events,
         "total_events": total_events,
         "rmax": rmax,
-        "rng": "split",  # the frozen stream (see BENCH_event_rng.json)
+        "rng": rng,
         "tile": TILE,
         "event_block": min(1 << 16, n_events),
         "interpret": interpret,

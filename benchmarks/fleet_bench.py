@@ -1,21 +1,24 @@
-"""Fleet scaling: the ``shard="lanes"`` sweep across simulated devices.
+"""Fleet scaling: the ``shard="lanes"`` sweep across devices.
 
-Each device count runs in its own subprocess with
+On a TPU backend the sweep runs in this process over the real local
+devices (``lane_mesh(n)`` for n = 1, 2, 4, ... up to the device count): a
+process that holds the chip never starts a child that would need it.  On
+the CPU backend each device count runs in its own subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — the flag must be
-set before the JAX backend initializes, so the parent process (which holds
-the single real device) can never measure multi-device itself.  The child
-times one sharded single-queue sweep (``impl="xla"``, ``rng="slab"``, the
-recommended fast path) with :func:`repro.obs.timing.time_compiled`, so the
-curve carries the compile-vs-steady split per device count.
+set before the JAX backend initializes, so the parent (which holds the
+single host device) cannot simulate more devices itself.  Either way one
+sharded single-queue sweep (``impl="xla"``, ``rng="slab"``, the
+recommended fast path) is timed with
+:func:`repro.obs.timing.time_compiled`, so the curve carries the
+compile-vs-steady split per device count.
 
 Writes BENCH_fleet.json (BENCH_fleet_smoke.json under ``--smoke``) with a
 ``devices → {t_run_s, t_compile_s, events_per_s}`` scaling curve and the
 usual provenance stamp.  The headline (guarded by CI's suite manifest) is
 the 1-device sharded throughput: on a CPU host the simulated devices all
 share the same cores, so the *absolute* curve is flat-ish by construction
-— the bench's job is to keep the sharded dispatch itself from regressing
-and to report honest numbers for docs/scaling.md / EXPERIMENTS.md, not to
-demonstrate CPU speedups.
+— the bench's job there is to keep the sharded dispatch itself from
+regressing, not to demonstrate CPU speedups.
 """
 from __future__ import annotations
 
@@ -39,28 +42,36 @@ def _bench_json_path() -> str:
     return os.path.join(_REPO_ROOT, name)
 
 
-# child source: measure one sharded sweep at this process's device count.
+def measure_devices(n_dev: int, n_r: int, n_seeds: int,
+                    n_events: int) -> dict:
+    """Time one sharded sweep over this process's first ``n_dev`` devices."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import Exponential, ThreePhaseKernel, run_sweep
+    from repro.distributed.sharding import lane_mesh
+    from repro.obs.timing import time_compiled
+
+    kw = dict(k=10.0, n_events=n_events, key=jax.random.key(0),
+              n_seeds=n_seeds, rmax=32, rng="slab",
+              shard="lanes", mesh=lane_mesh(n_dev))
+    out, timing = time_compiled(lambda: run_sweep(
+        Exponential(1 / 12), Exponential(1 / 24), ThreePhaseKernel(),
+        {"r": jnp.linspace(0.25, 4.0, n_r)}, **kw))
+    timing["jobs_completed"] = int(
+        jnp.sum(jnp.asarray(out["jobs_completed"])))
+    return timing
+
+
+# child source (CPU backend): simulate n_dev host devices, then measure.
 # Parameters arrive via argv (n_devices, n_r, n_seeds, n_events); the
 # result leaves as one JSON line on stdout.
 _CHILD = """
 import json, os, sys
-n_dev, n_r, n_seeds, n_events = map(int, sys.argv[1:5])
+args = list(map(int, sys.argv[1:5]))
 os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=%d" % n_dev)
-import jax, jax.numpy as jnp
-from repro.core import Exponential, ThreePhaseKernel, run_sweep
-from repro.distributed.sharding import lane_mesh
-from repro.obs.timing import time_compiled
-
-assert len(jax.devices()) >= n_dev, (n_dev, jax.devices())
-kw = dict(k=10.0, n_events=n_events, key=jax.random.key(0),
-          n_seeds=n_seeds, rmax=32, rng="slab",
-          shard="lanes", mesh=lane_mesh(n_dev))
-out, timing = time_compiled(lambda: run_sweep(
-    Exponential(1 / 12), Exponential(1 / 24), ThreePhaseKernel(),
-    {"r": jnp.linspace(0.25, 4.0, n_r)}, **kw))
-timing["jobs_completed"] = int(jnp.sum(jnp.asarray(out["jobs_completed"])))
-print(json.dumps(timing))
+    "--xla_force_host_platform_device_count=%d" % args[0])
+from benchmarks.fleet_bench import measure_devices
+print(json.dumps(measure_devices(*args)))
 """
 
 
@@ -69,7 +80,7 @@ def _measure_child(n_devices: int, n_r: int, n_seeds: int,
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)  # the child sets its own, pre-backend
     env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(_REPO_ROOT, "src")]
+        [os.path.join(_REPO_ROOT, "src"), _REPO_ROOT]
         + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p])
     out = subprocess.run(
         [sys.executable, "-c", _CHILD, str(n_devices), str(n_r),
@@ -86,15 +97,23 @@ def measure_fleet_scaling(device_counts=None, n_r: int = 32,
                           n_seeds: int = 4,
                           n_events: int | None = None) -> dict:
     """Devices × lanes scaling curve for the sharded sweep dispatch."""
+    import jax
+
+    simulated = jax.default_backend() == "cpu"
     if device_counts is None:
-        device_counts = (1, 2) if _SCALE < 1.0 else (1, 2, 4, 8)
+        if simulated:
+            device_counts = (1, 2) if _SCALE < 1.0 else (1, 2, 4, 8)
+        else:
+            device_counts = tuple(2 ** i for i in range(
+                jax.device_count().bit_length()))
+    measure = _measure_child if simulated else measure_devices
     if n_events is None:
         n_events = max(2_000, int(50_000 * _SCALE))
     lanes = n_r * n_seeds
     total_events = lanes * n_events
     curve = {}
     for n_dev in device_counts:
-        timing = _measure_child(n_dev, n_r, n_seeds, n_events)
+        timing = measure(n_dev, n_r, n_seeds, n_events)
         curve[str(n_dev)] = {
             "t_run_s": timing["t_run_s"],
             "t_compile_s": timing["t_compile_s"],
@@ -115,7 +134,7 @@ def measure_fleet_scaling(device_counts=None, n_r: int = 32,
         "events_per_s_1dev": one["events_per_s"],
         "provenance": provenance(
             seed=0, impl="xla", rng="slab", shard="lanes",
-            simulated_devices="--xla_force_host_platform_device_count"),
+            simulated_devices=simulated),
     }
     with open(_bench_json_path(), "w") as f:
         json.dump(result, f, indent=2)
@@ -125,6 +144,8 @@ def measure_fleet_scaling(device_counts=None, n_r: int = 32,
 def bench_fleet_scaling():
     """Benchmark-harness entry: rows + headline (1-device sharded ev/s)."""
     res = measure_fleet_scaling()
+    kind = ("simulated" if res["provenance"]["simulated_devices"]
+            else res["provenance"]["device_kind"])
     rows = []
     for n_dev in res["device_counts"]:
         c = res["curve"][str(n_dev)]
@@ -133,7 +154,7 @@ def bench_fleet_scaling():
             "us_per_call": c["t_run_s"] * 1e6,
             "derived": (
                 f"{res['lanes']} lanes × {res['n_events_per_lane']} ev on "
-                f"{n_dev} simulated device(s): {c['events_per_s']:.0f} ev/s "
+                f"{n_dev} {kind} device(s): {c['events_per_s']:.0f} ev/s "
                 f"(compile {c['t_compile_s']:.2f}s, "
                 f"{c['lanes_per_device']} lanes/device)"),
         })

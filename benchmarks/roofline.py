@@ -1,9 +1,9 @@
 """Roofline derivation from dry-run artifacts (§Roofline of EXPERIMENTS.md).
 
-Hardware constants (TPU v5e target):
-  peak bf16 compute   197 TFLOP/s per chip
-  HBM bandwidth       819 GB/s per chip
-  ICI link bandwidth  ~50 GB/s per link
+Hardware peaks live in :data:`PEAKS`, one row per ``jax.Device.device_kind``
+with its source; :func:`peaks` raises for a kind that is not in the table.
+The LM dry-run compiles for the v5e production meshes, so its rows are
+derived against :data:`DRYRUN_TARGET`.
 
 Terms per (arch × shape × mesh), all in seconds per step:
   compute    = HLO_FLOPs_per_device / peak
@@ -28,12 +28,28 @@ import glob
 import json
 import os
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+#: Published per-chip peaks by ``device_kind``.  "TPU v5 lite" is TPU v5e
+#: (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16, 819 GB/s
+#: HBM, 1,600 Gbit/s of chip-to-chip interconnect over 4 links (50 GB/s
+#: each).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_s": 819e9,
+                    "link_bytes_s": 50e9},
+}
+
+#: The chip the LM dry-run's production meshes are compiled for.
+DRYRUN_TARGET = "TPU v5 lite"
 
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
                             "artifacts")
+
+
+def peaks(device_kind: str) -> dict:
+    """The :data:`PEAKS` row of ``device_kind``; an unknown kind raises."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add its row to PEAKS with a source")
+    return PEAKS[device_kind]
 
 
 def model_flops(art: dict) -> float:
@@ -51,16 +67,18 @@ def model_flops(art: dict) -> float:
 
 def derive(art: dict) -> dict:
     chips = art["chips"]
-    compute = art["flops_per_device"] / PEAK_FLOPS
-    memory = art["bytes_accessed_per_device"] / HBM_BW
-    collective = art["collectives"]["wire_bytes_per_device"] / LINK_BW
+    peak = peaks(DRYRUN_TARGET)
+    compute = art["flops_per_device"] / peak["flops"]
+    memory = art["bytes_accessed_per_device"] / peak["hbm_bytes_s"]
+    collective = (art["collectives"]["wire_bytes_per_device"]
+                  / peak["link_bytes_s"])
     terms = {"compute": compute, "memory": memory, "collective": collective}
     bottleneck = max(terms, key=terms.get)
     mf = model_flops(art)
     hlo_total = art["flops_per_device"] * chips
     useful = mf / hlo_total if hlo_total else 0.0
     bound = max(terms.values())
-    mfu_bound = mf / (chips * PEAK_FLOPS * bound) if bound else 0.0
+    mfu_bound = mf / (chips * peak["flops"] * bound) if bound else 0.0
     return {
         **{k: art[k] for k in ("arch", "shape", "mesh", "chips")},
         "compute_s": compute,
@@ -94,8 +112,9 @@ def bench_engine_roofline():
 
     The event loop's working set per (event × grid point) is the engine
     state + stats (~``16·rmax + 96`` bytes read+written); comparing achieved
-    event throughput against the streaming-bandwidth bound says how far the
-    batched engine sits from its memory roofline on this host.  (Run
+    event throughput against the device's HBM bound (:data:`PEAKS`, by the
+    bench's ``device_kind``) says how far the batched engine sits from its
+    memory roofline.  A bench measured on cpu gets no roofline.  (Run
     ``benchmarks/sweep_bench.py`` first — benchmarks/run.py orders them.)
     """
     root = os.path.join(os.path.dirname(__file__), "..")
@@ -106,10 +125,12 @@ def bench_engine_roofline():
         return [{"name": "engine_roofline/missing", "us_per_call": 0,
                  "derived": "BENCH_sweep.json not found; run sweep bench"}], 0.0
     r = json.load(open(path))
+    if r.get("backend") == "cpu":
+        return [{"name": "engine_roofline/cpu", "us_per_call": 0,
+                 "derived": f"{os.path.basename(path)} was measured on cpu; "
+                            f"no published peak, no roofline"}], 0.0
     state_bytes = 2 * (16 * r["rmax"] + 96)  # state+stats, read and written
-    # CPU hosts: assume ~20 GB/s sustained single-core-ish stream as the
-    # reference bound; TPU/GPU backends use HBM_BW.
-    bw = HBM_BW if r.get("backend") not in (None, "cpu") else 20e9
+    bw = peaks(r["provenance"]["device_kind"])["hbm_bytes_s"]
     bound_ev_s = bw / state_bytes
     frac = r["sweep_events_per_s"] / bound_ev_s
     rows = [{

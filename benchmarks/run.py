@@ -1,13 +1,17 @@
 """Benchmark harness: one function per paper table/figure, the sweep-engine
 throughput bench, and the roofline table from dry-run artifacts.
 
-    PYTHONPATH=src python benchmarks/run.py [--smoke] [--json PATH] [--only SUBSTR]
+    PYTHONPATH=src:. python benchmarks/run.py [--smoke] [--json PATH]
+        [--only SUBSTR] [--interpret]
 
 Prints ``name,us_per_call,derived`` CSV.  ``--smoke`` shrinks event counts
 (~20× fewer events) so the whole suite runs in a couple of minutes on CPU —
 statistical targets in the derived strings only hold at full scale, but the
 sweep-engine speedup numbers still land in BENCH_sweep.json.  ``--json``
 additionally dumps all rows (plus per-bench headline scalars) to PATH.
+The Pallas kernel bench times the compiled kernel and needs a TPU;
+``--interpret`` times its interpreter instead.  JAX's persistent compile
+cache is on (:func:`repro.obs.timing.enable_compile_cache`).
 """
 from __future__ import annotations
 
@@ -24,7 +28,13 @@ def main() -> None:
                     help="also dump rows to a BENCH_*.json file")
     ap.add_argument("--only", default=None, metavar="SUBSTR",
                     help="run only benches whose name contains SUBSTR")
+    ap.add_argument("--interpret", action="store_true",
+                    help="time the Pallas kernel through its interpreter "
+                         "(hosts without a TPU)")
     args = ap.parse_args()
+
+    from repro.obs.timing import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import deadline_bench
     from benchmarks import engine_kernel_bench
@@ -38,6 +48,7 @@ def main() -> None:
     from benchmarks import sweep_bench
     from benchmarks.roofline import bench_engine_roofline, bench_roofline
 
+    engine_kernel_bench.set_interpret(args.interpret)
     if args.smoke:
         pb.set_scale(0.05)
         sweep_bench.set_scale(0.1)

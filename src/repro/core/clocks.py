@@ -121,7 +121,9 @@ def sample_hazard_clocks(tags: tuple, k: jax.Array,
 
 def u01(bits: jax.Array) -> jax.Array:
     """uint32 bits → float32 uniforms on [0, 1) (24-bit resolution)."""
-    return (bits >> np.uint32(8)).astype(jnp.float32) * np.float32(2.0 ** -24)
+    # via int32: exact below 2**24, and Mosaic has no uint32 -> float cast
+    return ((bits >> np.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+            * np.float32(2.0 ** -24))
 
 
 def exp_from_u(u: jax.Array) -> jax.Array:
@@ -142,6 +144,21 @@ def synth_key(bits: jax.Array) -> jax.Array:
     engine's own per-event ladders and clock refreshes stay slab-driven.
     """
     return jnp.stack([bits[0], bits[1]])
+
+
+def argmin_first(x: jax.Array) -> jax.Array:
+    """``jnp.argmin`` of a 1-D array — the first index of its minimum —
+    built from min-reductions, which Mosaic lowers for every dtype (its
+    argmin takes float32 only).  Event bodies pick slots, pools and
+    regions with it, so one traced body serves every executor."""
+    iota = jax.lax.iota(jnp.int32, x.shape[0])
+    return jnp.min(jnp.where(x == jnp.min(x), iota, np.int32(x.shape[0])))
+
+
+def argmax_first(x: jax.Array) -> jax.Array:
+    """``jnp.argmax`` twin of :func:`argmin_first`."""
+    iota = jax.lax.iota(jnp.int32, x.shape[0])
+    return jnp.min(jnp.where(x == jnp.max(x), iota, np.int32(x.shape[0])))
 
 
 # ---------------------------------------------------------------------------
@@ -186,9 +203,13 @@ def thinning_pick(hazard, u):
         return int(min(np.sum(float(u) * cum[-1] >= cum[:-1]),
                        len(cum) - 1))
     h = jnp.asarray(hazard, jnp.float32)
-    cum = jnp.cumsum(h)
-    pick = jnp.sum((jnp.asarray(u, jnp.float32) * cum[-1] >= cum[:-1])
-                   .astype(jnp.int32))
+    # jnp.cumsum's left-to-right running sum, spelled out: Mosaic has no
+    # cumsum
+    cum = [h[0]]
+    for p in range(1, h.shape[0]):
+        cum.append(cum[-1] + h[p])
+    x = jnp.asarray(u, jnp.float32) * cum[-1]
+    pick = sum((x >= c).astype(jnp.int32) for c in cum[:-1])
     return jnp.minimum(pick, h.shape[0] - 1).astype(jnp.int32)
 
 
@@ -330,19 +351,18 @@ def window_slab(key: jax.Array, n_events: int,
     return ks[0], jax.random.bits(ks[1], (n_events, n_cols), jnp.uint32)
 
 
-def lane_window_slabs(key: jax.Array, plan: tuple[int, ...],
-                      n_cols: int) -> jax.Array:
-    """All of one lane's window slabs, stacked (n_windows, max_ev, n_cols).
+def lane_slab_keys(key: jax.Array, n_windows: int) -> jax.Array:
+    """One lane's per-window slab keys, (n_windows, 2) raw uint32 words.
 
-    Uses the exact per-window shapes of :func:`window_slab` (the ladder the
-    scan executor walks) and zero-pads each window up to the plan maximum,
-    so the rows a window actually consumes are bitwise the scan path's —
-    the Pallas/ref executors feed this stack in as a per-window input
-    block.
+    Walks the :func:`window_slab` ladder: window ``w``'s slab is
+    ``jax.random.bits(keys[w], (n_ev_w, n_cols), uint32)``, bitwise the
+    slab the scan executor draws for that window.  The batched-event
+    executors carry these keys instead of the slabs themselves and build
+    each event's row with :func:`slab_row`.
     """
-    max_ev = max(plan)
-    slabs = []
-    for n_ev in plan:
-        key, slab = window_slab(key, n_ev, n_cols)
-        slabs.append(jnp.pad(slab, ((0, max_ev - n_ev), (0, 0))))
-    return jnp.stack(slabs)
+    keys = []
+    for _ in range(n_windows):
+        ks = jax.random.split(key)
+        key = ks[0]
+        keys.append(ks[1])
+    return jnp.stack(keys)

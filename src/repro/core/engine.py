@@ -65,9 +65,9 @@ Executors (``impl=``)
 Every entry point dispatches between executors sharing the same traced
 event bodies: ``impl="xla"`` is the nested-vmap ``lax.scan`` program above;
 ``impl="pallas"`` hands the fleet to the batched-event kernel in
-:mod:`repro.kernels.sweep` — engine state laid out as (tile, rmax) VMEM
-blocks (market clocks as (tile, n_pools)) resident across a whole float32
-window of events, with the clock merge, slot reductions, and one-hot
+:mod:`repro.kernels.sweep` — engine state laid out lane-last as
+(rmax, tile) VMEM blocks (market clocks as (n_pools, tile)) resident
+across a whole float32 window of events, with the clock merge, slot reductions, and one-hot
 updates fused into one kernel body instead of N width-``rmax`` HLO selects
 re-read from HBM per event; ``impl="ref"`` is the kernel's pure-JAX scan
 reference on the identical lane layout.  Bit-for-bit contract
@@ -76,8 +76,9 @@ config and tile size; against the ``"xla"`` executor, integer event
 accounting is bitwise identical and float32 window sums match to ~1 ulp
 (the XLA executor keeps a broadcast-nested batch layout that is ~2.5×
 faster on CPU but whose transcendental codegen can round an ulp apart —
-see EXPERIMENTS.md).  ``interpret=None`` auto-falls back to the Pallas
-interpreter off-TPU, so tier-1 stays green everywhere.
+see EXPERIMENTS.md).  ``interpret=None`` compiles the kernel with Mosaic
+on a TPU and runs the Pallas interpreter elsewhere; the compiled kernel
+runs ``rng="slab"`` only (``rng="split"`` raises).
 
 Randomness (``rng=``)
 ---------------------
@@ -124,8 +125,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.arrivals import ArrivalProcess
-from repro.core.clocks import (SlabLayout, build_slab_layout, hazard_clock,
-                               lane_window_slabs, process_udim,
+from repro.core.clocks import (SlabLayout, argmin_first, build_slab_layout,
+                               hazard_clock, lane_slab_keys, process_udim,
                                sample_clock_vector, sample_hazard_clocks,
                                split_event_keys, synth_key, tagged_keys,
                                thinning_pick, window_slab)
@@ -319,7 +320,7 @@ def _engine_event(job: ArrivalProcess, spot: ArrivalProcess,
     else:
         panic_armed = None
     deadline = jnp.min(budgets_masked)
-    defect_slot = jnp.argmin(budgets_masked)
+    defect_slot = argmin_first(budgets_masked)
 
     dt = jnp.minimum(jnp.minimum(carry.next_job, carry.next_spot), deadline)
     is_spot = carry.next_spot <= jnp.minimum(carry.next_job, deadline)
@@ -347,10 +348,10 @@ def _engine_event(job: ArrivalProcess, spot: ArrivalProcess,
         admit_raw, budget = _admit_slab(kernel, params, carry.qlen, layout, x)
     admit = is_job & admit_raw & (carry.qlen < rmax)
     od_now = is_job & (~admit)  # rejected -> immediate on-demand, delay 0
-    join_slot = jnp.argmin(carry.occ.astype(jnp.int32))  # first free slot
+    join_slot = argmin_first(carry.occ.astype(jnp.int32))  # first free slot
 
     # ---- spot slot: serve the FIFO-oldest job ----
-    serve_slot = jnp.argmin(jnp.where(carry.occ, carry.order, _ORDER_MAX))
+    serve_slot = argmin_first(jnp.where(carry.occ, carry.order, _ORDER_MAX))
     has_job = carry.qlen > 0
     served = is_spot & has_job
     wait_served = jnp.sum(jnp.where(iota == serve_slot, ages, 0.0))
@@ -632,9 +633,9 @@ def _scan_window_slab(step, zeros, state, n_events: int, n_cols: int,
     """Slab-stream window: ONE counter-based bits call generates the whole
     window's ``(n_events, n_cols)`` uint32 slab, the event scan consumes it
     row by row as ``xs``, and the lane key advances once per window (not
-    per event).  :func:`repro.core.clocks.lane_window_slabs` walks the same
-    ladder with the same shapes, so the Pallas/ref executors consume
-    bitwise-identical slabs.
+    per event).  :func:`repro.core.clocks.lane_slab_keys` walks the same
+    key ladder, so the Pallas/ref executors consume bitwise-identical
+    slab rows.
 
     ``paired`` flags a tuple-wrapped state — ``(engine, EnvState)`` when
     the env axis is on, and/or an outermost ``(state, WorkState)`` when
@@ -800,6 +801,22 @@ def _check_rng(rng: str) -> None:
         raise ValueError(f"unknown rng {rng!r} (expected 'split'|'slab')")
 
 
+def _resolve_interpret(name: str, impl: str, rng: str,
+                       interpret: bool | None) -> bool:
+    """The Pallas executor's mode: ``interpret=None`` is compiled Mosaic
+    on a TPU backend and the Pallas interpreter on any other.  The
+    compiled kernel hashes the slab stream in-kernel; the split stream's
+    per-event ``jax.random.split`` has no Mosaic lowering, so that pairing
+    raises instead of running some other way."""
+    interpret = default_interpret() if interpret is None else interpret
+    if impl == "pallas" and not interpret and rng != "slab":
+        raise ValueError(
+            f"{name}: the compiled Pallas kernel (interpret=False) runs "
+            f"rng='slab' only, got rng={rng!r}; use rng='slab', "
+            f"impl='xla', or interpret=True")
+    return interpret
+
+
 def _check_telemetry(telemetry) -> None:
     if telemetry is not None and not isinstance(telemetry, Telemetry):
         raise TypeError(
@@ -936,14 +953,23 @@ def _run_sweep_jit(job, spot, kernel, rmax, n_events, chunk_events, burn_in,
     return jax.vmap(per_seeds, in_axes=(0, 0, None))(params, k_cost, keys)
 
 
-def _lane_slabs(state0, plan, layout: SlabLayout) -> jax.Array:
-    """All lanes' per-window slabs, (lanes, n_windows, max_ev, n_cols) —
-    generated OUTSIDE the kernel from each lane's initial key, so the
-    Pallas executor sees the slab as a plain per-window input block and
-    performs zero in-kernel RNG.  Values consumed per window are bitwise
-    the scan executor's (:func:`_scan_window_slab`)."""
-    return jax.vmap(
-        lambda k: lane_window_slabs(k, plan, layout.n_cols))(state0.key)
+def _lane_slabs(state0, plan, layout: SlabLayout, compiled: bool):
+    """The batched-event executors' slab stream: each lane's per-window
+    slab keys, (lanes, n_windows, 2) uint32, with the row width.  Walked
+    OUTSIDE the kernel from each lane's initial key; window ``w``'s rows
+    are bitwise the slab the scan executor draws
+    (:func:`_scan_window_slab`), hashed per event in-kernel and drawn per
+    window by the reference.  A ``compiled`` kernel runs no typed-key
+    PRNG, so a hook without a slab-aware ``*_u`` twin raises here."""
+    if compiled and "key" in (layout.admit_mode, layout.on_preempt_mode,
+                              layout.route_mode):
+        raise ValueError(
+            "the compiled Pallas kernel needs slab-aware kernel hooks "
+            "(admit_u/admit_market_u/on_preempt_u/route_u); this kernel "
+            "draws from a PRNG key — run it with impl='xla' or "
+            "interpret=True")
+    keys = jax.vmap(lambda k: lane_slab_keys(k, len(plan)))(state0.key)
+    return keys, layout.n_cols
 
 
 def _env_lane_blocks(ep: dict, lanes: int):
@@ -987,9 +1013,10 @@ def _run_sweep_pallas_jit(job, spot, kernel, rmax, n_events, chunk_events,
 
     if rng == "slab":
         layout = _engine_layout(job, spot, kernel)
-        xs = _lane_slabs(state0, plan, layout)
+        slab = _lane_slabs(state0, plan, layout,
+                           compiled=executor == "pallas" and not interpret)
     else:
-        layout, xs = None, None
+        layout, slab = None, None
     if ep is not None:
         # slabs above walk the bare engine key ladder; only now does the
         # lane state become the (engine, env-cursor) pair
@@ -1018,11 +1045,11 @@ def _run_sweep_pallas_jit(job, spot, kernel, rmax, n_events, chunk_events,
     epilogue = _rebase_for(ep, work)
     if executor == "ref":
         _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, xs=xs,
+            step, state0, params_b, zeros, plan, slab=slab,
             epilogue=epilogue)
     else:
         _, stats = batched_events(
-            step, state0, params_b, zeros, plan, xs=xs,
+            step, state0, params_b, zeros, plan, slab=slab,
             tile=tile, interpret=interpret, epilogue=epilogue)
     if burn_in:
         stats = jax.tree.map(lambda x: x[:, 1:], stats)
@@ -1091,7 +1118,8 @@ def _sweep_lanes(job, spot, kernel, rmax, n_events, chunk_events, burn_in,
     state0 = jax.vmap(
         lambda key: init_engine_state(key, job, spot, rmax, ep=ep))(keys_f)
     plan = _window_plan(n_events, chunk_events, burn_in)
-    xs = _lane_slabs(state0, plan, layout) if layout is not None else None
+    slab = None if layout is None else _lane_slabs(
+        state0, plan, layout, compiled=executor == "pallas" and not interpret)
     if ep is not None:
         params_b["ep"], es0 = _env_lane_blocks(ep, keys_f.shape[0])
         state0 = (state0, es0)
@@ -1116,10 +1144,10 @@ def _sweep_lanes(job, spot, kernel, rmax, n_events, chunk_events, burn_in,
     epilogue = _rebase_for(ep, work)
     if executor == "ref":
         _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, xs=xs, epilogue=epilogue)
+            step, state0, params_b, zeros, plan, slab=slab, epilogue=epilogue)
     else:
         _, stats = batched_events(
-            step, state0, params_b, zeros, plan, xs=xs, tile=tile,
+            step, state0, params_b, zeros, plan, slab=slab, tile=tile,
             interpret=interpret, epilogue=epilogue)
     if burn_in:
         stats = jax.tree.map(lambda x: x[:, 1:], stats)
@@ -1319,6 +1347,7 @@ def run_sim(
     _check_env(env)
     _check_work(work, kernel)
     _check_run_shape("run_sim", n_events, burn_in)
+    interp = _resolve_interpret("run_sim", impl, rng, interpret)
     ep = _env_params(env, 1)
     wk = None if work is None else work.params()
     chunk = n_events if chunk_events is None else min(chunk_events, n_events)
@@ -1326,7 +1355,7 @@ def run_sim(
         if impl in ("pallas", "ref"):
             stats = _run_sweep_pallas_jit(
                 job, spot, kernel, rmax, n_events, chunk, burn_in, tile,
-                default_interpret() if interpret is None else interpret,
+                interp,
                 jax.tree.map(lambda x: jnp.asarray(x)[None], params),
                 jnp.float32(k)[None], _raw_keys(key)[None], executor=impl,
                 rng=rng, tel=telemetry, ep=ep, work=work, wk=wk)
@@ -1378,12 +1407,14 @@ def run_sweep(
     ``impl`` selects the executor: ``"xla"`` is the nested-vmap
     ``lax.scan`` program; ``"pallas"`` runs the fleet through the batched
     -event kernel (:mod:`repro.kernels.sweep`) — engine state resident in
-    VMEM as (tile, rmax) blocks for a whole float32 window of events;
-    ``"ref"`` is the kernel's pure-JAX scan reference (the bit-for-bit
-    oracle; see the module docstring for the exact cross-executor
-    equality contract).  ``tile`` is lanes per kernel instance;
+    VMEM as lane-last (rmax, tile) blocks for a whole float32 window of
+    events; ``"ref"`` is the kernel's pure-JAX scan reference (the
+    bit-for-bit oracle; see the module docstring for the exact
+    cross-executor equality contract).  ``tile`` is lanes per kernel
+    instance (compiled: a multiple of 128, or every lane);
     ``interpret=None`` auto-selects compiled Mosaic on TPU and the Pallas
-    interpreter elsewhere (the CPU fallback).  ``rng="slab"`` selects the
+    interpreter elsewhere; ``interpret=False`` compiles or fails, and
+    runs ``rng="slab"`` only.  ``rng="slab"`` selects the
     fast slab PRNG stream (module docstring, "Randomness") — recommended
     for new sweeps; the default ``"split"`` is the frozen seed-compatible
     stream.
@@ -1407,6 +1438,7 @@ def run_sweep(
     _check_work(work, kernel)
     _check_shard("run_sweep", shard, mesh)
     _check_run_shape("run_sweep", n_events, burn_in)
+    interp = _resolve_interpret("run_sweep", impl, rng, interpret)
     ep = _env_params(env, 1)
     wk = None if work is None else work.params()
     params = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
@@ -1426,14 +1458,14 @@ def run_sweep(
                     f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
             stats = _run_sweep_sharded_jit(
                 job, spot, kernel, rmax, n_events, chunk, burn_in, tile,
-                default_interpret() if interpret is None else interpret,
+                interp,
                 lane_mesh() if mesh is None else mesh, params_flat, k_flat,
                 _raw_keys(keys), executor=impl, rng=rng, tel=telemetry,
                 ep=ep, work=work, wk=wk)
         elif impl in ("pallas", "ref"):
             stats = _run_sweep_pallas_jit(
                 job, spot, kernel, rmax, n_events, chunk, burn_in, tile,
-                default_interpret() if interpret is None else interpret,
+                interp,
                 params_flat, k_flat, _raw_keys(keys), executor=impl,
                 rng=rng, tel=telemetry, ep=ep, work=work, wk=wk)
         elif impl == "xla":
@@ -1700,14 +1732,14 @@ def _market_event(job: ArrivalProcess, market: SpotMarket, kernel, rmax: int,
     else:
         panic_armed = None
     deadline = jnp.min(budgets_masked)
-    defect_slot = jnp.argmin(budgets_masked)
+    defect_slot = argmin_first(budgets_masked)
 
     min_spot = jnp.min(carry.next_spot)
-    spot_pool = jnp.argmin(carry.next_spot).astype(jnp.int32)
+    spot_pool = argmin_first(carry.next_spot).astype(jnp.int32)
     if preempt_on:
         if layout is None:
             min_pre = jnp.min(carry.next_preempt)
-            pre_pool = jnp.argmin(carry.next_preempt).astype(jnp.int32)
+            pre_pool = argmin_first(carry.next_preempt).astype(jnp.int32)
         else:
             min_pre = carry.next_preempt[0]
             pre_pool = thinning_pick(eff_hazard,
@@ -1749,7 +1781,7 @@ def _market_event(job: ArrivalProcess, market: SpotMarket, kernel, rmax: int,
         # `rates` expression below, so the drain-off program keeps its
         # original op order (CSE merges the duplicate).
         alive_p = (mp["rate"] / mp["spot_scale"]) * avail_row > 0
-        cheapest = jnp.argmin(
+        cheapest = argmin_first(
             jnp.where(alive_p, eff_price, INF)).astype(jnp.int32)
         alive_slot = jnp.sum(
             jnp.where(carry.pool[:, None] == iota_p[None, :],
@@ -1776,11 +1808,11 @@ def _market_event(job: ArrivalProcess, market: SpotMarket, kernel, rmax: int,
             kernel, params, carry.qlen, pool_state, layout, x)
     admit = is_job & admit_raw & (carry.qlen < rmax)
     od_now = is_job & (~admit)
-    join_slot = jnp.argmin(carry.occ.astype(jnp.int32))
+    join_slot = argmin_first(carry.occ.astype(jnp.int32))
 
     # ---- pool spot slot: serve the FIFO-oldest job tagged to that pool ----
     eligible_s = carry.occ & (carry.pool == spot_pool)
-    serve_slot = jnp.argmin(jnp.where(eligible_s, carry.order, _ORDER_MAX))
+    serve_slot = argmin_first(jnp.where(eligible_s, carry.order, _ORDER_MAX))
     has_elig = jnp.any(eligible_s)
     served = is_spot & has_elig
     wait_served = jnp.sum(jnp.where(iota == serve_slot, ages, 0.0))
@@ -1815,7 +1847,7 @@ def _market_event(job: ArrivalProcess, market: SpotMarket, kernel, rmax: int,
     # ---- pool preemption: revoke the FIFO-oldest job on that pool ----
     if preempt_on:
         eligible_p = carry.occ & (carry.pool == pre_pool)
-        pre_slot = jnp.argmin(jnp.where(eligible_p, carry.order, _ORDER_MAX))
+        pre_slot = argmin_first(jnp.where(eligible_p, carry.order, _ORDER_MAX))
         pre_hit = is_pre & jnp.any(eligible_p)
         age_pre = jnp.sum(jnp.where(iota == pre_slot, ages, 0.0))
         # re-admission sees the queue WITHOUT the revoked job (the host
@@ -2202,9 +2234,10 @@ def _run_market_sweep_pallas_jit(job, market, kernel, rmax, preempt_on,
     plan = _window_plan(n_events, chunk_events, burn_in)
 
     if layout is not None:
-        xs = _lane_slabs(state0, plan, layout)
+        slab = _lane_slabs(state0, plan, layout,
+                           compiled=executor == "pallas" and not interpret)
     else:
-        xs = None
+        slab = None
     if ep is not None:
         params_b["ep"], es0 = _env_lane_blocks(ep, keys_f.shape[0])
         state0 = (state0, es0)
@@ -2232,11 +2265,11 @@ def _run_market_sweep_pallas_jit(job, market, kernel, rmax, preempt_on,
     epilogue = _rebase_for(ep, work)
     if executor == "ref":
         _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, xs=xs,
+            step, state0, params_b, zeros, plan, slab=slab,
             epilogue=epilogue)
     else:
         _, stats = batched_events(
-            step, state0, params_b, zeros, plan, xs=xs, tile=tile,
+            step, state0, params_b, zeros, plan, slab=slab, tile=tile,
             interpret=interpret, epilogue=epilogue)
     if burn_in:
         stats = jax.tree.map(lambda x: x[:, 1:], stats)
@@ -2282,7 +2315,8 @@ def _market_sweep_lanes(job, market, kernel, rmax, preempt_on, n_events,
             key, job, market, rmax, m, preempt_on,
             scalar_preempt=layout is not None, ep=ep))(keys_f, mp_f)
     plan = _window_plan(n_events, chunk_events, burn_in)
-    xs = _lane_slabs(state0, plan, layout) if layout is not None else None
+    slab = None if layout is None else _lane_slabs(
+        state0, plan, layout, compiled=executor == "pallas" and not interpret)
     if ep is not None:
         params_b["ep"], es0 = _env_lane_blocks(ep, keys_f.shape[0])
         state0 = (state0, es0)
@@ -2310,10 +2344,10 @@ def _market_sweep_lanes(job, market, kernel, rmax, preempt_on, n_events,
     epilogue = _rebase_for(ep, work)
     if executor == "ref":
         _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, xs=xs, epilogue=epilogue)
+            step, state0, params_b, zeros, plan, slab=slab, epilogue=epilogue)
     else:
         _, stats = batched_events(
-            step, state0, params_b, zeros, plan, xs=xs, tile=tile,
+            step, state0, params_b, zeros, plan, slab=slab, tile=tile,
             interpret=interpret, epilogue=epilogue)
     if burn_in:
         stats = jax.tree.map(lambda x: x[:, 1:], stats)
@@ -2489,6 +2523,7 @@ def run_market_sim(
     _check_env(env)
     _check_work(work, kernel)
     _check_run_shape("run_market_sim", n_events, burn_in)
+    interp = _resolve_interpret("run_market_sim", impl, rng, interpret)
     mp = market.params()
     ep = _env_params(env, market.n_pools)
     wk = None if work is None else work.params()
@@ -2498,7 +2533,7 @@ def run_market_sim(
             stats = _run_market_sweep_pallas_jit(
                 job, market, kernel, rmax, market.preemptible, n_events,
                 chunk, burn_in, tile,
-                default_interpret() if interpret is None else interpret,
+                interp,
                 jax.tree.map(lambda x: jnp.asarray(x)[None], params),
                 jax.tree.map(lambda x: jnp.asarray(x)[None], mp),
                 jnp.float32(k)[None], _raw_keys(key)[None], executor=impl,
@@ -2576,6 +2611,7 @@ def run_market_sweep(
     _check_work(work, kernel)
     _check_shard("run_market_sweep", shard, mesh)
     _check_run_shape("run_market_sweep", n_events, burn_in)
+    interp = _resolve_interpret("run_market_sweep", impl, rng, interpret)
     _check_loc_overrides("run_market_sweep", n, "pool", prices=prices,
                          hazards=hazards, notices=notices,
                          spot_scales=spot_scales)
@@ -2607,7 +2643,7 @@ def run_market_sweep(
             stats = _run_market_sweep_sharded_jit(
                 job, market, kernel, rmax, preempt_on, n_events, chunk,
                 burn_in, tile,
-                default_interpret() if interpret is None else interpret,
+                interp,
                 lane_mesh() if mesh is None else mesh, params_flat, mp_flat,
                 k_flat, _raw_keys(keys), executor=impl, rng=rng,
                 tel=telemetry, ep=ep, work=work, wk=wk)
@@ -2615,7 +2651,7 @@ def run_market_sweep(
             stats = _run_market_sweep_pallas_jit(
                 job, market, kernel, rmax, preempt_on, n_events, chunk,
                 burn_in, tile,
-                default_interpret() if interpret is None else interpret,
+                interp,
                 params_flat, mp_flat, k_flat, _raw_keys(keys), executor=impl,
                 rng=rng, tel=telemetry, ep=ep, work=work, wk=wk)
         elif impl == "xla":
@@ -2899,16 +2935,16 @@ def _region_event(topo: RegionTopology, kernel, preempt_on: bool,
     else:
         panic_armed = None
     deadline = jnp.min(budgets_masked)
-    defect_slot = jnp.argmin(budgets_masked)
+    defect_slot = argmin_first(budgets_masked)
 
     min_job = jnp.min(carry.next_job)
-    home = jnp.argmin(carry.next_job).astype(jnp.int32)
+    home = argmin_first(carry.next_job).astype(jnp.int32)
     min_spot = jnp.min(carry.next_spot)
-    spot_region = jnp.argmin(carry.next_spot).astype(jnp.int32)
+    spot_region = argmin_first(carry.next_spot).astype(jnp.int32)
     if preempt_on:
         if layout is None:
             min_pre = jnp.min(carry.next_preempt)
-            pre_region = jnp.argmin(carry.next_preempt).astype(jnp.int32)
+            pre_region = argmin_first(carry.next_preempt).astype(jnp.int32)
         else:
             min_pre = carry.next_preempt[0]
             pre_region = thinning_pick(
@@ -2974,12 +3010,12 @@ def _region_event(topo: RegionTopology, kernel, preempt_on: bool,
     admit = is_job & admit_raw & (qlen_t < rmax_t)
     od_now = is_job & (~admit)
     target_mask = slot_region == target
-    join_slot = jnp.argmin(jnp.where(target_mask,
+    join_slot = argmin_first(jnp.where(target_mask,
                                      carry.occ.astype(jnp.int32), 2))
 
     # ---- region spot slot: serve the FIFO-oldest job queued there --------
     eligible_s = carry.occ & (slot_region == spot_region)
-    serve_slot = jnp.argmin(jnp.where(eligible_s, carry.order, _ORDER_MAX))
+    serve_slot = argmin_first(jnp.where(eligible_s, carry.order, _ORDER_MAX))
     has_elig = jnp.any(eligible_s)
     served = is_spot & has_elig
     wait_served = jnp.sum(jnp.where(iota_s == serve_slot, ages, 0.0))
@@ -3014,7 +3050,7 @@ def _region_event(topo: RegionTopology, kernel, preempt_on: bool,
     # ---- region preemption: revoke the FIFO-oldest job in that region ----
     if preempt_on:
         eligible_p = carry.occ & (slot_region == pre_region)
-        pre_slot = jnp.argmin(jnp.where(eligible_p, carry.order, _ORDER_MAX))
+        pre_slot = argmin_first(jnp.where(eligible_p, carry.order, _ORDER_MAX))
         pre_hit = is_pre & jnp.any(eligible_p)
         age_pre = jnp.sum(jnp.where(iota_s == pre_slot, ages, 0.0))
         # re-admission sees the region's queue WITHOUT the revoked job (the
@@ -3410,9 +3446,10 @@ def _run_region_sweep_pallas_jit(topo, kernel, preempt_on, n_events,
     plan = _window_plan(n_events, chunk_events, burn_in)
 
     if layout is not None:
-        xs = _lane_slabs(state0, plan, layout)
+        slab = _lane_slabs(state0, plan, layout,
+                           compiled=executor == "pallas" and not interpret)
     else:
-        xs = None
+        slab = None
     if ep is not None:
         params_b["ep"], es0 = _env_lane_blocks(ep, keys_f.shape[0])
         state0 = (state0, es0)
@@ -3441,11 +3478,11 @@ def _run_region_sweep_pallas_jit(topo, kernel, preempt_on, n_events,
     epilogue = _rebase_for(ep, work)
     if executor == "ref":
         _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, xs=xs,
+            step, state0, params_b, zeros, plan, slab=slab,
             epilogue=epilogue)
     else:
         _, stats = batched_events(
-            step, state0, params_b, zeros, plan, xs=xs, tile=tile,
+            step, state0, params_b, zeros, plan, slab=slab, tile=tile,
             interpret=interpret, epilogue=epilogue)
     if burn_in:
         stats = jax.tree.map(lambda x: x[:, 1:], stats)
@@ -3490,7 +3527,8 @@ def _region_sweep_lanes(topo, kernel, preempt_on, n_events, chunk_events,
             key, topo, r, preempt_on,
             scalar_preempt=layout is not None, ep=ep))(keys_f, rp_f)
     plan = _window_plan(n_events, chunk_events, burn_in)
-    xs = _lane_slabs(state0, plan, layout) if layout is not None else None
+    slab = None if layout is None else _lane_slabs(
+        state0, plan, layout, compiled=executor == "pallas" and not interpret)
     if ep is not None:
         params_b["ep"], es0 = _env_lane_blocks(ep, keys_f.shape[0])
         state0 = (state0, es0)
@@ -3519,10 +3557,10 @@ def _region_sweep_lanes(topo, kernel, preempt_on, n_events, chunk_events,
     epilogue = _rebase_for(ep, work)
     if executor == "ref":
         _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, xs=xs, epilogue=epilogue)
+            step, state0, params_b, zeros, plan, slab=slab, epilogue=epilogue)
     else:
         _, stats = batched_events(
-            step, state0, params_b, zeros, plan, xs=xs, tile=tile,
+            step, state0, params_b, zeros, plan, slab=slab, tile=tile,
             interpret=interpret, epilogue=epilogue)
     if burn_in:
         stats = jax.tree.map(lambda x: x[:, 1:], stats)
@@ -3676,6 +3714,7 @@ def run_region_sim(
     _check_env(env)
     _check_work(work, kernel)
     _check_run_shape("run_region_sim", n_events, burn_in)
+    interp = _resolve_interpret("run_region_sim", impl, rng, interpret)
     rp = topology.params()
     ep = _env_params(env, topology.n_regions)
     wk = None if work is None else work.params()
@@ -3685,7 +3724,7 @@ def run_region_sim(
             stats = _run_region_sweep_pallas_jit(
                 topology, kernel, topology.preemptible, n_events, chunk,
                 burn_in, tile,
-                default_interpret() if interpret is None else interpret,
+                interp,
                 jax.tree.map(lambda x: jnp.asarray(x)[None], params),
                 jax.tree.map(lambda x: jnp.asarray(x)[None], rp),
                 jnp.float32(k)[None], _raw_keys(key)[None], executor=impl,
@@ -3772,6 +3811,7 @@ def run_region_sweep(
     _check_work(work, kernel)
     _check_shard("run_region_sweep", shard, mesh)
     _check_run_shape("run_region_sweep", n_events, burn_in)
+    interp = _resolve_interpret("run_region_sweep", impl, rng, interpret)
     _check_loc_overrides("run_region_sweep", n, "region", prices=prices,
                          hazards=hazards, notices=notices,
                          spot_scales=spot_scales, job_scales=job_scales)
@@ -3812,14 +3852,14 @@ def run_region_sweep(
                     f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
             stats = _run_region_sweep_sharded_jit(
                 topology, kernel, preempt_on, n_events, chunk, burn_in, tile,
-                default_interpret() if interpret is None else interpret,
+                interp,
                 lane_mesh() if mesh is None else mesh, params_flat, rp_flat,
                 k_flat, _raw_keys(keys), executor=impl, rng=rng,
                 tel=telemetry, ep=ep, work=work, wk=wk)
         elif impl in ("pallas", "ref"):
             stats = _run_region_sweep_pallas_jit(
                 topology, kernel, preempt_on, n_events, chunk, burn_in, tile,
-                default_interpret() if interpret is None else interpret,
+                interp,
                 params_flat, rp_flat, k_flat, _raw_keys(keys), executor=impl,
                 rng=rng, tel=telemetry, ep=ep, work=work, wk=wk)
         elif impl == "xla":

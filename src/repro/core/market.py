@@ -57,7 +57,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.arrivals import ArrivalProcess
-from repro.core.clocks import choice_cols, gumbel_from_u, kernel_slab_cols
+from repro.core.clocks import (argmax_first, argmin_first, choice_cols,
+                               gumbel_from_u, kernel_slab_cols)
 from repro.core.policies import three_phase_admit_prob
 
 _INF = np.float32(3e38)  # np scalar: inlines as a literal in kernel traces
@@ -241,16 +242,16 @@ def choose_pool(choice: str, pool_state: PoolState, params,
     """
     n = pool_state.price.shape[0]
     if choice == "cheapest":
-        return jnp.argmin(pool_state.price).astype(jnp.int32)
+        return argmin_first(pool_state.price).astype(jnp.int32)
     if choice == "fastest":
-        return jnp.argmax(pool_state.rate).astype(jnp.int32)
+        return argmax_first(pool_state.rate).astype(jnp.int32)
     if choice == "least_loaded":
-        return jnp.argmin(pool_state.qlen_pool).astype(jnp.int32)
+        return argmin_first(pool_state.qlen_pool).astype(jnp.int32)
     if choice == "uniform":
         return jax.random.randint(key, (), 0, n, jnp.int32)
     if choice == "weighted":
         g = jax.random.gumbel(key, (n,), jnp.float32)
-        return jnp.argmax(params["pool_logits"] + g).astype(jnp.int32)
+        return argmax_first(params["pool_logits"] + g).astype(jnp.int32)
     raise ValueError(f"unknown pool choice rule {choice!r}")
 
 
@@ -267,7 +268,7 @@ def choose_pool_u(choice: str, pool_state: PoolState, params,
         return jnp.minimum((u[0] * n).astype(jnp.int32), n - 1)
     if choice == "weighted":
         g = gumbel_from_u(u[:n])
-        return jnp.argmax(params["pool_logits"] + g).astype(jnp.int32)
+        return argmax_first(params["pool_logits"] + g).astype(jnp.int32)
     return choose_pool(choice, pool_state, params, key=None)
 
 
@@ -379,7 +380,7 @@ def _failover_alive(target, alive, price):
     """Re-target a dead loc to the cheapest alive one (identity when the
     chosen loc is alive; position 0 when nothing is — callers gate on
     ``jnp.any(alive)``)."""
-    cheapest_alive = jnp.argmin(jnp.where(alive, price, _INF)).astype(
+    cheapest_alive = argmin_first(jnp.where(alive, price, _INF)).astype(
         jnp.int32)
     return jnp.where(alive[target], jnp.asarray(target, jnp.int32),
                      cheapest_alive)

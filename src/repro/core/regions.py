@@ -51,7 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.arrivals import ArrivalProcess
-from repro.core.clocks import choice_cols, gumbel_from_u
+from repro.core.clocks import (argmax_first, argmin_first, choice_cols,
+                               gumbel_from_u)
 
 _INF = np.float32(3e38)  # np scalar: inlines as a literal in kernel traces
 
@@ -251,16 +252,16 @@ def choose_region(choice: str, view: RegionView, params,
     if choice == "home":
         return view.home
     if choice == "cheapest":
-        return jnp.argmin(view.price).astype(jnp.int32)
+        return argmin_first(view.price).astype(jnp.int32)
     if choice == "fastest":
-        return jnp.argmax(view.rate).astype(jnp.int32)
+        return argmax_first(view.rate).astype(jnp.int32)
     if choice == "least_loaded":
-        return jnp.argmin(view.qlen_region).astype(jnp.int32)
+        return argmin_first(view.qlen_region).astype(jnp.int32)
     if choice == "uniform":
         return jax.random.randint(key, (), 0, n, jnp.int32)
     if choice == "weighted":
         g = jax.random.gumbel(key, (n,), jnp.float32)
-        return jnp.argmax(params["region_logits"] + g).astype(jnp.int32)
+        return argmax_first(params["region_logits"] + g).astype(jnp.int32)
     raise ValueError(f"unknown routing rule {choice!r}")
 
 
@@ -274,7 +275,7 @@ def choose_region_u(choice: str, view: RegionView, params,
         return jnp.minimum((u[0] * n).astype(jnp.int32), n - 1)
     if choice == "weighted":
         g = gumbel_from_u(u[:n])
-        return jnp.argmax(params["region_logits"] + g).astype(jnp.int32)
+        return argmax_first(params["region_logits"] + g).astype(jnp.int32)
     return choose_region(choice, view, params, key=None)
 
 

@@ -32,20 +32,12 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 from jax.tree_util import DictKey, GetAttrKey, SequenceKey
 
-# jax.shard_map graduated from jax.experimental.shard_map (and renamed its
-# replication-check kwarg check_rep -> check_vma) in jax 0.6; support both.
-# Same shim as repro.layers.moe — duplicated here so the event engine's
-# sharded sweeps never import the LM layer stack.
-if hasattr(jax, "shard_map"):
-    def shard_map_1d(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
 
-    def shard_map_1d(f, *, mesh, in_specs, out_specs):
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
+def shard_map_1d(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the replication check (the lane bodies
+    are per-lane independent; nothing is replicated across shards)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 #: Mesh axis name for the engine's flattened sweep lane axis.

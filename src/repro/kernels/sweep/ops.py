@@ -22,11 +22,11 @@ def default_interpret() -> bool:
 
 
 def batched_events(step, state, params, stats_zero, events_per_window, *,
-                   xs=None, tile: int = 256, interpret: bool | None = None,
+                   slab=None, tile: int = 256, interpret: bool | None = None,
                    epilogue=None):
     """Run stacked event windows on-chip; see ``batched_event_windows``."""
     if interpret is None:
         interpret = default_interpret()
     return batched_event_windows(step, state, params, stats_zero,
-                                 events_per_window, xs=xs, tile=tile,
+                                 events_per_window, slab=slab, tile=tile,
                                  interpret=interpret, epilogue=epilogue)

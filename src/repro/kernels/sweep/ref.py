@@ -14,12 +14,13 @@ import jax.numpy as jnp
 
 
 def batched_event_windows_ref(step, state, params, stats_zero,
-                              events_per_window, *, xs=None, epilogue=None):
+                              events_per_window, *, slab=None, epilogue=None):
     """Reference: ``(final_state, stats)`` with stats leaves (B, W, ...).
 
-    ``xs`` (optional) matches the kernel's contract: a pytree of
-    ``(B, n_windows, max_ev, ...)`` per-event window inputs; the event body
-    then takes a fourth argument — this event's row.
+    ``slab`` (optional) matches the kernel's contract: ``(keys, n_cols)``
+    with ``(B, n_windows, 2)`` raw uint32 keys; each window's slab is drawn
+    whole with ``jax.random.bits`` and the event body takes a fourth
+    argument — this event's row.
     """
     b = jax.tree.leaves(state)[0].shape[0]
     vstep = jax.vmap(step)
@@ -32,9 +33,7 @@ def batched_event_windows_ref(step, state, params, stats_zero,
             st, acc = carry
             if xw is None:
                 return vstep(st, acc, params)
-            x = jax.tree.map(
-                lambda leaf: jax.lax.dynamic_index_in_dim(
-                    leaf, i, axis=1, keepdims=False), xw)
+            x = jax.lax.dynamic_index_in_dim(xw, i, axis=1, keepdims=False)
             return vstep(st, acc, params, x)
 
         state, acc = jax.lax.fori_loop(0, n_ev, event, (state, zeros))
@@ -44,8 +43,11 @@ def batched_event_windows_ref(step, state, params, stats_zero,
 
     windows = []
     for w, n_ev in enumerate(events_per_window):
-        xw = None if xs is None else jax.tree.map(lambda leaf: leaf[:, w],
-                                                  xs)
+        xw = None
+        if slab is not None:
+            keys, n_cols = slab
+            xw = jax.vmap(lambda k, n=n_ev, c=n_cols: jax.random.bits(
+                k, (n, c), jnp.uint32))(keys[:, w])
         state, acc = window(state, n_ev, xw)
         windows.append(acc)
     stats = jax.tree.map(lambda *leaves: jnp.stack(leaves, axis=1), *windows)

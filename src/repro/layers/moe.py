@@ -31,19 +31,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-# jax.shard_map graduated from jax.experimental.shard_map (and renamed its
-# replication-check kwarg check_rep -> check_vma) in jax 0.6; support both.
-if hasattr(jax, "shard_map"):
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-
 
 def moe_init(key, d_model: int, moe_d_ff: int, num_experts: int,
              num_padded: int, dtype=jnp.bfloat16) -> dict:
@@ -195,10 +182,11 @@ def moe_apply_sharded(params, x, cfg, mesh, batch_axes: tuple,
         "w_down": P(model_axis, None, None),
     }
     x2d = x.reshape(B * S, D)
-    y2d, aux = _shard_map(
+    y2d, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(param_specs, P(batch_axes, None)),
         out_specs=(P(batch_axes, None), P()),
+        check_vma=False,
     )(params, x2d)
     return y2d.reshape(B, S, D), aux

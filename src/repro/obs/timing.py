@@ -7,7 +7,8 @@ Three small tools every measurement surface in the repo shares:
   it).  The first call pays trace + XLA compile + one run; steady state
   is the mean of further calls blocked to completion.
 * :func:`provenance` — the audit stamp every ``BENCH_*.json`` carries:
-  git commit, jax version, backend/platform, python.  A BENCH number
+  git commit, jax version, backend, device kind and count, platform,
+  python.  A BENCH number
   without its commit and backend is unfalsifiable; with them the BENCH
   trajectory across PRs is a real measurement series.
 * :func:`annotate` — named ``jax.profiler`` trace scopes on the engine
@@ -16,10 +17,16 @@ Three small tools every measurement surface in the repo shares:
   device time to the loop that spent it.  Compiles to nothing when no
   profiler is attached; falls back to a null context where the profiler
   API is unavailable (minimal CPU wheels).
+
+:func:`enable_compile_cache` turns on JAX's persistent compilation cache
+for a script (``chip_smoke.py``, ``benchmarks/run.py``); importing the
+library never does.
 """
 from __future__ import annotations
 
 import contextlib
+import os
+import pathlib
 import platform as _platform
 import subprocess
 import sys
@@ -67,11 +74,33 @@ def provenance(**extra) -> dict:
         "git_commit": _git_commit(),
         "jax_version": jax.__version__,
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": jax.device_count(),
         "platform": _platform.platform(),
         "python": sys.version.split()[0],
     }
     stamp.update(extra)
     return stamp
+
+
+#: Where the persistent compilation cache lives when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: one fixed, gitignored directory
+#: of the checkout (the path is part of the cache key, so it never moves).
+COMPILE_CACHE_DIR = (pathlib.Path(__file__).resolve().parents[3]
+                     / ".jax_compile_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    A set ``JAX_COMPILATION_CACHE_DIR`` is JAX's own setting and is left
+    alone; otherwise the cache goes to :data:`COMPILE_CACHE_DIR`.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def annotate(name: str):
