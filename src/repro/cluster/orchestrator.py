@@ -61,7 +61,7 @@ from repro.core.policies import (
     three_phase_admit_prob,
 )
 from repro.core.regions import RegionTopology, host_route
-from repro.obs.timing import annotate
+from repro.obs.timing import EntrySpan
 from repro.obs.trace import TraceRecorder
 
 
@@ -486,10 +486,11 @@ class SpotCluster:
 
         from repro.core.engine import run_market_sweep
 
-        if key is None:
-            key = jax.random.key(int(self.rng.integers(2**31)))
-        kern = NoticeAwareKernel(checkpoint_time=self.checkpoint_hours)
-        with annotate("repro.cluster.what_if_sweep[market]"):
+        with EntrySpan("repro.cluster.what_if_sweep[market]"):
+            if key is None:
+                key = jax.random.key(int(self.rng.integers(2**31)))
+            kern = NoticeAwareKernel(checkpoint_time=self.checkpoint_hours)
+            # the engine joins this call's span and phases
             return run_market_sweep(
                 self.jobs, self.market, kern,
                 {"r": jnp.asarray(rs, jnp.float32)},
@@ -769,12 +770,13 @@ class MultiRegionCluster:
         from repro.core.engine import run_region_sweep
         from repro.core.regions import RoutingKernel
 
-        if key is None:
-            key = jax.random.key(int(self.rng.integers(2**31)))
-        kern = RoutingKernel(
-            NoticeAwareKernel(checkpoint_time=self.checkpoint_hours),
-            choice=self.route if choice is None else choice)
-        with annotate("repro.cluster.what_if_sweep[region]"):
+        with EntrySpan("repro.cluster.what_if_sweep[region]"):
+            if key is None:
+                key = jax.random.key(int(self.rng.integers(2**31)))
+            kern = RoutingKernel(
+                NoticeAwareKernel(checkpoint_time=self.checkpoint_hours),
+                choice=self.route if choice is None else choice)
+            # the engine joins this call's span and phases
             return run_region_sweep(
                 self.topology, kern, {"r": jnp.asarray(rs, jnp.float32)},
                 k=self.k if k is None else k, n_events=n_events, key=key,

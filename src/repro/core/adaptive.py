@@ -44,7 +44,7 @@ from repro.core.engine import (_check_env, _env_params, _rebase_order,
 from repro.core.env import init_env_state
 from repro.core.market import NoticeAwareKernel, SpotMarket, as_market
 from repro.core.policies import ThreePhaseKernel
-from repro.obs.timing import annotate
+from repro.obs.timing import EntrySpan
 
 _THREE_PHASE = ThreePhaseKernel()
 
@@ -261,11 +261,12 @@ def adaptive_admission_control(
     (cumulative, matching the paper's C(r(n)) and d(r(n)) plots), plus the
     final knob ``r_star`` and Theorem-1 cross-check fields.
     """
-    market = as_market(spot)
-    kernel = _default_kernel(market) if kernel is None else kernel
-    _check_env(env)
-    ep = _env_params(env, market.n_pools)
-    with annotate("repro.adaptive_admission_control"):
+    with EntrySpan("repro.adaptive_admission_control") as call:
+        market = as_market(spot)
+        kernel = _default_kernel(market) if kernel is None else kernel
+        _check_env(env)
+        ep = _env_params(env, market.n_pools)
+        call.phase("dispatch")
         r_final, tr = _adaptive_jit(
             job, market, kernel, rmax_slots, window_events, n_windows,
             jnp.float32(k), jnp.float32(delta), jnp.float32(eta),
@@ -274,7 +275,8 @@ def adaptive_admission_control(
             max_step=None if max_step is None else float(max_step),
             shock_reset=bool(shock_reset),
         )
-    return _assemble(tr, r_final)
+        r_final, tr = call.to_host((r_final, tr))
+        return _assemble(tr, r_final)
 
 
 def adaptive_admission_control_batched(
@@ -314,25 +316,27 @@ def adaptive_admission_control_batched(
     leading batch axis on every array (and on the ``final_*``/``r_star``
     scalars).
     """
-    market = as_market(spot)
-    kernel = _default_kernel(market) if kernel is None else kernel
-    _check_env(env)
-    ep = _env_params(env, market.n_pools)
-    args = [jnp.asarray(x, jnp.float32)
-            for x in (k, delta, eta, eta_decay, r0, r_max)]
-    batch = jnp.broadcast_shapes(*(a.shape for a in args), (1,))
-    n = int(np.prod(batch))
-    args = [jnp.broadcast_to(a, batch).reshape(-1) for a in args]
-    keys = (jax.random.split(key, n) if independent_keys
-            else jnp.repeat(key[None], n, axis=0))
-    with annotate("repro.adaptive_admission_control_batched"):
+    with EntrySpan("repro.adaptive_admission_control_batched") as call:
+        market = as_market(spot)
+        kernel = _default_kernel(market) if kernel is None else kernel
+        _check_env(env)
+        ep = _env_params(env, market.n_pools)
+        args = [jnp.asarray(x, jnp.float32)
+                for x in (k, delta, eta, eta_decay, r0, r_max)]
+        batch = jnp.broadcast_shapes(*(a.shape for a in args), (1,))
+        n = int(np.prod(batch))
+        args = [jnp.broadcast_to(a, batch).reshape(-1) for a in args]
+        keys = (jax.random.split(key, n) if independent_keys
+                else jnp.repeat(key[None], n, axis=0))
+        call.phase("dispatch")
         r_final, tr = _adaptive_batched_jit(
             job, market, kernel, rmax_slots, window_events, n_windows,
             *args, keys, ep=ep,
             max_step=None if max_step is None else float(max_step),
             shock_reset=bool(shock_reset),
         )
-    # restore multi-dimensional batch shapes (e.g. a delta × r0 meshgrid)
-    r_final = r_final.reshape(batch)
-    tr = jax.tree.map(lambda x: x.reshape(batch + x.shape[1:]), tr)
-    return _assemble(tr, r_final)
+        r_final, tr = call.to_host((r_final, tr))
+        # restore multi-dimensional batch shapes (e.g. a delta × r0 meshgrid)
+        r_final = r_final.reshape(batch)
+        tr = jax.tree.map(lambda x: x.reshape(batch + x.shape[1:]), tr)
+        return _assemble(tr, r_final)

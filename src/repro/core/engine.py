@@ -146,7 +146,7 @@ from repro.kernels.sweep import (batched_events, batched_event_windows_ref,
                                  default_interpret)
 from repro.obs.stats import (Telemetry, summarize_telemetry,
                              telemetry_update, telemetry_zeros)
-from repro.obs.timing import annotate
+from repro.obs.timing import EntrySpan
 
 # numpy (not jnp) scalars: they inline as jaxpr literals, so the event
 # bodies stay capture-free inside the Pallas kernel trace (device-array
@@ -968,7 +968,8 @@ def _lane_slabs(state0, plan, layout: SlabLayout, compiled: bool):
             "(admit_u/admit_market_u/on_preempt_u/route_u); this kernel "
             "draws from a PRNG key — run it with impl='xla' or "
             "interpret=True")
-    keys = jax.vmap(lambda k: lane_slab_keys(k, len(plan)))(state0.key)
+    with jax.named_scope("repro.glue.lane_slabs"):
+        keys = jax.vmap(lambda k: lane_slab_keys(k, len(plan)))(state0.key)
     return keys, layout.n_cols
 
 
@@ -1341,17 +1342,19 @@ def run_sim(
     deadlines — and adds the survival ledger to the returned dict
     (module docstring of :mod:`repro.core.work`).
     """
-    params = {} if params is None else params
-    _check_rng(rng)
-    _check_telemetry(telemetry)
-    _check_env(env)
-    _check_work(work, kernel)
-    _check_run_shape("run_sim", n_events, burn_in)
-    interp = _resolve_interpret("run_sim", impl, rng, interpret)
-    ep = _env_params(env, 1)
-    wk = None if work is None else work.params()
-    chunk = n_events if chunk_events is None else min(chunk_events, n_events)
-    with annotate(f"repro.run_sim[{impl}]"):
+    with EntrySpan(f"repro.run_sim[{impl}]") as call:
+        params = {} if params is None else params
+        _check_rng(rng)
+        _check_telemetry(telemetry)
+        _check_env(env)
+        _check_work(work, kernel)
+        _check_run_shape("run_sim", n_events, burn_in)
+        interp = _resolve_interpret("run_sim", impl, rng, interpret)
+        ep = _env_params(env, 1)
+        wk = None if work is None else work.params()
+        chunk = (n_events if chunk_events is None
+                 else min(chunk_events, n_events))
+        call.phase("dispatch")
         if impl in ("pallas", "ref"):
             stats = _run_sweep_pallas_jit(
                 job, spot, kernel, rmax, n_events, chunk, burn_in, tile,
@@ -1368,9 +1371,10 @@ def run_sim(
         else:
             raise ValueError(
                 f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
-    return {name: _scalar_or_array(v)
-            for name, v in summarize(stats, telemetry, env=env,
-                                     work=work).items()}
+        stats = call.to_host(stats)
+        return {name: _scalar_or_array(v)
+                for name, v in summarize(stats, telemetry, env=env,
+                                         work=work).items()}
 
 
 def run_sweep(
@@ -1431,27 +1435,29 @@ def run_sweep(
     Returns :func:`summarize`'s dict with every value shaped
     ``grid_shape + (n_seeds,)``.
     """
-    params = {} if params is None else params
-    _check_rng(rng)
-    _check_telemetry(telemetry)
-    _check_env(env)
-    _check_work(work, kernel)
-    _check_shard("run_sweep", shard, mesh)
-    _check_run_shape("run_sweep", n_events, burn_in)
-    interp = _resolve_interpret("run_sweep", impl, rng, interpret)
-    ep = _env_params(env, 1)
-    wk = None if work is None else work.params()
-    params = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
-    k = jnp.asarray(k, jnp.float32)
-    grid_shape = jnp.broadcast_shapes(
-        k.shape, *(x.shape for x in jax.tree.leaves(params))
-    )
-    flat = lambda x: jnp.broadcast_to(x, grid_shape).reshape(-1)
-    params_flat = jax.tree.map(flat, params)
-    k_flat = flat(k)
-    keys = jax.random.split(key, n_seeds)
-    chunk = n_events if chunk_events is None else min(chunk_events, n_events)
-    with annotate(f"repro.run_sweep[{impl}]"):
+    with EntrySpan(f"repro.run_sweep[{impl}]") as call:
+        params = {} if params is None else params
+        _check_rng(rng)
+        _check_telemetry(telemetry)
+        _check_env(env)
+        _check_work(work, kernel)
+        _check_shard("run_sweep", shard, mesh)
+        _check_run_shape("run_sweep", n_events, burn_in)
+        interp = _resolve_interpret("run_sweep", impl, rng, interpret)
+        ep = _env_params(env, 1)
+        wk = None if work is None else work.params()
+        params = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
+        k = jnp.asarray(k, jnp.float32)
+        grid_shape = jnp.broadcast_shapes(
+            k.shape, *(x.shape for x in jax.tree.leaves(params))
+        )
+        flat = lambda x: jnp.broadcast_to(x, grid_shape).reshape(-1)
+        params_flat = jax.tree.map(flat, params)
+        k_flat = flat(k)
+        keys = jax.random.split(key, n_seeds)
+        chunk = (n_events if chunk_events is None
+                 else min(chunk_events, n_events))
+        call.phase("dispatch")
         if shard == "lanes":
             if impl not in ("xla", "pallas", "ref"):
                 raise ValueError(
@@ -1475,9 +1481,10 @@ def run_sweep(
         else:
             raise ValueError(
                 f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
-    # values shaped (grid_points, n_seeds)
-    out = summarize(stats, telemetry, env=env, work=work)
-    return _reshape_sweep(out, grid_shape, n_seeds)
+        stats = call.to_host(stats)
+        # values shaped (grid_points, n_seeds)
+        out = summarize(stats, telemetry, env=env, work=work)
+        return _reshape_sweep(out, grid_shape, n_seeds)
 
 
 # ===========================================================================
@@ -2516,19 +2523,21 @@ def run_market_sim(
     structure — checkpoint-priced recovery, restart overhead, deadlines —
     and the survival ledger (module docstring of :mod:`repro.core.work`).
     """
-    market = as_market(market)
-    params = {} if params is None else params
-    _check_rng(rng)
-    _check_telemetry(telemetry)
-    _check_env(env)
-    _check_work(work, kernel)
-    _check_run_shape("run_market_sim", n_events, burn_in)
-    interp = _resolve_interpret("run_market_sim", impl, rng, interpret)
-    mp = market.params()
-    ep = _env_params(env, market.n_pools)
-    wk = None if work is None else work.params()
-    chunk = n_events if chunk_events is None else min(chunk_events, n_events)
-    with annotate(f"repro.run_market_sim[{impl}]"):
+    with EntrySpan(f"repro.run_market_sim[{impl}]") as call:
+        market = as_market(market)
+        params = {} if params is None else params
+        _check_rng(rng)
+        _check_telemetry(telemetry)
+        _check_env(env)
+        _check_work(work, kernel)
+        _check_run_shape("run_market_sim", n_events, burn_in)
+        interp = _resolve_interpret("run_market_sim", impl, rng, interpret)
+        mp = market.params()
+        ep = _env_params(env, market.n_pools)
+        wk = None if work is None else work.params()
+        chunk = (n_events if chunk_events is None
+                 else min(chunk_events, n_events))
+        call.phase("dispatch")
         if impl in ("pallas", "ref"):
             stats = _run_market_sweep_pallas_jit(
                 job, market, kernel, rmax, market.preemptible, n_events,
@@ -2549,9 +2558,10 @@ def run_market_sim(
         else:
             raise ValueError(
                 f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
-    return {name: _scalar_or_array(v)
-            for name, v in summarize_market(stats, telemetry, env=env,
-                                            work=work).items()}
+        stats = call.to_host(stats)
+        return {name: _scalar_or_array(v)
+                for name, v in summarize_market(stats, telemetry, env=env,
+                                                work=work).items()}
 
 
 def run_market_sweep(
@@ -2602,40 +2612,42 @@ def run_market_sweep(
     ``grid_shape + (n_seeds,)`` and per-pool statistics
     ``grid_shape + (n_seeds, P)``.
     """
-    market = as_market(market)
-    n = market.n_pools
-    params = {} if params is None else params
-    _check_rng(rng)
-    _check_telemetry(telemetry)
-    _check_env(env)
-    _check_work(work, kernel)
-    _check_shard("run_market_sweep", shard, mesh)
-    _check_run_shape("run_market_sweep", n_events, burn_in)
-    interp = _resolve_interpret("run_market_sweep", impl, rng, interpret)
-    _check_loc_overrides("run_market_sweep", n, "pool", prices=prices,
-                         hazards=hazards, notices=notices,
-                         spot_scales=spot_scales)
-    ep = _env_params(env, n)
-    wk = None if work is None else work.params()
-    params = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
-    k = jnp.asarray(k, jnp.float32)
-    overrides = {"price": prices, "hazard": hazards, "notice": notices,
-                 "spot_scale": spot_scales}
-    override_shapes = [jnp.asarray(v).shape[:-1]
-                       for v in overrides.values()
-                       if v is not None and jnp.asarray(v).ndim > 1]
-    grid_shape = jnp.broadcast_shapes(
-        k.shape, *(x.shape for x in jax.tree.leaves(params)),
-        *override_shapes,
-    )
-    flat = lambda x: jnp.broadcast_to(x, grid_shape).reshape(-1)
-    params_flat = jax.tree.map(flat, params)
-    k_flat = flat(k)
-    mp_flat = _broadcast_market_params(market, overrides, grid_shape)
-    preempt_on = market.preemptible or hazards is not None
-    keys = jax.random.split(key, n_seeds)
-    chunk = n_events if chunk_events is None else min(chunk_events, n_events)
-    with annotate(f"repro.run_market_sweep[{impl}]"):
+    with EntrySpan(f"repro.run_market_sweep[{impl}]") as call:
+        market = as_market(market)
+        n = market.n_pools
+        params = {} if params is None else params
+        _check_rng(rng)
+        _check_telemetry(telemetry)
+        _check_env(env)
+        _check_work(work, kernel)
+        _check_shard("run_market_sweep", shard, mesh)
+        _check_run_shape("run_market_sweep", n_events, burn_in)
+        interp = _resolve_interpret("run_market_sweep", impl, rng, interpret)
+        _check_loc_overrides("run_market_sweep", n, "pool", prices=prices,
+                             hazards=hazards, notices=notices,
+                             spot_scales=spot_scales)
+        ep = _env_params(env, n)
+        wk = None if work is None else work.params()
+        params = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
+        k = jnp.asarray(k, jnp.float32)
+        overrides = {"price": prices, "hazard": hazards, "notice": notices,
+                     "spot_scale": spot_scales}
+        override_shapes = [jnp.asarray(v).shape[:-1]
+                           for v in overrides.values()
+                           if v is not None and jnp.asarray(v).ndim > 1]
+        grid_shape = jnp.broadcast_shapes(
+            k.shape, *(x.shape for x in jax.tree.leaves(params)),
+            *override_shapes,
+        )
+        flat = lambda x: jnp.broadcast_to(x, grid_shape).reshape(-1)
+        params_flat = jax.tree.map(flat, params)
+        k_flat = flat(k)
+        mp_flat = _broadcast_market_params(market, overrides, grid_shape)
+        preempt_on = market.preemptible or hazards is not None
+        keys = jax.random.split(key, n_seeds)
+        chunk = (n_events if chunk_events is None
+                 else min(chunk_events, n_events))
+        call.phase("dispatch")
         if shard == "lanes":
             if impl not in ("xla", "pallas", "ref"):
                 raise ValueError(
@@ -2663,8 +2675,9 @@ def run_market_sweep(
         else:
             raise ValueError(
                 f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
-    out = summarize_market(stats, telemetry, env=env, work=work)
-    return _reshape_sweep(out, grid_shape, n_seeds)
+        stats = call.to_host(stats)
+        out = summarize_market(stats, telemetry, env=env, work=work)
+        return _reshape_sweep(out, grid_shape, n_seeds)
 
 
 # ===========================================================================
@@ -3707,19 +3720,21 @@ def run_region_sim(
     ``work`` attaches the work structure and survival ledger exactly as
     in :func:`run_market_sim`.
     """
-    topology = as_topology(topology)
-    params = {} if params is None else params
-    _check_rng(rng)
-    _check_telemetry(telemetry)
-    _check_env(env)
-    _check_work(work, kernel)
-    _check_run_shape("run_region_sim", n_events, burn_in)
-    interp = _resolve_interpret("run_region_sim", impl, rng, interpret)
-    rp = topology.params()
-    ep = _env_params(env, topology.n_regions)
-    wk = None if work is None else work.params()
-    chunk = n_events if chunk_events is None else min(chunk_events, n_events)
-    with annotate(f"repro.run_region_sim[{impl}]"):
+    with EntrySpan(f"repro.run_region_sim[{impl}]") as call:
+        topology = as_topology(topology)
+        params = {} if params is None else params
+        _check_rng(rng)
+        _check_telemetry(telemetry)
+        _check_env(env)
+        _check_work(work, kernel)
+        _check_run_shape("run_region_sim", n_events, burn_in)
+        interp = _resolve_interpret("run_region_sim", impl, rng, interpret)
+        rp = topology.params()
+        ep = _env_params(env, topology.n_regions)
+        wk = None if work is None else work.params()
+        chunk = (n_events if chunk_events is None
+                 else min(chunk_events, n_events))
+        call.phase("dispatch")
         if impl in ("pallas", "ref"):
             stats = _run_region_sweep_pallas_jit(
                 topology, kernel, topology.preemptible, n_events, chunk,
@@ -3740,9 +3755,10 @@ def run_region_sim(
         else:
             raise ValueError(
                 f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
-    return {name: _scalar_or_array(v)
-            for name, v in summarize_region(stats, telemetry, env=env,
-                                            work=work).items()}
+        stats = call.to_host(stats)
+        return {name: _scalar_or_array(v)
+                for name, v in summarize_region(stats, telemetry, env=env,
+                                                work=work).items()}
 
 
 def run_region_sweep(
@@ -3802,50 +3818,52 @@ def run_region_sweep(
     ``grid_shape + (n_seeds,)`` and per-region statistics
     ``grid_shape + (n_seeds, R)``.
     """
-    topology = as_topology(topology)
-    n = topology.n_regions
-    params = {} if params is None else params
-    _check_rng(rng)
-    _check_telemetry(telemetry)
-    _check_env(env)
-    _check_work(work, kernel)
-    _check_shard("run_region_sweep", shard, mesh)
-    _check_run_shape("run_region_sweep", n_events, burn_in)
-    interp = _resolve_interpret("run_region_sweep", impl, rng, interpret)
-    _check_loc_overrides("run_region_sweep", n, "region", prices=prices,
-                         hazards=hazards, notices=notices,
-                         spot_scales=spot_scales, job_scales=job_scales)
-    ep = _env_params(env, n)
-    wk = None if work is None else work.params()
-    params = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
-    vparams = {} if vector_params is None else jax.tree.map(
-        lambda x: jnp.asarray(x, jnp.float32), dict(vector_params))
-    if vparams and not isinstance(params, dict):
-        raise TypeError("vector_params requires params to be a dict")
-    k = jnp.asarray(k, jnp.float32)
-    overrides = {"price": prices, "hazard": hazards, "notice": notices,
-                 "spot_scale": spot_scales, "job_scale": job_scales}
-    override_shapes = [jnp.asarray(v).shape[:-1]
-                       for v in overrides.values()
-                       if v is not None and jnp.asarray(v).ndim > 1]
-    grid_shape = jnp.broadcast_shapes(
-        k.shape, *(x.shape for x in jax.tree.leaves(params)),
-        *(x.shape[:-1] for x in jax.tree.leaves(vparams)),
-        *override_shapes,
-    )
-    flat = lambda x: jnp.broadcast_to(x, grid_shape).reshape(-1)
-    vflat = lambda x: jnp.broadcast_to(
-        x, grid_shape + x.shape[-1:]).reshape((-1,) + x.shape[-1:])
-    params_flat = {**jax.tree.map(flat, params),
-                   **jax.tree.map(vflat, vparams)} if vparams \
-        else jax.tree.map(flat, params)
-    k_flat = flat(k)
-    rp_flat = _broadcast_config_params(n, topology.params(), overrides,
-                                       grid_shape)
-    preempt_on = topology.preemptible or hazards is not None
-    keys = jax.random.split(key, n_seeds)
-    chunk = n_events if chunk_events is None else min(chunk_events, n_events)
-    with annotate(f"repro.run_region_sweep[{impl}]"):
+    with EntrySpan(f"repro.run_region_sweep[{impl}]") as call:
+        topology = as_topology(topology)
+        n = topology.n_regions
+        params = {} if params is None else params
+        _check_rng(rng)
+        _check_telemetry(telemetry)
+        _check_env(env)
+        _check_work(work, kernel)
+        _check_shard("run_region_sweep", shard, mesh)
+        _check_run_shape("run_region_sweep", n_events, burn_in)
+        interp = _resolve_interpret("run_region_sweep", impl, rng, interpret)
+        _check_loc_overrides("run_region_sweep", n, "region", prices=prices,
+                             hazards=hazards, notices=notices,
+                             spot_scales=spot_scales, job_scales=job_scales)
+        ep = _env_params(env, n)
+        wk = None if work is None else work.params()
+        params = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
+        vparams = {} if vector_params is None else jax.tree.map(
+            lambda x: jnp.asarray(x, jnp.float32), dict(vector_params))
+        if vparams and not isinstance(params, dict):
+            raise TypeError("vector_params requires params to be a dict")
+        k = jnp.asarray(k, jnp.float32)
+        overrides = {"price": prices, "hazard": hazards, "notice": notices,
+                     "spot_scale": spot_scales, "job_scale": job_scales}
+        override_shapes = [jnp.asarray(v).shape[:-1]
+                           for v in overrides.values()
+                           if v is not None and jnp.asarray(v).ndim > 1]
+        grid_shape = jnp.broadcast_shapes(
+            k.shape, *(x.shape for x in jax.tree.leaves(params)),
+            *(x.shape[:-1] for x in jax.tree.leaves(vparams)),
+            *override_shapes,
+        )
+        flat = lambda x: jnp.broadcast_to(x, grid_shape).reshape(-1)
+        vflat = lambda x: jnp.broadcast_to(
+            x, grid_shape + x.shape[-1:]).reshape((-1,) + x.shape[-1:])
+        params_flat = {**jax.tree.map(flat, params),
+                       **jax.tree.map(vflat, vparams)} if vparams \
+            else jax.tree.map(flat, params)
+        k_flat = flat(k)
+        rp_flat = _broadcast_config_params(n, topology.params(), overrides,
+                                           grid_shape)
+        preempt_on = topology.preemptible or hazards is not None
+        keys = jax.random.split(key, n_seeds)
+        chunk = (n_events if chunk_events is None
+                 else min(chunk_events, n_events))
+        call.phase("dispatch")
         if shard == "lanes":
             if impl not in ("xla", "pallas", "ref"):
                 raise ValueError(
@@ -3871,5 +3889,6 @@ def run_region_sweep(
         else:
             raise ValueError(
                 f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
-    out = summarize_region(stats, telemetry, env=env, work=work)
-    return _reshape_sweep(out, grid_shape, n_seeds)
+        stats = call.to_host(stats)
+        out = summarize_region(stats, telemetry, env=env, work=work)
+        return _reshape_sweep(out, grid_shape, n_seeds)

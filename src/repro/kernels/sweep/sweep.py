@@ -114,7 +114,8 @@ def _lanes_last(x):
 
 def _lanes_first(x, shape: tuple):
     """Inverse of :func:`_lanes_last` for per-lane ``shape``."""
-    return jnp.moveaxis(x.reshape(shape + x.shape[-1:]), -1, 0)
+    with jax.named_scope("repro.glue.lanes_first"):
+        return jnp.moveaxis(x.reshape(shape + x.shape[-1:]), -1, 0)
 
 
 def _resident_spec(shape: tuple, tile: int) -> pl.BlockSpec:
@@ -272,9 +273,10 @@ def batched_event_windows(step, state, params, stats_zero, events_per_window,
             fill = jnp.broadcast_to(x[:1], (pad,) + x.shape[1:])
             return jnp.concatenate([x, fill])
 
-        state_leaves = [padlane(x) for x in state_leaves]
-        params_leaves = [padlane(x) for x in params_leaves]
-        key_leaves = [padlane(x) for x in key_leaves]
+        with jax.named_scope("repro.glue.pad_lanes"):
+            state_leaves = [padlane(x) for x in state_leaves]
+            params_leaves = [padlane(x) for x in params_leaves]
+            key_leaves = [padlane(x) for x in key_leaves]
     bp = b + pad
     n_windows = len(events_per_window)
     nev = jnp.asarray(events_per_window, jnp.int32)
@@ -310,6 +312,7 @@ def batched_event_windows(step, state, params, stats_zero, events_per_window,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="repro_batched_events",
     )(nev, *state_leaves, *params_leaves, *key_leaves)
     n_state = len(state_leaves)
     final_state = jax.tree.unflatten(

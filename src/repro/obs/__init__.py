@@ -21,7 +21,8 @@ zero cost, bitwise-reproduced stats (frozen in tests/test_obs.py).
 * :mod:`repro.obs.trace` — event tracing (device rings / host recorder)
   and the Chrome/Perfetto exporter.
 * :mod:`repro.obs.timing` — compile-vs-steady timing, BENCH provenance
-  stamps, profiler trace scopes.
+  stamps, profiler trace scopes, the entry points' phase spans and the
+  compile counter.
 """
 from .shocks import (ENV_INT_STATS, EnvWindowStats, env_merge,
                      env_reduce, env_update, env_zeros, summarize_env)
@@ -32,7 +33,7 @@ from .stats import (EVENT_TYPES, TEL_INT_STATS, Telemetry,
                     TelemetryWindowStats, sketch_quantile,
                     summarize_telemetry, telemetry_merge, telemetry_reduce,
                     telemetry_update, telemetry_zeros)
-from .timing import annotate, provenance, time_compiled
+from .timing import annotate, compile_count, provenance, time_compiled
 from .trace import (TraceRecorder, device_trace_records, to_perfetto,
                     write_perfetto)
 
@@ -47,6 +48,7 @@ __all__ = [
     "TelemetryWindowStats",
     "TraceRecorder",
     "annotate",
+    "compile_count",
     "device_trace_records",
     "env_merge",
     "env_reduce",
