@@ -480,6 +480,14 @@ class SpotCluster:
         ``mesh``) partitions the what-if lane axis across devices exactly
         as in :func:`repro.core.engine.run_sweep` — wide grids answer at
         fleet scale (docs/scaling.md).
+
+        The executor follows the backend (:func:`_what_if_executor`): the
+        compiled batched-event kernel on a TPU, the XLA scan elsewhere,
+        both on the ``rng="slab"`` stream, so integer stats agree bitwise
+        across backends.  Its answers differ key for key from those of the
+        ``rng="split"`` stream it ran before, under the same laws; that
+        stream stays one :func:`~repro.core.engine.run_market_sweep` call
+        away.
         """
         import jax
         import jax.numpy as jnp
@@ -496,6 +504,7 @@ class SpotCluster:
                 {"r": jnp.asarray(rs, jnp.float32)},
                 k=self.k if k is None else k, n_events=n_events, key=key,
                 n_seeds=n_seeds, telemetry=telemetry, shard=shard, mesh=mesh,
+                **_what_if_executor(),
             )
 
     # ------------------------------------------------------ deadline slack
@@ -527,6 +536,18 @@ class SpotCluster:
                 del self._step_times[pod_id]
                 return True
         return False
+
+
+def _what_if_executor() -> dict:
+    """:meth:`SpotCluster.what_if_sweep`'s executor, from the backend:
+    Mosaic compiles the kernel for every what-if variant (``telemetry=``
+    and ``shard="lanes"`` too; tests/test_tpu_compile.py), and the kernel
+    runs the slab stream only."""
+    from repro.kernels.sweep.ops import default_interpret
+
+    if default_interpret():
+        return {"impl": "xla", "rng": "slab"}
+    return {"impl": "pallas", "interpret": False, "rng": "slab"}
 
 
 @dataclasses.dataclass

@@ -6,7 +6,10 @@ the chip: 4,096 lanes (64 r-values x 64 seeds), ``rmax=64``, ``tile=256``,
 ``chunk_events=65536``.  The compiled Pallas executors (``interpret=False``,
 ``rng="slab"``) of all three loops must lower to a Mosaic kernel
 (``tpu_custom_call``) that the compiler accepts; the XLA executor and the
-four-chip ``shard="lanes"`` program must compile too.  Nothing runs.
+four-chip ``shard="lanes"`` program must compile too.  So must what
+``SpotCluster.what_if_sweep`` runs on a TPU, at its own 32 lanes, in every
+variant: plain, ``telemetry=`` and ``shard="lanes"`` on four chips.
+Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -79,10 +82,10 @@ def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _per_point(tree, sharding):
+def _per_point(tree, sharding, g=G):
     """A config's param arrays with a leading grid axis, as shapes."""
     return jax.tree.map(
-        lambda a: _spec(sharding, (G,) + np.shape(a), np.asarray(a).dtype),
+        lambda a: _spec(sharding, (g,) + np.shape(a), np.asarray(a).dtype),
         tree)
 
 
@@ -139,6 +142,49 @@ def test_sharded_pallas_compiles_for_four_chips(topo, no_compile_cache):
         _spec(rep, (S, 2), jnp.uint32), executor="pallas",
         rng="slab").compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _what_if_lowering(variant, topo, monkeypatch):
+    """What ``SpotCluster.what_if_sweep`` runs on a TPU for 16 r at its
+    defaults (2 seeds: 32 lanes; 20,000 events: one window, one tile)."""
+    import inspect
+
+    import repro.kernels.sweep.ops as ops
+    from repro.cluster.orchestrator import SpotCluster, _what_if_executor
+    from repro.obs import Telemetry
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    ex = _what_if_executor()
+    assert ex["impl"] == "pallas", variant
+    defaults = {name: p.default for name, p in inspect.signature(
+        SpotCluster.what_if_sweep).parameters.items()}
+    n_events, s = defaults["n_events"], defaults["n_seeds"]
+    tile = inspect.signature(E.run_market_sweep).parameters["tile"].default
+    chunk = min(E.DEFAULT_CHUNK_EVENTS, n_events)
+    g = 16
+    kernel = NoticeAwareKernel(checkpoint_time=0.025)
+    tel = Telemetry() if variant == "telemetry" else None
+    if variant == "lanes4":
+        mesh = Mesh(np.array(topo.devices), ("lanes",))
+        sh = NamedSharding(mesh, PartitionSpec())
+        fn, mesh_arg = E._run_market_sweep_sharded_jit, (mesh,)
+    else:
+        sh = SingleDeviceSharding(topo.devices[0])
+        fn, mesh_arg = E._run_market_sweep_pallas_jit, ()
+    return fn.lower(
+        Exponential(LAM), MARKET, kernel, RMAX, MARKET.preemptible,
+        n_events, chunk, 0, tile, ex["interpret"], *mesh_arg,
+        {"r": _spec(sh, (g,))}, _per_point(MARKET.params(), sh, g),
+        _spec(sh, (g,)),
+        _spec(sh, (s, 2), jnp.uint32), executor=ex["impl"], rng=ex["rng"],
+        tel=tel)
+
+
+@pytest.mark.parametrize("variant", ["plain", "telemetry", "lanes4"])
+def test_what_if_compiles_to_the_kernel(variant, topo, no_compile_cache,
+                                        monkeypatch):
+    compiled = _what_if_lowering(variant, topo, monkeypatch).compile()
+    assert "repro_batched_events" in compiled.as_text()
 
 
 def test_compiled_kernel_refuses_split_stream():
