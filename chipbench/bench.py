@@ -244,13 +244,16 @@ def latency_line(run: Run) -> str:
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              t_start: float, root: pathlib.Path = ROOT,
              devices=None, control: bool = False,
-             keep_trace: pathlib.Path | None = None) -> dict:
+             keep_trace: pathlib.Path | None = None,
+             calls: int | None = None) -> dict:
     """Set up, measure and check one cell; return the result line.
 
     ``devices`` skips the look for a chip (tests pass the CPU's);
     ``control`` runs the configuration's control in the program's place
     (tests and ``readings.py`` only); ``keep_trace`` writes the reduced
-    trace of a traced run there (``tools/record_trace.py``)."""
+    trace of a traced run there (``tools/record_trace.py``); ``calls``
+    ends the untraced window at that many calls, if ``seconds`` have not
+    ended it first (tests only)."""
     bench = load_benchmark(root)
     cell = Cell.load(bench, workload, root / "chipbench")
     chips = int(cell.workload["chips"])
@@ -275,7 +278,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                           root / CACHE / "trace" / workload,
                           int(cell.traffic["traced_calls"]))
         else:
-            run_window(load, run, seconds)
+            run_window(load, run, seconds, calls)
     except Exception:  # a call that fails is an answer that never came
         error = traceback.format_exc()
     used = devices[:chips]

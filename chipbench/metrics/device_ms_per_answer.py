@@ -1,5 +1,8 @@
-"""Device busy time per what-if answer, in ms: the XLA executor's scan
-and whatever else the answer ran on the device."""
+"""Device busy time per what-if answer, in ms: the union of the device
+ops' intervals in the traced calls, mean over devices, over the
+answers.  It holds the kernel and the glue around it (slab keys,
+padding, reductions), so work moved onto the device shows here and not
+in ``kernel_ns_per_event.whatif``."""
 
 
 def read(run):

@@ -66,16 +66,25 @@ def count_z(counts, expected) -> float:
     return seed_z(c[~never], e[~never][:, None])
 
 
-def repeats(answers: list[dict], name: str = "time") -> int:
-    """Lanes whose ``name`` equals another seed's at the same grid point,
-    plus answers whose ``name`` equals the previous answer's.  Every call
-    draws fresh keys and every seed its own stream, so any repeat is a
-    lane or an answer that was not simulated."""
+#: The statistics that identify a simulated lane: its simulated hours
+#: and arrivals depend on the seed alone (the same at every grid point),
+#: its completions and on-demand jobs on the seed and the policy.
+LANE_ROW = ("time", "jobs_arrived", "jobs_completed", "ondemand")
+
+
+def repeats(answers: list[dict]) -> int:
+    """Lanes whose ``LANE_ROW`` equals another seed's at the same grid
+    point, plus answers whose rows all equal the previous answer's.  Every
+    call draws fresh keys and every seed its own stream, so any repeat is
+    a lane or an answer that was not simulated.  A single statistic would
+    not do: two seeds whose float32 hours happen to be equal are equal at
+    every grid point."""
     n = 0
     prev = None
     for a in answers:
-        x = np.asarray(a[name], np.float64)
-        for row in x.reshape(x.shape[0], x.shape[1], -1):
+        x = np.concatenate([np.asarray(a[name], np.float64).reshape(
+            *np.shape(a[name])[:2], -1) for name in LANE_ROW], axis=-1)
+        for row in x:  # one grid point: (seeds, statistics)
             n += row.shape[0] - np.unique(row, axis=0).shape[0]
         if prev is not None and prev.shape == x.shape and np.array_equal(prev, x):
             n += 1

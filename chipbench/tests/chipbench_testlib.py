@@ -3,6 +3,7 @@ that the CPU runs in seconds, with the Pallas kernel interpreted."""
 from __future__ import annotations
 
 import json
+import math
 import pathlib
 import shutil
 import time
@@ -31,12 +32,14 @@ def tiny_root(tmp: pathlib.Path) -> pathlib.Path:
 
 
 def run_tiny(root: pathlib.Path, workload: str, seed: int = 11, *,
-             seconds: float = 0.0, control: bool = False) -> dict:
-    """One untraced run of ``workload`` on the CPU: no look for a chip,
-    and no persistent compilation cache written."""
+             calls: int = 2, control: bool = False) -> dict:
+    """One untraced run of ``workload`` on the CPU, its window ``calls``
+    calls however long they take: no look for a chip, and no persistent
+    compilation cache written."""
     import jax
     from chipbench import bench
     with mock.patch.object(bench, "enable_compile_cache"):
-        return bench.run_cell(workload, seed, seconds, False,
+        return bench.run_cell(workload, seed, math.inf, False,
                               t_start=time.perf_counter(), root=root,
-                              devices=jax.devices(), control=control)
+                              devices=jax.devices(), control=control,
+                              calls=calls)

@@ -80,15 +80,22 @@ def tiny(tmp_path_factory):
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_each_cell_runs_and_checks_correct(tiny, workload):
-    line = run_tiny(tiny, workload, seconds=0.5)
+    line = run_tiny(tiny, workload)
     assert line["correct"], line["checks"]
-    assert line["attempted"] >= 1 and line["failed"] == 0
+    assert line["attempted"] == 2 and line["failed"] == 0
     want = {m["name"] for m in bench.cell_metrics(BENCH, workload,
                                                   "end_to_end")}
     assert set(line["metrics"]) == want
     assert all(v["value"] > 0 for v in line["metrics"].values())
     assert line["device"]["platform"] == "cpu"
     assert list(line)[-1] == "checks"
+
+
+@pytest.mark.parametrize("calls", [1, 3])
+def test_a_window_asked_for_calls_makes_that_many(tiny, calls):
+    line = run_tiny(tiny, "market4.whatif", calls=calls)
+    assert line["attempted"] == calls
+    assert line["correct"], line["checks"]
 
 
 def test_a_cell_is_added_with_new_files_and_entries_only(tiny):

@@ -46,6 +46,8 @@ def test_busy_window_and_idle_on_a_synthetic_trace():
                     trace=tr)
     assert _read("kernel_ns_per_event", run) == pytest.approx(24.0 * 1e9
                                                               / 2000)
+    assert _read("kernel_ns_per_event.whatif", run) == pytest.approx(
+        24.0 * 1e9 / 2000)
     assert _read("glue_busy_pct", run) == pytest.approx(
         100 * (13.75 - 12.0) / 13.75)
     assert _read("device_idle_pct.events", run) == pytest.approx(
@@ -69,9 +71,11 @@ def test_a_reader_finds_nothing_without_a_kernel_or_a_trace():
     run = bench.Run(setup_s=1.0, lane_events_per_call=10,
                     calls=[(0.0, 2.0)], answers=[{}], trace=tr)
     assert _read("kernel_ns_per_event", run) is None
+    assert _read("kernel_ns_per_event.whatif", run) is None
     assert _read("glue_busy_pct", run) is None
     run.trace = None
-    for name in ("kernel_ns_per_event", "device_idle_pct.events",
+    for name in ("kernel_ns_per_event", "kernel_ns_per_event.whatif",
+                 "device_idle_pct.events",
                  "device_ms_per_answer", "host_ms_per_answer"):
         assert _read(name, run) is None
 

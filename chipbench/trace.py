@@ -142,6 +142,20 @@ class Trace:
         return json.dumps({"ops": self.ops, "spans": self.spans})
 
 
+def kernel_ns_per_event(run):
+    """Device time of the batched-event kernel per simulated lane-event:
+    the kernel's op time, summed over the devices, over the lane-events
+    of the traced calls (``n_events + burn_in`` per lane and call).  The
+    kernel is the op of the Mosaic custom-call category."""
+    tr = run.trace
+    if tr is None or not tr.ops or not run.calls:
+        return None
+    kernel_s = tr.busy_s(lambda n, c: c == KERNEL_CATEGORY) * len(tr.ops)
+    if kernel_s <= 0:
+        return None
+    return kernel_s * 1e9 / run.lane_events
+
+
 def _op(event) -> list:
     """``[start, end, name, category]`` of a device op, in seconds."""
     name, _, hlo = event.name.partition(" = ")
