@@ -15,7 +15,8 @@ and 4,096 lanes (64 r-values x 64 seeds), with the Pallas kernel compiled
   at integer r the mean ``avg_cost`` matches the M/M/1/N closed form
   (``theorem5_cost``), and at every r the Theorem-1 cost law holds for
   the measured ``pi0_spot``;
-* market and regions: exact per-lane accounting identities;
+* market and regions: exact per-lane accounting identities (the regions
+  are the chip benchmark's ``region2_least_loaded``, revocations on);
 * ``pallas`` vs ``xla``: the contract in :data:`XLA_CONTRACT`.
 
 Tolerances are in units of the seed spread: |mean difference| <= Z_MAX
@@ -207,19 +208,26 @@ def market_phases(key):
 
 
 def region_phases(key):
+    """The chip benchmark's two spot regions (``region2_least_loaded``),
+    hazards and notice on: routing, per-region admission and the
+    revocation and resume branch."""
     import numpy as np
-    from repro.core import (Exponential, Region, RegionTopology,
-                            RoutingKernel, ThreePhaseKernel,
-                            run_region_sweep)
+    from repro.core import (Exponential, NoticeAwareKernel, Region,
+                            RegionTopology, RoutingKernel, run_region_sweep)
 
-    topo = RegionTopology(regions=(
-        Region(job=Exponential(1 / 24), spot=Exponential(1 / 48), price=0.9),
-        Region(job=Exponential(1 / 24), spot=Exponential(1 / 48), price=0.2),
-    ))
-    kern = RoutingKernel(ThreePhaseKernel(), choice="least_loaded")
+    cfg = json.loads((ROOT / "chipbench" / "configs"
+                      / "region2_least_loaded.json").read_text())
+    topo = RegionTopology(regions=tuple(
+        Region(job=Exponential(g["job_rate"]),
+               spot=Exponential(g["spot_rate"]), price=g["price"],
+               hazard=g["hazard"], notice=g["notice"], rmax=g["rmax"])
+        for g in cfg["regions"]))
+    kern = RoutingKernel(
+        NoticeAwareKernel(checkpoint_time=cfg["checkpoint_hours"]),
+        choice=cfg["routing"])
     rs = r_grid()
     lane_events = rs.size * N_SEEDS * (LOOP_EVENTS + BURN_IN)
-    kw = dict(k=K, n_events=LOOP_EVENTS, key=key, n_seeds=N_SEEDS,
+    kw = dict(k=cfg["k"], n_events=LOOP_EVENTS, key=key, n_seeds=N_SEEDS,
               burn_in=BURN_IN, rng="slab")  # rmax: Region.rmax, 64
     outs = {}
     for impl, extra in (("xla", {"impl": "xla"}), ("pallas", PALLAS)):
@@ -235,12 +243,17 @@ def region_phases(key):
         served = np.asarray(out["region_served"]).sum(axis=-1)
         check(f"regions[{impl}] sum(region_served) - spot_served",
               float(np.max(np.abs(served - out["spot_served"]))), 0.0)
-        closed = out["spot_served"] + out["ondemand"]
-        check(f"regions[{impl}] jobs_completed - (spot + on-demand)",
-              float(np.max(np.abs(out["jobs_completed"] - closed))), 0.0)
+        # a leg closes by spot service, on-demand, or a preempted resume
+        legs = out["spot_served"] + out["ondemand"] + out["resumed"]
+        check(f"regions[{impl}] jobs_completed - closed legs",
+              float(np.max(np.abs(out["jobs_completed"] - legs))), 0.0)
         routed = np.asarray(out["region_jobs"]).sum(axis=-1)
         check(f"regions[{impl}] sum(region_jobs) - jobs_arrived",
               float(np.max(np.abs(routed - out["jobs_arrived"]))), 0.0)
+        if not (np.sum(out["resumed"]) > 0
+                and np.all(np.sum(out["region_preempted"], axis=(0, 1)) > 0)):
+            raise CheckFailed(f"regions[{impl}]: no revocation or no resume "
+                              f"in some region")
         if not np.all(np.isfinite(np.asarray(out["avg_cost_job"]))):
             raise CheckFailed(f"regions[{impl}]: non-finite avg_cost_job")
     check_xla_contract("regions", outs["pallas"], outs["xla"],

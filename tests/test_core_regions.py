@@ -213,6 +213,32 @@ def test_region_sweep_pallas_bit_for_bit():
                        "hetero-routing")
 
 
+def test_region2_revocations_slab_pallas_bit_for_bit():
+    """The two spot regions the chip benchmark runs (``region2_least_
+    loaded``: even demand and supply, the Advisor bands' hazards, a
+    two-minute notice, a 90 s checkpoint, least-loaded routing) on the
+    slab stream, with the hazards scaled up so that revocations and
+    resumes occur at this size: the interpreted kernel is the scan
+    oracle bit for bit."""
+    hazard_scale = 200.0
+    topo = RegionTopology(regions=tuple(
+        Region(Exponential(1 / 24), Exponential(1 / 48), price=price,
+               hazard=hazard * hazard_scale, notice=1 / 30, rmax=64)
+        for price, hazard in ((1.0, 0.0002635231406129536),
+                              (2.0, 3.46819287456026e-05))))
+    kern = RoutingKernel(NoticeAwareKernel(checkpoint_time=0.025),
+                         choice="least_loaded")
+    params = {"r": jnp.array([0.75, 1.5, 2.0, 3.25])}
+    kw = dict(k=K, n_events=3_000, key=jax.random.key(16), n_seeds=3,
+              burn_in=500, chunk_events=1_024, rng="slab")
+    ref = run_region_sweep(topo, kern, params, impl="ref", **kw)
+    pal = run_region_sweep(topo, kern, params, impl="pallas",
+                           interpret=True, tile=8, **kw)
+    assert_stats_equal(ref, pal, "region2 slab")
+    assert (np.asarray(pal["resumed"]) > 0).any()
+    assert (np.asarray(pal["region_preempted"]) > 0).all()
+
+
 # ---------------------------------------------------------------------------
 # Property: statistics exactly invariant under region relabeling
 # ---------------------------------------------------------------------------

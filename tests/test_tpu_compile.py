@@ -5,11 +5,12 @@ for a described ``v5e:2x2`` topology at the size ``chip_smoke.py`` runs on
 the chip: 4,096 lanes (64 r-values x 64 seeds), ``rmax=64``, ``tile=256``,
 ``chunk_events=65536``.  The compiled Pallas executors (``interpret=False``,
 ``rng="slab"``) of all three loops must lower to a Mosaic kernel
-(``tpu_custom_call``) that the compiler accepts; the XLA executor and the
-four-chip ``shard="lanes"`` program must compile too.  So must what
-``SpotCluster.what_if_sweep`` runs on a TPU, at its own 32 lanes, in every
-variant: plain, ``telemetry=`` and ``shard="lanes"`` on four chips.
-Nothing runs.
+(``tpu_custom_call``) that the compiler accepts, the region loop also
+with its revocation and resume branch on (``region_preempt``); the XLA
+executor and the four-chip ``shard="lanes"`` program must compile too.
+So must what ``SpotCluster.what_if_sweep`` runs on a TPU, at its own 32
+lanes, in every variant: plain, ``telemetry=`` and ``shard="lanes"`` on
+four chips.  Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -48,6 +49,15 @@ ONE_POOL = SpotMarket(pools=(
 TOPOLOGY = RegionTopology(regions=(
     Region(job=Exponential(1 / 24), spot=Exponential(1 / 48), price=0.9),
     Region(job=Exponential(1 / 24), spot=Exponential(1 / 48), price=0.2),
+))
+#: The benchmark's two spot regions (``region2_least_loaded``): the
+#: Advisor bands' hazards and a two-minute notice, so the region loop's
+#: revocation and resume branch is in the kernel.
+PREEMPT_TOPOLOGY = RegionTopology(regions=(
+    Region(job=Exponential(1 / 24), spot=Exponential(1 / 48), price=1.0,
+           hazard=0.0002635231406129536, notice=1 / 30),
+    Region(job=Exponential(1 / 24), spot=Exponential(1 / 48), price=2.0,
+           hazard=3.46819287456026e-05, notice=1 / 30),
 ))
 
 
@@ -104,6 +114,13 @@ def _pallas_lowering(loop, sh):
             NoticeAwareKernel(checkpoint_time=0.05), RMAX,
             market.preemptible, N_EVENTS, CHUNK, BURN_IN, TILE, False,
             r, _per_point(market.params(), sh), k, keys, **kw)
+    if loop == "region_preempt":
+        kernel = RoutingKernel(NoticeAwareKernel(checkpoint_time=0.025),
+                               choice="least_loaded")
+        return E._run_region_sweep_pallas_jit.lower(
+            PREEMPT_TOPOLOGY, kernel, True, N_EVENTS, CHUNK, BURN_IN,
+            TILE, False, r, _per_point(PREEMPT_TOPOLOGY.params(), sh), k,
+            keys, **kw)
     return E._run_region_sweep_pallas_jit.lower(
         TOPOLOGY, RoutingKernel(ThreePhaseKernel(), choice="least_loaded"),
         TOPOLOGY.preemptible, N_EVENTS, CHUNK, BURN_IN, TILE, False, r,
@@ -111,7 +128,7 @@ def _pallas_lowering(loop, sh):
 
 
 @pytest.mark.parametrize("loop", ["single", "market", "one_pool_market",
-                                  "region"])
+                                  "region", "region_preempt"])
 def test_pallas_executor_compiles_to_mosaic(loop, one_chip,
                                             no_compile_cache):
     lowered = _pallas_lowering(loop, one_chip)
