@@ -490,7 +490,6 @@ class SpotCluster:
         away.
         """
         import jax
-        import jax.numpy as jnp
 
         from repro.core.engine import run_market_sweep
 
@@ -498,10 +497,13 @@ class SpotCluster:
             if key is None:
                 key = jax.random.key(int(self.rng.integers(2**31)))
             kern = NoticeAwareKernel(checkpoint_time=self.checkpoint_hours)
-            # the engine joins this call's span and phases
+            # the engine joins this call's span and phases, and casts ``rs``
+            # inside its one jitted call (a list becomes one host array
+            # here, not a pytree of scalars)
+            if not isinstance(rs, jax.Array):
+                rs = np.asarray(rs)
             return run_market_sweep(
-                self.jobs, self.market, kern,
-                {"r": jnp.asarray(rs, jnp.float32)},
+                self.jobs, self.market, kern, {"r": rs},
                 k=self.k if k is None else k, n_events=n_events, key=key,
                 n_seeds=n_seeds, telemetry=telemetry, shard=shard, mesh=mesh,
                 **_what_if_executor(),

@@ -2484,12 +2484,50 @@ def _broadcast_config_params(n: int, cfg: dict, overrides: dict,
             .reshape((-1, n)) for name, v in cfg.items()}
 
 
-def _broadcast_market_params(market: SpotMarket, mp_overrides: dict,
-                             grid_shape: tuple) -> dict:
-    """Pools-config overrides → flat traced market params (see
-    :func:`_broadcast_config_params`)."""
-    return _broadcast_config_params(market.n_pools, market.params(),
-                                    mp_overrides, grid_shape)
+@functools.partial(
+    jax.jit,
+    static_argnames=("job", "market", "kernel", "rmax", "preempt_on",
+                     "n_events", "chunk_events", "burn_in", "tile",
+                     "interpret", "mesh", "impl", "rng", "tel", "work",
+                     "grid_shape", "n_seeds"),
+)
+def _market_sweep_call(job, market, kernel, rmax, preempt_on, n_events,
+                       chunk_events, burn_in, tile, interpret, mesh, impl,
+                       rng, tel, work, grid_shape, n_seeds, params, mp, k,
+                       overrides, key, ep=None, wk=None):
+    """:func:`run_market_sweep`'s argument prep and executor as ONE
+    program, so an answer is one dispatch: the float32 casts, the grid
+    broadcast and flattening, the pools-config overrides and the seed-key
+    split run under the trace, then the executor that ``impl`` and
+    ``mesh`` select (jitted itself, so inlined).
+
+    ``mp`` (:meth:`SpotMarket.host_params`) enters as an operand, never
+    as a constant: XLA rewrites a division by a constant into a
+    multiplication by its reciprocal, which would move the clocks
+    ``init_market_state`` draws by an ulp."""
+    flat = lambda x: jnp.broadcast_to(jnp.asarray(x, jnp.float32),
+                                      grid_shape).reshape(-1)
+    params_flat = jax.tree.map(flat, params)
+    k_flat = flat(k)
+    mp_flat = _broadcast_config_params(market.n_pools, mp, overrides,
+                                       grid_shape)
+    keys = jax.random.split(key, n_seeds)
+    if mesh is not None:
+        return _run_market_sweep_sharded_jit(
+            job, market, kernel, rmax, preempt_on, n_events, chunk_events,
+            burn_in, tile, interpret, mesh, params_flat, mp_flat, k_flat,
+            _raw_keys(keys), executor=impl, rng=rng, tel=tel, ep=ep,
+            work=work, wk=wk)
+    if impl == "xla":
+        return _run_market_sweep_jit(
+            job, market, kernel, rmax, preempt_on, n_events, chunk_events,
+            burn_in, rng, params_flat, mp_flat, k_flat, keys, tel=tel, ep=ep,
+            work=work, wk=wk)
+    return _run_market_sweep_pallas_jit(
+        job, market, kernel, rmax, preempt_on, n_events, chunk_events,
+        burn_in, tile, interpret, params_flat, mp_flat, k_flat,
+        _raw_keys(keys), executor=impl, rng=rng, tel=tel, ep=ep, work=work,
+        wk=wk)
 
 
 def run_market_sim(
@@ -2626,55 +2664,30 @@ def run_market_sweep(
         _check_loc_overrides("run_market_sweep", n, "pool", prices=prices,
                              hazards=hazards, notices=notices,
                              spot_scales=spot_scales)
-        ep = _env_params(env, n)
-        wk = None if work is None else work.params()
-        params = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
-        k = jnp.asarray(k, jnp.float32)
-        overrides = {"price": prices, "hazard": hazards, "notice": notices,
-                     "spot_scale": spot_scales}
-        override_shapes = [jnp.asarray(v).shape[:-1]
-                           for v in overrides.values()
-                           if v is not None and jnp.asarray(v).ndim > 1]
-        grid_shape = jnp.broadcast_shapes(
-            k.shape, *(x.shape for x in jax.tree.leaves(params)),
-            *override_shapes,
-        )
-        flat = lambda x: jnp.broadcast_to(x, grid_shape).reshape(-1)
-        params_flat = jax.tree.map(flat, params)
-        k_flat = flat(k)
-        mp_flat = _broadcast_market_params(market, overrides, grid_shape)
-        preempt_on = market.preemptible or hazards is not None
-        keys = jax.random.split(key, n_seeds)
-        chunk = (n_events if chunk_events is None
-                 else min(chunk_events, n_events))
-        call.phase("dispatch")
-        if shard == "lanes":
-            if impl not in ("xla", "pallas", "ref"):
-                raise ValueError(
-                    f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
-            stats = _run_market_sweep_sharded_jit(
-                job, market, kernel, rmax, preempt_on, n_events, chunk,
-                burn_in, tile,
-                interp,
-                lane_mesh() if mesh is None else mesh, params_flat, mp_flat,
-                k_flat, _raw_keys(keys), executor=impl, rng=rng,
-                tel=telemetry, ep=ep, work=work, wk=wk)
-        elif impl in ("pallas", "ref"):
-            stats = _run_market_sweep_pallas_jit(
-                job, market, kernel, rmax, preempt_on, n_events, chunk,
-                burn_in, tile,
-                interp,
-                params_flat, mp_flat, k_flat, _raw_keys(keys), executor=impl,
-                rng=rng, tel=telemetry, ep=ep, work=work, wk=wk)
-        elif impl == "xla":
-            stats = _run_market_sweep_jit(job, market, kernel, rmax,
-                                          preempt_on, n_events, chunk,
-                                          burn_in, rng, params_flat, mp_flat,
-                                          k_flat, keys, tel=telemetry, ep=ep,
-                                          work=work, wk=wk)
-        else:
+        if impl not in ("xla", "pallas", "ref"):
             raise ValueError(
                 f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
+        ep = _env_params(env, n)
+        wk = None if work is None else work.params()
+        overrides = {"price": prices, "hazard": hazards, "notice": notices,
+                     "spot_scale": spot_scales}
+        # shapes only: np.shape builds no device array
+        override_shapes = [np.shape(v)[:-1] for v in overrides.values()
+                           if v is not None and np.ndim(v) > 1]
+        grid_shape = np.broadcast_shapes(
+            np.shape(k), *(np.shape(x) for x in jax.tree.leaves(params)),
+            *override_shapes)
+        preempt_on = market.preemptible or hazards is not None
+        chunk = (n_events if chunk_events is None
+                 else min(chunk_events, n_events))
+        if shard == "lanes" and mesh is None:
+            mesh = lane_mesh()
+        call.phase("dispatch")
+        stats = _market_sweep_call(
+            job, market, kernel, rmax, preempt_on, n_events, chunk, burn_in,
+            tile, interp, mesh, impl, rng, telemetry, work, grid_shape,
+            n_seeds, params, market.host_params(), k, overrides, key, ep=ep,
+            wk=wk)
         stats = call.to_host(stats)
         out = summarize_market(stats, telemetry, env=env, work=work)
         return _reshape_sweep(out, grid_shape, n_seeds)

@@ -149,12 +149,18 @@ class SpotMarket:
         params rather than being materialized inside the event body so the
         body stays constant-capture-free under the Pallas kernel trace.
         """
+        return {name: jnp.asarray(v) for name, v in self.host_params().items()}
+
+    def host_params(self) -> dict:
+        """:meth:`params` as float32 NumPy arrays, bitwise the same values:
+        an entry point hands them to its one jitted call as operands,
+        building no device array of its own."""
         return {
-            "price": jnp.asarray(self.prices(), jnp.float32),
-            "hazard": jnp.asarray(self.hazards(), jnp.float32),
-            "notice": jnp.asarray(self.notices(), jnp.float32),
-            "spot_scale": jnp.ones((self.n_pools,), jnp.float32),
-            "rate": jnp.asarray(self.rates(), jnp.float32),
+            "price": self.prices().astype(np.float32),
+            "hazard": self.hazards().astype(np.float32),
+            "notice": self.notices().astype(np.float32),
+            "spot_scale": np.ones((self.n_pools,), np.float32),
+            "rate": self.rates().astype(np.float32),
         }
 
     # ------------------------------------------------------------- utilities

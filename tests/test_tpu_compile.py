@@ -8,15 +8,16 @@ the chip: 4,096 lanes (64 r-values x 64 seeds), ``rmax=64``, ``tile=256``,
 (``tpu_custom_call``) that the compiler accepts, the region loop also
 with its revocation and resume branch on (``region_preempt``); the XLA
 executor and the four-chip ``shard="lanes"`` program must compile too.
-So must what ``SpotCluster.what_if_sweep`` runs on a TPU, at its own 32
-lanes, in every variant: plain, ``telemetry=`` and ``shard="lanes"`` on
-four chips.  Nothing runs.
+So must the one program ``SpotCluster.what_if_sweep`` dispatches on a
+TPU, argument prep included, at its own 32 lanes, in every variant:
+plain, ``telemetry=`` and ``shard="lanes"`` on four chips.  Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.  The persistent compile cache is off around these compiles (a
 compile for a described chip cannot be read back).
 """
+import inspect
 import os
 
 import numpy as np
@@ -162,39 +163,51 @@ def test_sharded_pallas_compiles_for_four_chips(topo, no_compile_cache):
 
 
 def _what_if_lowering(variant, topo, monkeypatch):
-    """What ``SpotCluster.what_if_sweep`` runs on a TPU for 16 r at its
-    defaults (2 seeds: 32 lanes; 20,000 events: one window, one tile)."""
-    import inspect
-
+    """What ``SpotCluster.what_if_sweep`` dispatches on a TPU for 16 r at
+    its defaults (2 seeds: 32 lanes; 20,000 events: one window, one tile):
+    the engine's one program (``_market_sweep_call``), lowered at the very
+    arguments the what-if hands it (``rs`` float32 NumPy, ``k`` a float, a
+    typed key, the market's NumPy params) placed on the described chips."""
     import repro.kernels.sweep.ops as ops
-    from repro.cluster.orchestrator import SpotCluster, _what_if_executor
+    from repro.cluster.orchestrator import (OnlineAdmissionController,
+                                            SpotCluster)
     from repro.obs import Telemetry
 
+    class Dispatched(Exception):
+        pass
+
+    front, seen = E._market_sweep_call, {}
+
+    def dispatch(*args, **kw):
+        seen.update(inspect.signature(front).bind(*args, **kw).arguments)
+        raise Dispatched
+
     monkeypatch.setattr(ops, "default_interpret", lambda: False)
-    ex = _what_if_executor()
-    assert ex["impl"] == "pallas", variant
-    defaults = {name: p.default for name, p in inspect.signature(
-        SpotCluster.what_if_sweep).parameters.items()}
-    n_events, s = defaults["n_events"], defaults["n_seeds"]
-    tile = inspect.signature(E.run_market_sweep).parameters["tile"].default
-    chunk = min(E.DEFAULT_CHUNK_EVENTS, n_events)
-    g = 16
-    kernel = NoticeAwareKernel(checkpoint_time=0.025)
-    tel = Telemetry() if variant == "telemetry" else None
-    if variant == "lanes4":
-        mesh = Mesh(np.array(topo.devices), ("lanes",))
-        sh = NamedSharding(mesh, PartitionSpec())
-        fn, mesh_arg = E._run_market_sweep_sharded_jit, (mesh,)
-    else:
-        sh = SingleDeviceSharding(topo.devices[0])
-        fn, mesh_arg = E._run_market_sweep_pallas_jit, ()
-    return fn.lower(
-        Exponential(LAM), MARKET, kernel, RMAX, MARKET.preemptible,
-        n_events, chunk, 0, tile, ex["interpret"], *mesh_arg,
-        {"r": _spec(sh, (g,))}, _per_point(MARKET.params(), sh, g),
-        _spec(sh, (g,)),
-        _spec(sh, (s, 2), jnp.uint32), executor=ex["impl"], rng=ex["rng"],
-        tel=tel)
+    monkeypatch.setattr(E, "_market_sweep_call", dispatch)
+    mesh = Mesh(np.array(topo.devices), ("lanes",))
+    variant_kw = {"plain": {}, "telemetry": {"telemetry": Telemetry()},
+                  "lanes4": {"shard": "lanes", "mesh": mesh}}[variant]
+    cluster = SpotCluster(job_process=Exponential(LAM), market=MARKET,
+                          k_cost=10.0,
+                          controller=OnlineAdmissionController(delta=12.0),
+                          checkpoint_hours=0.025)
+    with pytest.raises(Dispatched):
+        cluster.what_if_sweep(np.linspace(0.5, 8.0, 16, dtype=np.float32),
+                              key=jax.random.key(0), **variant_kw)
+    assert (seen["impl"], seen["interpret"], seen["rng"]) == (
+        "pallas", False, "slab"), variant
+    assert (seen["grid_shape"], seen["n_seeds"]) == ((16,), 2), variant
+    sh = (NamedSharding(mesh, PartitionSpec()) if variant == "lanes4"
+          else SingleDeviceSharding(topo.devices[0]))
+
+    def placed(x):
+        aval = jax.typeof(x)
+        return jax.ShapeDtypeStruct(aval.shape, aval.dtype, sharding=sh,
+                                    weak_type=aval.weak_type)
+
+    operands = ("params", "mp", "k", "overrides", "key", "ep", "wk")
+    return front.lower(**{name: jax.tree.map(placed, v) if name in operands
+                          else v for name, v in seen.items()})
 
 
 @pytest.mark.parametrize("variant", ["plain", "telemetry", "lanes4"])
