@@ -1,7 +1,7 @@
-"""The frozen lowering matrix for the env=None zero-cost contract.
+"""The frozen lowering matrix: the engine's programs, byte for byte.
 
 Shared by ``tools/freeze_hlo_baseline.py`` (which writes
-``tests/data/hlo_pr6.json`` from the pre-env tree) and
+``tests/data/hlo_pr6.json`` from a reference tree) and
 ``tests/test_env.py`` (which re-lowers the same matrix and compares
 sha256 digests byte-for-byte).  Lowered StableHLO text is
 compiler-version specific, so the baseline records the jax version and
@@ -19,6 +19,17 @@ as trailing ``work=None, wk=None`` defaults, so these digests — still
 compared against the *pre-env* baseline — are simultaneously the
 byte-identity proof for ``work=None``.  A new axis that moves even one
 op in the off program shows up here as a moved digest.
+
+Beyond the 24 (loop × executor × rng) cells, the matrix holds the
+programs the chip and the sharded path run: the three loops'
+``shard="lanes"`` programs over a one-device lane mesh (``xla`` and
+interpreted ``pallas``), ``run_market_sweep``'s one jitted front at every
+executor and with a mesh, and each loop's executors with the telemetry,
+env and work axes all on (``*+axes``) — so a rewrite of the executor
+layer is held to the same programs, axes on and off.
+
+Symbol names of the engine's own jits, where one program calls another,
+are normalized like the entry name: renaming an executor moves no digest.
 """
 from __future__ import annotations
 
@@ -28,21 +39,36 @@ import re
 import jax
 import jax.numpy as jnp
 
+import numpy as np
+
 from repro.core import engine
 from repro.core.arrivals import Exponential
+from repro.core.env import EnvTimeline, inject_storm
 from repro.core.market import NoticeAwareKernel, SpotMarket, SpotPool
 from repro.core.policies import ThreePhaseKernel
 from repro.core.regions import Region, RegionTopology, RoutingKernel
+from repro.core.work import WorkModel
+from repro.distributed.sharding import lane_mesh
+from repro.obs import Telemetry
 
 _N_EVENTS, _CHUNK, _BURN = 3000, 1024, 512
 
 # jit entry names embed the wrapper's function name; normalize them so a
 # pure rename (no program change) cannot masquerade as a lowering change
 _NAME = re.compile(r"jit__\w+")
+# a jit called from another jit lowers to a private function named after
+# it (``@_name``, ``@_name_12`` when repeated)
+_SYMBOL = re.compile(r"@(\w+?)(_\d+)?\b")
+_JITS = frozenset(name for name, f in vars(engine).items()
+                  if callable(f) and hasattr(f, "lower"))
+
+
+def _nested(m) -> str:
+    return "@jit_NESTED" if m.group(1) in _JITS else m.group(0)
 
 
 def _digest(lowered) -> str:
-    text = _NAME.sub("jit_ENTRY", lowered.as_text())
+    text = _SYMBOL.sub(_nested, _NAME.sub("jit_ENTRY", lowered.as_text()))
     return hashlib.sha256(text.encode()).hexdigest()
 
 
@@ -113,7 +139,103 @@ def lowering_digests() -> dict:
                 engine._run_region_sweep_pallas_jit.lower(
                     topo, rkern, True, _N_EVENTS, _CHUNK, _BURN, 2, True,
                     pflat, rp_f, kflat, rkeys, executor=ex, rng=rng))
+    out.update(_sharded_digests(job, spot, kern, market, mkern, topo, rkern,
+                                pflat, kflat, rkeys, mp_f, rp_f))
+    out.update(_front_digests(job, market, mkern))
+    out.update(_axes_digests(job, spot, kern, market, mkern, topo, rkern,
+                             params, mp, rp, k, key, pflat, kflat, keys,
+                             rkeys, mp_f, rp_f))
     return out
+
+
+def _sharded_digests(job, spot, kern, market, mkern, topo, rkern, pflat,
+                     kflat, rkeys, mp_f, rp_f) -> dict:
+    """The three ``shard="lanes"`` programs over a one-device mesh."""
+    mesh, out = lane_mesh(), {}
+    for rng in ("split", "slab"):
+        for ex in ("xla", "pallas"):
+            kw = dict(executor=ex, rng=rng)
+            out[f"sweep_sharded_{ex}/{rng}"] = _digest(
+                engine._run_sweep_sharded_jit.lower(
+                    job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, 2, True,
+                    mesh, pflat, kflat, rkeys, **kw))
+            out[f"market_sweep_sharded_{ex}/{rng}"] = _digest(
+                engine._run_market_sweep_sharded_jit.lower(
+                    job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN,
+                    2, True, mesh, pflat, mp_f, kflat, rkeys, **kw))
+            out[f"region_sweep_sharded_{ex}/{rng}"] = _digest(
+                engine._run_region_sweep_sharded_jit.lower(
+                    topo, rkern, True, _N_EVENTS, _CHUNK, _BURN, 2, True,
+                    mesh, pflat, rp_f, kflat, rkeys, **kw))
+    return out
+
+
+def _front_digests(job, market, mkern) -> dict:
+    """``run_market_sweep``'s one program (prep and executor), at the
+    arguments the entry point hands it."""
+    params = {"r": np.linspace(0.5, 2.0, 3).astype(np.float32)}
+    overrides = {"price": None, "hazard": None, "notice": None,
+                 "spot_scale": None}
+    key, out = jax.random.key(0), {}
+    for rng in ("split", "slab"):
+        for impl, mesh in (("xla", None), ("pallas", None), ("ref", None),
+                           ("pallas", lane_mesh())):
+            name = impl if mesh is None else f"sharded_{impl}"
+            out[f"market_call_{name}/{rng}"] = _digest(
+                engine._market_sweep_call.lower(
+                    job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN, 2,
+                    True, mesh, impl, rng, None, None, (3,), 2, params,
+                    market.host_params(), 12.0, overrides, key))
+    return out
+
+
+def _axes_digests(job, spot, kern, market, mkern, topo, rkern, params, mp,
+                  rp, k, key, pflat, kflat, keys, rkeys, mp_f, rp_f) -> dict:
+    """Every loop's sim, sweep and lane executors with telemetry, a
+    storm timeline and a work model on, on the slab stream."""
+    tel = Telemetry()
+    env = inject_storm(EnvTimeline.constant(), 50.0, 150.0)
+    work = WorkModel(total_work=2.0, restart_overhead=0.25, ckpt="notice",
+                     ckpt_time=0.05)
+    wk, mesh = work.params(), lane_mesh()
+    ax = lambda n: dict(tel=tel, ep=env.params(n), work=work, wk=wk)
+    n_m, n_r = market.n_pools, topo.n_regions
+    lanes = dict(executor="pallas", rng="slab")
+    return {
+        "sim+axes/slab": _digest(engine._run_sim_jit.lower(
+            job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, "slab", params, k,
+            key, **ax(1))),
+        "sweep+axes/slab": _digest(engine._run_sweep_jit.lower(
+            job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, "slab", pflat,
+            kflat, keys, **ax(1))),
+        "sweep_pallas+axes/slab": _digest(engine._run_sweep_pallas_jit.lower(
+            job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, 2, True, pflat,
+            kflat, rkeys, **lanes, **ax(1))),
+        "sweep_sharded_xla+axes/slab": _digest(
+            engine._run_sweep_sharded_jit.lower(
+                job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, 2, True, mesh,
+                pflat, kflat, rkeys, executor="xla", rng="slab", **ax(1))),
+        "market_sim+axes/slab": _digest(engine._run_market_sim_jit.lower(
+            job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN, "slab",
+            params, mp, k, key, **ax(n_m))),
+        "market_sweep+axes/slab": _digest(engine._run_market_sweep_jit.lower(
+            job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN, "slab",
+            pflat, mp_f, kflat, keys, **ax(n_m))),
+        "market_sweep_pallas+axes/slab": _digest(
+            engine._run_market_sweep_pallas_jit.lower(
+                job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN, 2,
+                True, pflat, mp_f, kflat, rkeys, **lanes, **ax(n_m))),
+        "region_sim+axes/slab": _digest(engine._run_region_sim_jit.lower(
+            topo, rkern, True, _N_EVENTS, _CHUNK, _BURN, "slab", params, rp,
+            k, key, **ax(n_r))),
+        "region_sweep+axes/slab": _digest(engine._run_region_sweep_jit.lower(
+            topo, rkern, True, _N_EVENTS, _CHUNK, _BURN, "slab", pflat, rp_f,
+            kflat, keys, **ax(n_r))),
+        "region_sweep_pallas+axes/slab": _digest(
+            engine._run_region_sweep_pallas_jit.lower(
+                topo, rkern, True, _N_EVENTS, _CHUNK, _BURN, 2, True, pflat,
+                rp_f, kflat, rkeys, **lanes, **ax(n_r))),
+    }
 
 
 def environment_tag() -> dict:

@@ -163,11 +163,17 @@ def test_constant_timeline_sweep_bitwise_off(rng):
 
 
 def test_env_off_lowering_frozen():
-    """env=None compiles the byte-identical program it did before the env
-    axis existed: sha256 of the lowered StableHLO for all 24 matrix cells
-    matches the frozen pre-env baseline.  Lowered in a fresh subprocess
-    with XLA_FLAGS scrubbed — other test modules override the host
-    device count in-process, which perturbs lowered text."""
+    """The engine compiles the byte-identical programs of the frozen
+    baseline: sha256 of the lowered StableHLO for every cell of
+    tests/_hlo_matrix.py — the 24 (loop × executor × rng) cells with
+    every optional axis off, the three ``shard="lanes"`` programs,
+    ``run_market_sweep``'s one jitted front, and each loop's executors
+    with the telemetry, env and work axes on.  The baseline was frozen
+    with tools/freeze_hlo_baseline.py from the engine of commit 2918bde
+    under jax 0.9.0 on the CPU backend (the one before it dated from
+    jax 0.4.37, so this test skipped under 0.9.0).  Lowered in a fresh
+    subprocess with XLA_FLAGS scrubbed — other test modules override the
+    host device count in-process, which perturbs lowered text."""
     baseline = json.loads(_BASELINE.read_text())
     here = Path(__file__).parent
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
@@ -184,7 +190,7 @@ def test_env_off_lowering_frozen():
     digests = fresh["digests"]
     assert digests.keys() == baseline["digests"].keys()
     moved = [k for k, v in digests.items() if baseline["digests"][k] != v]
-    assert not moved, f"env=None lowering changed for cells: {moved}"
+    assert not moved, f"lowering changed for cells: {moved}"
 
 
 # ---------------------------------------------------------------------------

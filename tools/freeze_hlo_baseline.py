@@ -1,12 +1,13 @@
-"""Freeze the env=None lowering digests to tests/data/hlo_pr6.json.
+"""Freeze the lowering digests of tests/_hlo_matrix.py to
+tests/data/hlo_pr6.json.
 
-    PYTHONPATH=src python tools/freeze_hlo_baseline.py
+    env -u XLA_FLAGS PYTHONPATH=src JAX_PLATFORMS=cpu \
+        python tools/freeze_hlo_baseline.py
 
-Run from a tree whose ``env=None`` program is the reference (the PR-6
-engine, or any tree whose env-off lowering is known-good); the frozen
-test tests/test_env.py::test_env_none_lowering_unchanged then pins every
-subsequent tree's env-off lowering against it byte-for-byte (same jax
-version + backend only — the digests are compiler-version specific).
+Run from a tree whose programs are the reference (an engine whose
+lowering is known-good); tests/test_env.py::test_env_off_lowering_frozen
+then pins every later tree's lowering against it byte for byte (same jax
+version and backend only — the digests are compiler-version specific).
 """
 from __future__ import annotations
 
