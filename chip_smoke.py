@@ -118,10 +118,17 @@ def check_xla_contract(name, pal, xla, seed_stat):
     print(f"  {name} pallas==xla every stat bitwise: {same}")
 
 
-def mosaic_compiled(tag: str) -> bool:
-    """Whether a lowered executor named ``tag`` holds a Mosaic kernel."""
+def ir_dumps() -> set:
+    """The lowered modules dumped so far."""
+    return set(IR_DIR.glob("*.mlir"))
+
+
+def mosaic_compiled(before: set) -> bool:
+    """Whether a program lowered since the dumps ``before`` holds a Mosaic
+    kernel: one jitted executor serves every loop (and the market's runs
+    inside its jitted front), so a phase looks at what it lowered."""
     return any("tpu_custom_call" in p.read_text()
-               for p in IR_DIR.glob(f"*{tag}*.mlir"))
+               for p in ir_dumps() - before)
 
 
 def single_queue_phases(key):
@@ -135,14 +142,14 @@ def single_queue_phases(key):
     kw = dict(k=K, n_events=SINGLE_EVENTS, key=key, n_seeds=N_SEEDS,
               rmax=RMAX, burn_in=BURN_IN, rng="slab")
     job, spot, kern = Exponential(LAM), Exponential(MU), ThreePhaseKernel()
-    outs = {}
+    before, outs = ir_dumps(), {}
     for impl, extra in (("xla", {"impl": "xla"}), ("pallas", PALLAS)):
         outs[impl] = run_phase(
             f"single_queue[{impl}] {rs.size * N_SEEDS} lanes x "
             f"{SINGLE_EVENTS} events",
             lambda: run_sweep(job, spot, kern, {"r": rs}, **extra, **kw),
             lane_events)
-    if not mosaic_compiled("_run_sweep_pallas_jit"):
+    if not mosaic_compiled(before):
         raise CheckFailed("single_queue: no tpu_custom_call in the pallas "
                           "executor's lowering")
     for impl, out in outs.items():
@@ -182,7 +189,7 @@ def market_phases(key):
     lane_events = rs.size * N_SEEDS * (LOOP_EVENTS + BURN_IN)
     kw = dict(k=K, n_events=LOOP_EVENTS, key=key, n_seeds=N_SEEDS,
               rmax=RMAX, burn_in=BURN_IN, rng="slab")
-    outs = {}
+    before, outs = ir_dumps(), {}
     for impl, extra in (("xla", {"impl": "xla"}), ("pallas", PALLAS)):
         outs[impl] = run_phase(
             f"market[{impl}] 4 pools, {rs.size * N_SEEDS} lanes x "
@@ -190,7 +197,7 @@ def market_phases(key):
             lambda: run_market_sweep(Exponential(LAM), market, kern,
                                      {"r": rs}, **extra, **kw),
             lane_events)
-    if not mosaic_compiled("_run_market_sweep_pallas_jit"):
+    if not mosaic_compiled(before):
         raise CheckFailed("market: no tpu_custom_call in the pallas "
                           "executor's lowering")
     for impl, out in outs.items():
@@ -229,14 +236,14 @@ def region_phases(key):
     lane_events = rs.size * N_SEEDS * (LOOP_EVENTS + BURN_IN)
     kw = dict(k=cfg["k"], n_events=LOOP_EVENTS, key=key, n_seeds=N_SEEDS,
               burn_in=BURN_IN, rng="slab")  # rmax: Region.rmax, 64
-    outs = {}
+    before, outs = ir_dumps(), {}
     for impl, extra in (("xla", {"impl": "xla"}), ("pallas", PALLAS)):
         outs[impl] = run_phase(
             f"regions[{impl}] 2 regions, {rs.size * N_SEEDS} lanes x "
             f"{LOOP_EVENTS} events",
             lambda: run_region_sweep(topo, kern, {"r": rs}, **extra,
                                      **kw), lane_events)
-    if not mosaic_compiled("_run_region_sweep_pallas_jit"):
+    if not mosaic_compiled(before):
         raise CheckFailed("regions: no tpu_custom_call in the pallas "
                           "executor's lowering")
     for impl, out in outs.items():
@@ -272,18 +279,19 @@ def sharded_phases(key, n_chips: int):
     kw = dict(k=K, n_events=LOOP_EVENTS, key=key, n_seeds=N_SEEDS,
               rmax=RMAX, burn_in=BURN_IN, rng="slab", **PALLAS)
     job, spot, kern = Exponential(LAM), Exponential(MU), ThreePhaseKernel()
+    before = ir_dumps()
     sharded = run_phase(
         f"single_queue[pallas] shard=lanes over {n_chips} chips, "
         f"{SHARD_LANES} lanes x {LOOP_EVENTS} events",
         lambda: run_sweep(job, spot, kern, {"r": rs}, shard="lanes",
                           mesh=lane_mesh(n_chips), **kw), lane_events)
+    if not mosaic_compiled(before):
+        raise CheckFailed("sharded: no tpu_custom_call in the sharded "
+                          "executor's lowering")
     one = run_phase(
         f"single_queue[pallas] unsharded on 1 chip, {SHARD_LANES} lanes "
         f"x {LOOP_EVENTS} events",
         lambda: run_sweep(job, spot, kern, {"r": rs}, **kw), lane_events)
-    if not mosaic_compiled("_run_sweep_sharded_jit"):
-        raise CheckFailed("sharded: no tpu_custom_call in the sharded "
-                          "executor's lowering")
     bad = [n for n in INT_STATS if n in one
            and not np.array_equal(np.asarray(one[n]), np.asarray(sharded[n]))]
     n_int = sum(n in one for n in INT_STATS)

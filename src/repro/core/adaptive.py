@@ -8,13 +8,13 @@ a projected gradient step on the slack penalty L(r) = ½(d(r) − δ)²:
 
 exactly as the paper's Algorithm 1 (the sign of ∂d/∂r is absorbed into η > 0
 since d(r) is increasing in r).  The event window is the engine's
-:func:`repro.core.engine.run_market_window`: since PR 2 the learner runs on
-the **spot-market subsystem** (heterogeneous pools, preemption with notice —
-:mod:`repro.core.market`), so fleets can be trained against revocation-prone
-multi-pool markets on-device.  A plain :class:`~repro.core.arrivals
-.ArrivalProcess` is wrapped as the degenerate one-pool market, which
-reproduces the PR-1 engine bit-for-bit — pre-market learner trajectories
-are unchanged.
+:func:`repro.core.engine.run_window` over the market loop: since PR 2 the
+learner runs on the **spot-market subsystem** (heterogeneous pools,
+preemption with notice — :mod:`repro.core.market`), so fleets can be
+trained against revocation-prone multi-pool markets on-device.  A plain
+:class:`~repro.core.arrivals.ArrivalProcess` is wrapped as the degenerate
+one-pool market, which reproduces the PR-1 engine bit-for-bit —
+pre-market learner trajectories are unchanged.
 
 :func:`adaptive_admission_control_batched` vmaps the whole learner over
 arrays of (δ, η, η-decay, r₀, r_max, k): a fleet of learners — e.g. one per
@@ -38,9 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.arrivals import ArrivalProcess
-from repro.core.engine import (_check_env, _env_params, _rebase_order,
-                               _rebase_order_env, init_market_state,
-                               run_market_window)
+from repro.core.engine import (_check_env, _env_params, _MarketLoop,
+                               _rebase_order, run_window)
 from repro.core.env import init_env_state
 from repro.core.market import NoticeAwareKernel, SpotMarket, as_market
 from repro.core.policies import ThreePhaseKernel
@@ -88,23 +87,20 @@ def _adaptive_core(job, market, kernel, rmax, window_events, n_windows,
     default off, compiling the identical pre-env program.
     """
     mp = market.params()
-    preempt_on = market.preemptible
-    state0 = init_market_state(key, job, market, rmax, mp, preempt_on,
-                               ep=ep)
+    loop = _MarketLoop(job, market, kernel, rmax, market.preemptible)
+    state0 = loop.init(key, mp, False, ep)
     if ep is not None:
         state0 = (state0, init_env_state(ep))
 
     def outer(sc, idx):
         state, r = sc
-        state, s = run_market_window(job, market, kernel, rmax, preempt_on,
-                                     state, {"r": r}, mp, k_cost,
-                                     window_events, ep=ep)
+        state, s = run_window(loop, state, {"r": r}, mp, k_cost,
+                              window_events, ep=ep)
         if ep is not None:
             s, es = s
         # learner horizons are unbounded (windows × events); rebase the
         # int32 join-sequence counters every window so they never wrap
-        state = _rebase_order(state) if ep is None else _rebase_order_env(
-            state)
+        state = _rebase_order(state)
         completed = jnp.maximum(s.jobs_completed, 1).astype(jnp.float32)
         d = s.delay_sum / completed
         c = s.cost_sum / completed

@@ -62,6 +62,20 @@ time-averaged and as the fraction of spot arrivals finding the queue empty
 
 Executors (``impl=``)
 ---------------------
+The executor layer is written once over a static **loop description**
+(:class:`_QueueLoop`, :class:`_MarketLoop`, :class:`_RegionLoop`: the
+single queue, the spot market, the regions), which supplies only what the
+three loops differ in — slab layout, per-lane state init, event step,
+window zeros, location and slot counts, and the per-lane config tree
+(``mp``/``rp``) riding beside ``params`` and ``k``.  Over it sit one
+window scanner (:func:`run_window`), one chunk plan (:func:`run_chunked`),
+the single-run scan (:func:`_run_sim_jit`), the nested-vmap sweep
+(:func:`_run_sweep_jit`), the flat-lane body (:func:`_sweep_lanes`) and
+one flat-lane jit for the kernel, its reference and ``shard="lanes"``
+(:func:`_run_lanes_jit`); every entry point runs one check routine
+(:func:`_prepare`) and one dispatch on ``impl``/``mesh``
+(:func:`_execute`).  The event bodies stay three.
+
 Every entry point dispatches between executors sharing the same traced
 event bodies: ``impl="xla"`` is the nested-vmap ``lax.scan`` program above;
 ``impl="pallas"`` hands the fleet to the batched-event kernel in
@@ -117,6 +131,7 @@ equal telemetry-off stats exactly; the summaries only gain new fields.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple, Protocol, runtime_checkable
 
@@ -522,6 +537,54 @@ def _engine_event(job: ArrivalProcess, spot: ArrivalProcess,
     return out_carry, out_stats
 
 
+@dataclasses.dataclass(frozen=True)
+class _QueueLoop:
+    """The single-queue loop as the executor layer sees it.
+
+    A loop description is static and hashable: it is one static argument
+    of every executor jit, and it supplies exactly what the three loops
+    differ in — the slab layout, the per-lane state init, the event step
+    over the lane-params dict, the window zeros and location count, the
+    slot count of the work structure, and the name (``cfg``) of the
+    per-lane config tree that rides beside ``params`` and ``k`` (none
+    here; ``"mp"`` for the market, ``"rp"`` for the regions).  That name
+    is a key of the lane-params dict, whose sorted keys fix the kernel's
+    operand order: renaming it changes the compiled program.
+    """
+
+    job: ArrivalProcess
+    spot: ArrivalProcess
+    kernel: PolicyKernel
+    rmax: int
+
+    cfg = None
+    n_locs = 1
+
+    @property
+    def slots(self) -> int:
+        return self.rmax
+
+    def layout(self) -> SlabLayout:
+        return build_slab_layout(self.kernel,
+                                 job_udim=process_udim(self.job),
+                                 spot_udim=process_udim(self.spot))
+
+    def init(self, key, cfg, slab: bool, ep) -> EngineState:
+        return init_engine_state(key, self.job, self.spot, self.rmax, ep=ep)
+
+    def zeros(self) -> WindowStats:
+        return WindowStats.zeros()
+
+    def event(self, layout, carry, stats, params, cfg, k_cost, **axes):
+        return _engine_event(self.job, self.spot, self.kernel, self.rmax,
+                             layout, carry, stats, params, k_cost, **axes)
+
+
+# ===========================================================================
+# The executor layer: one of each executor over a loop description
+# ===========================================================================
+
+
 def _rebase_order(state):
     """Rebase join sequence numbers to the oldest occupied slot.
 
@@ -532,41 +595,17 @@ def _rebase_order(state):
     ``next_seq`` itself when the queue is empty) at every window boundary
     keeps the counter below window-events + rmax forever.  The shift is
     uniform across occupied slots, so every order comparison — and therefore
-    every statistic — is bitwise unchanged; works on any state carrying
-    ``occ``/``order``/``next_seq`` (EngineState and MarketState).
+    every statistic — is bitwise unchanged; works on any loop's state, and
+    through any ``((state, env?), work?)`` nesting (the env cursor and the
+    work structure cross windows untouched).
     """
+    if not hasattr(state, "occ"):
+        return (_rebase_order(state[0]),) + tuple(state[1:])
     base = jnp.min(jnp.where(state.occ, state.order, state.next_seq))
     return state._replace(
         order=jnp.where(state.occ, state.order - base, 0),
         next_seq=state.next_seq - base,
     )
-
-
-def _rebase_order_env(state):
-    """:func:`_rebase_order` for an ``(engine-state, EnvState)`` pair —
-    the window-boundary epilogue when the env axis is on (the timeline
-    cursor crosses windows untouched)."""
-    base, env_c = state
-    return (_rebase_order(base), env_c)
-
-
-def _rebase_order_any(state):
-    """:func:`_rebase_order` through arbitrary ``((state, env?), work?)``
-    nesting — the window-boundary epilogue when the work axis is on (env
-    cursor and work structure cross windows untouched)."""
-    if hasattr(state, "occ"):
-        return _rebase_order(state)
-    return (_rebase_order_any(state[0]),) + tuple(state[1:])
-
-
-def _rebase_for(ep, work):
-    """Window-boundary rebase epilogue for the active (env, work) axes.
-
-    Returns the exact pre-work function objects when ``work`` is off, so
-    the ``work=None`` program is the identical jaxpr it always was."""
-    if work is not None:
-        return _rebase_order_any
-    return _rebase_order if ep is None else _rebase_order_env
 
 
 def _base_key_state(state):
@@ -584,96 +623,53 @@ def _replace_base_key(state, key):
     return (_replace_base_key(state[0], key),) + tuple(state[1:])
 
 
-def _scan_window(step, zeros, state, n_events: int):
+def _scan_window(step, zeros, state, n_events: int,
+                 layout: SlabLayout | None = None):
     """Scan ``step`` for ``n_events`` events from fresh window accumulators.
 
-    Generic over the (state, stats) pytree pair — the PR-1 single-spot loop
-    and the market loop share this scanner (and :func:`_scan_chunked`), so
-    the chunked float32-window numerics are identical across both paths.
+    Generic over the (state, stats) pytree pair, so every loop shares this
+    scanner (and :func:`_scan_chunked`) and the chunked float32-window
+    numerics are identical across them.  With a slab ``layout`` ONE
+    counter-based bits call generates the whole window's
+    ``(n_events, n_cols)`` uint32 slab, the scan consumes it row by row,
+    and the lane key (on the innermost engine state of any env/work
+    nesting) advances once per window, not per event.
+    :func:`repro.core.clocks.lane_slab_keys` walks the same key ladder, so
+    the batched-event executors consume bitwise-identical slab rows.
     """
+    slab = None
+    if layout is not None:
+        key, slab = window_slab(_base_key_state(state).key, n_events,
+                                layout.n_cols)
+        state = _replace_base_key(state, key)
 
-    def body(sc, _):
-        c, s = step(sc[0], sc[1])
-        return (c, s), None
+    def body(sc, x):
+        return step(*sc, x=x), None
 
-    (state, stats), _ = jax.lax.scan(body, (state, zeros), None,
+    (state, stats), _ = jax.lax.scan(body, (state, zeros), slab,
                                      length=n_events)
     return state, stats
 
 
 def _scan_chunked(step, zeros, state, n_events: int, chunk_events: int,
-                  rebase=_rebase_order):
+                  layout: SlabLayout | None = None):
     """Run exactly ``n_events`` events as stacked float32 chunk windows.
 
     Every window boundary rebases the join-sequence counters
-    (:func:`_rebase_order` — or :func:`_rebase_order_env` when the state
-    is an env pair) so int32 ``order``/``next_seq`` never wrap on long
-    horizons; the Pallas kernel path applies the same epilogue, so the
-    two impls carry bitwise-identical state between windows.
+    (:func:`_rebase_order`) so int32 ``order``/``next_seq`` never wrap on
+    long horizons; the batched-event executors apply the same epilogue, so
+    every executor carries bitwise-identical state between windows.
     """
     n_chunks, rem = divmod(n_events, chunk_events)
 
     def chunk(c, _):
-        c, s = _scan_window(step, zeros, c, chunk_events)
-        return rebase(c), s
+        c, s = _scan_window(step, zeros, c, chunk_events, layout)
+        return _rebase_order(c), s
 
     state, stats = jax.lax.scan(chunk, state, None, length=n_chunks)
     if rem:
-        state, tail = _scan_window(step, zeros, state, rem)
-        state = rebase(state)
-        stats = jax.tree.map(
-            lambda s, t: jnp.concatenate([s, t[None]]), stats,
-            jax.tree.map(jnp.asarray, tail),
-        )
-    return state, stats
-
-
-def _scan_window_slab(step, zeros, state, n_events: int, n_cols: int,
-                      paired: bool = False):
-    """Slab-stream window: ONE counter-based bits call generates the whole
-    window's ``(n_events, n_cols)`` uint32 slab, the event scan consumes it
-    row by row as ``xs``, and the lane key advances once per window (not
-    per event).  :func:`repro.core.clocks.lane_slab_keys` walks the same
-    key ladder, so the Pallas/ref executors consume bitwise-identical
-    slab rows.
-
-    ``paired`` flags a tuple-wrapped state — ``(engine, EnvState)`` when
-    the env axis is on, and/or an outermost ``(state, WorkState)`` when
-    the work axis is on (NamedTuples are tuples, so this cannot be
-    sniffed) — the slab ladder walks the innermost engine state's key
-    either way."""
-    if paired:
-        key, slab = window_slab(_base_key_state(state).key, n_events, n_cols)
-        state = _replace_base_key(state, key)
-    else:
-        key, slab = window_slab(state.key, n_events, n_cols)
-        state = state._replace(key=key)
-
-    def body(sc, x):
-        c, s = step(sc[0], sc[1], x)
-        return (c, s), None
-
-    (state, stats), _ = jax.lax.scan(body, (state, zeros), slab)
-    return state, stats
-
-
-def _scan_chunked_slab(step, zeros, state, n_events: int, chunk_events: int,
-                       n_cols: int, paired: bool = False,
-                       rebase=_rebase_order):
-    """Slab-stream twin of :func:`_scan_chunked` (same chunk plan, same
-    per-window order rebase)."""
-    n_chunks, rem = divmod(n_events, chunk_events)
-
-    def chunk(c, _):
-        c, s = _scan_window_slab(step, zeros, c, chunk_events, n_cols,
-                                 paired=paired)
-        return rebase(c), s
-
-    state, stats = jax.lax.scan(chunk, state, None, length=n_chunks)
-    if rem:
-        state, tail = _scan_window_slab(step, zeros, state, rem, n_cols,
-                                        paired=paired)
-        state = rebase(state)
+        state, tail = _scan_window(step, zeros, state, rem, layout)
+        state = _rebase_order(state)
         stats = jax.tree.map(
             lambda s, t: jnp.concatenate([s, t[None]]), stats,
             jax.tree.map(jnp.asarray, tail),
@@ -697,103 +693,86 @@ def _raw_keys(keys: jax.Array) -> jax.Array:
     return keys
 
 
-def _engine_layout(job: ArrivalProcess, spot: ArrivalProcess,
-                   kernel) -> SlabLayout:
-    """Slab column map for the single-queue loop (built at trace time)."""
-    return build_slab_layout(kernel, job_udim=process_udim(job),
-                             spot_udim=process_udim(spot))
-
-
-def _with_zeros(zeros, tel: Telemetry | None, n_locs: int,
-                env: bool = False, work: bool = False):
-    """Pair base window zeros with telemetry zeros when that axis is on,
-    then with shock-counter zeros when the env axis is on, then
+def _window_zeros(loop, tel: Telemetry | None, ep, work):
+    """The loop's window zeros, paired with telemetry zeros when that axis
+    is on, then with shock-counter zeros when the env axis is on, then
     (outermost) with survival-ledger zeros when the work axis is on."""
+    zeros = loop.zeros()
     if tel is not None:
-        zeros = (zeros, telemetry_zeros(tel, n_locs))
-    if env:
+        zeros = (zeros, telemetry_zeros(tel, loop.n_locs))
+    if ep is not None:
         zeros = (zeros, env_zeros())
-    if work:
+    if work is not None:
         zeros = (zeros, survival_zeros())
     return zeros
 
 
-def run_window(job: ArrivalProcess, spot: ArrivalProcess,
-               kernel: PolicyKernel, rmax: int, state: EngineState, params,
-               k_cost: jax.Array, n_events: int,
+def run_window(loop, state, params, cfg, k_cost: jax.Array, n_events: int,
                layout: SlabLayout | None = None,
                tel: Telemetry | None = None, ep: dict | None = None,
-               work: WorkModel | None = None, wk: dict | None = None
-               ) -> tuple[EngineState, WindowStats]:
-    """Run ``n_events`` merged events; return state + one window of sums."""
-    step = functools.partial(_engine_event, job, spot, kernel, rmax, layout,
-                             params=params, k_cost=k_cost, tel=tel, ep=ep,
-                             work=work, wk=wk)
-    zeros = _with_zeros(WindowStats.zeros(), tel, 1, env=ep is not None,
-                        work=work is not None)
-    if layout is None:
-        return _scan_window(lambda c, s: step(c, s), zeros, state, n_events)
-    return _scan_window_slab(lambda c, s, x: step(c, s, x=x), zeros, state,
-                             n_events, layout.n_cols,
-                             paired=(ep is not None) or (work is not None))
+               work: WorkModel | None = None, wk: dict | None = None):
+    """Run ``n_events`` merged events of ``loop`` (a loop description);
+    return state + one window of sums.  ``cfg`` is the loop's config tree
+    (``None`` for the single queue)."""
+    step = functools.partial(loop.event, layout, params=params, cfg=cfg,
+                             k_cost=k_cost, tel=tel, ep=ep, work=work, wk=wk)
+    return _scan_window(step, _window_zeros(loop, tel, ep, work), state,
+                        n_events, layout)
 
 
-def run_chunked(job: ArrivalProcess, spot: ArrivalProcess,
-                kernel: PolicyKernel, rmax: int, state: EngineState, params,
-                k_cost: jax.Array, n_events: int, chunk_events: int,
-                layout: SlabLayout | None = None,
+def run_chunked(loop, state, params, cfg, k_cost: jax.Array, n_events: int,
+                chunk_events: int, layout: SlabLayout | None = None,
                 tel: Telemetry | None = None, ep: dict | None = None,
-                work: WorkModel | None = None, wk: dict | None = None
-                ) -> tuple[EngineState, WindowStats]:
-    """Run exactly ``n_events`` events as stacked float32 chunk windows.
+                work: WorkModel | None = None, wk: dict | None = None):
+    """Run exactly ``n_events`` events of ``loop`` as stacked float32
+    chunk windows.
 
     Returns stats with a leading chunk axis; :func:`summarize` reduces it in
     float64 so long horizons do not hit float32 sum saturation.
     """
-    step = functools.partial(_engine_event, job, spot, kernel, rmax, layout,
-                             params=params, k_cost=k_cost, tel=tel, ep=ep,
-                             work=work, wk=wk)
-    zeros = _with_zeros(WindowStats.zeros(), tel, 1, env=ep is not None,
-                        work=work is not None)
-    rebase = _rebase_for(ep, work)
-    if layout is None:
-        return _scan_chunked(lambda c, s: step(c, s), zeros, state,
-                             n_events, chunk_events, rebase=rebase)
-    return _scan_chunked_slab(lambda c, s, x: step(c, s, x=x), zeros, state,
-                              n_events, chunk_events, layout.n_cols,
-                              paired=(ep is not None) or (work is not None),
-                              rebase=rebase)
+    step = functools.partial(loop.event, layout, params=params, cfg=cfg,
+                             k_cost=k_cost, tel=tel, ep=ep, work=work, wk=wk)
+    return _scan_chunked(step, _window_zeros(loop, tel, ep, work), state,
+                         n_events, chunk_events, layout)
+
+
+def _lane_run(loop, layout, key, params, cfg, k_cost, n_events: int,
+              chunk_events: int, burn_in: int, tel, ep, work, wk):
+    """One lane of the scan executor: its state from ``key`` (paired with
+    the env cursor and then the work structure when those axes are on),
+    the burn-in window, then the chunked run; ``(state, stats)``."""
+    state = loop.init(key, cfg, layout is not None, ep)
+    if ep is not None:
+        state = (state, init_env_state(ep))
+    if work is not None:
+        state = (state, init_work_state(loop.slots))
+    axes = dict(layout=layout, tel=tel, ep=ep, work=work, wk=wk)
+    if burn_in:
+        state, _ = run_window(loop, state, params, cfg, k_cost, burn_in,
+                              **axes)
+        state = _rebase_order(state)
+    return run_chunked(loop, state, params, cfg, k_cost, n_events,
+                       chunk_events, **axes)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("job", "spot", "kernel", "rmax", "n_events",
-                     "chunk_events", "burn_in", "rng", "tel", "work"),
+    static_argnames=("loop", "n_events", "chunk_events", "burn_in", "rng",
+                     "tel", "work"),
 )
-def _run_sim_jit(job, spot, kernel, rmax, n_events, chunk_events, burn_in,
-                 rng, params, k_cost, key, tel=None, ep=None, work=None,
-                 wk=None):
+def _run_sim_jit(loop, n_events, chunk_events, burn_in, rng, params, cfg,
+                 k_cost, key, tel=None, ep=None, work=None, wk=None):
     """Single-point entry, compiled once per static signature at module scope
     (the seed re-jitted its burn-in path on every call).
 
-    ``ep`` is traced (an env-params dict, or None — a leafless pytree, so
+    ``cfg`` is the loop's config tree (``None``, a leafless pytree, for
+    the single queue); ``ep`` is traced (an env-params dict, or None — so
     the ``env=None`` program is the same jaxpr as before the axis);
     ``work``/``wk`` are the static/traced halves of the work axis, with
     the same leafless-when-off property."""
-    layout = _engine_layout(job, spot, kernel) if rng == "slab" else None
-    state = init_engine_state(key, job, spot, rmax, ep=ep)
-    if ep is not None:
-        state = (state, init_env_state(ep))
-    if work is not None:
-        state = (state, init_work_state(rmax))
-    if burn_in:
-        state, _ = run_window(job, spot, kernel, rmax, state, params, k_cost,
-                              burn_in, layout=layout, tel=tel, ep=ep,
-                              work=work, wk=wk)
-        state = _rebase_for(ep, work)(state)
-    return run_chunked(job, spot, kernel, rmax, state, params, k_cost,
-                       n_events, chunk_events, layout=layout, tel=tel, ep=ep,
-                       work=work, wk=wk)
+    layout = loop.layout() if rng == "slab" else None
+    return _lane_run(loop, layout, key, params, cfg, k_cost, n_events,
+                     chunk_events, burn_in, tel, ep, work, wk)
 
 
 def _check_rng(rng: str) -> None:
@@ -898,15 +877,15 @@ def _check_finite_stats(s) -> None:
 def _flat_lane_args(params_trees, k_cost, keys):
     """Flatten a (grid × seeds) product to grid-major lanes (seed fastest).
 
-    The Pallas executor's lane layout: params/k repeat per seed, raw seed
-    keys tile per grid point — the kernel operates on materialized per-lane
-    state/params tiles.  The XLA executor deliberately does NOT share this
-    layout: its nested vmap with broadcast (``in_axes=None``) arguments
-    compiles ~2.5× faster on CPU than any materialized-lane variant (the
-    batching rules keep grid-constant operands symbolically unbatched).
-    Per-lane arithmetic is the same traced event body either way; see
-    EXPERIMENTS.md ("Engine kernel") for the ulp-level float caveat this
-    split implies on CPU.
+    The batched-event executors' lane layout: params/k repeat per seed, raw
+    seed keys tile per grid point — the kernel operates on materialized
+    per-lane state/params tiles.  The XLA executor deliberately does NOT
+    share this layout: its nested vmap with broadcast (``in_axes=None``)
+    arguments compiles ~2.5× faster on CPU than any materialized-lane
+    variant (the batching rules keep grid-constant operands symbolically
+    unbatched).  Per-lane arithmetic is the same traced event body either
+    way; see EXPERIMENTS.md ("Engine kernel") for the ulp-level float
+    caveat this split implies on CPU.
     """
     g, s = k_cost.shape[0], keys.shape[0]
     rep = lambda x: jnp.repeat(x, s, axis=0)
@@ -921,36 +900,24 @@ def _unflatten_lanes(stats, g: int, s: int):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("job", "spot", "kernel", "rmax", "n_events",
-                     "chunk_events", "burn_in", "rng", "tel", "work"),
+    static_argnames=("loop", "n_events", "chunk_events", "burn_in", "rng",
+                     "tel", "work"),
 )
-def _run_sweep_jit(job, spot, kernel, rmax, n_events, chunk_events, burn_in,
-                   rng, params, k_cost, keys, tel=None, ep=None, work=None,
-                   wk=None):
-    """(grid × seeds) fleet as one nested-vmap XLA program (broadcast
-    ``in_axes`` — see :func:`_flat_lane_args` for why not flat lanes).
-    ``ep``/``wk`` are closed over by ``one`` (grid-constant, so the nested
-    vmap keeps them symbolically unbatched)."""
-    layout = _engine_layout(job, spot, kernel) if rng == "slab" else None
+def _run_sweep_jit(loop, n_events, chunk_events, burn_in, rng, params, cfg,
+                   k_cost, keys, tel=None, ep=None, work=None, wk=None):
+    """(grid × config × seeds) fleet as one nested-vmap XLA program
+    (broadcast ``in_axes`` — see :func:`_flat_lane_args` for why not flat
+    lanes).  ``ep``/``wk`` are closed over by ``one`` (grid-constant, so
+    the nested vmap keeps them symbolically unbatched)."""
+    layout = loop.layout() if rng == "slab" else None
 
-    def one(p, kc, key):
-        state = init_engine_state(key, job, spot, rmax, ep=ep)
-        if ep is not None:
-            state = (state, init_env_state(ep))
-        if work is not None:
-            state = (state, init_work_state(rmax))
-        if burn_in:
-            state, _ = run_window(job, spot, kernel, rmax, state, p, kc,
-                                  burn_in, layout=layout, tel=tel, ep=ep,
-                                  work=work, wk=wk)
-            state = _rebase_for(ep, work)(state)
-        _, stats = run_chunked(job, spot, kernel, rmax, state, p, kc,
-                               n_events, chunk_events, layout=layout,
-                               tel=tel, ep=ep, work=work, wk=wk)
-        return stats
+    def one(p, c, kc, key):
+        return _lane_run(loop, layout, key, p, c, kc, n_events, chunk_events,
+                         burn_in, tel, ep, work, wk)[1]
 
-    per_seeds = jax.vmap(one, in_axes=(None, None, 0))
-    return jax.vmap(per_seeds, in_axes=(0, 0, None))(params, k_cost, keys)
+    per_seeds = jax.vmap(one, in_axes=(None, None, None, 0))
+    return jax.vmap(per_seeds, in_axes=(0, 0, 0, None))(params, cfg, k_cost,
+                                                        keys)
 
 
 def _lane_slabs(state0, plan, layout: SlabLayout, compiled: bool):
@@ -958,7 +925,7 @@ def _lane_slabs(state0, plan, layout: SlabLayout, compiled: bool):
     slab keys, (lanes, n_windows, 2) uint32, with the row width.  Walked
     OUTSIDE the kernel from each lane's initial key; window ``w``'s rows
     are bitwise the slab the scan executor draws
-    (:func:`_scan_window_slab`), hashed per event in-kernel and drawn per
+    (:func:`_scan_window`), hashed per event in-kernel and drawn per
     window by the reference.  A ``compiled`` kernel runs no typed-key
     PRNG, so a hook without a slab-aware ``*_u`` twin raises here."""
     if compiled and "key" in (layout.admit_mode, layout.on_preempt_mode,
@@ -986,77 +953,6 @@ def _env_lane_blocks(ep: dict, lanes: int):
     return ep_b, es0
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("job", "spot", "kernel", "rmax", "n_events",
-                     "chunk_events", "burn_in", "tile", "interpret",
-                     "executor", "rng", "tel", "work"),
-)
-def _run_sweep_pallas_jit(job, spot, kernel, rmax, n_events, chunk_events,
-                          burn_in, tile, interpret, params, k_cost, keys,
-                          executor="pallas", rng="split", tel=None, ep=None,
-                          work=None, wk=None):
-    """The (grid × seeds) fleet as ONE Pallas batched-event kernel call.
-
-    Lanes are grid-major (seed fastest; :func:`_flat_lane_args`); per-lane
-    arithmetic is the same traced :func:`_engine_event` the XLA executor
-    scans.  Burn-in runs as a leading window through the same kernel and
-    its stats row is dropped.  ``executor="ref"`` swaps the kernel for its
-    pure-JAX scan reference on the identical lane layout — the bit-for-bit
-    oracle the equivalence tests freeze the kernel against.
-    """
-    g, s = k_cost.shape[0], keys.shape[0]
-    (params_f,), k_f, keys_f = _flat_lane_args((params,), k_cost, keys)
-    params_b = {"params": params_f, "k": k_f}
-    state0 = jax.vmap(
-        lambda key: init_engine_state(key, job, spot, rmax, ep=ep))(keys_f)
-    plan = _window_plan(n_events, chunk_events, burn_in)
-
-    if rng == "slab":
-        layout = _engine_layout(job, spot, kernel)
-        slab = _lane_slabs(state0, plan, layout,
-                           compiled=executor == "pallas" and not interpret)
-    else:
-        layout, slab = None, None
-    if ep is not None:
-        # slabs above walk the bare engine key ladder; only now does the
-        # lane state become the (engine, env-cursor) pair
-        params_b["ep"], es0 = _env_lane_blocks(ep, keys_f.shape[0])
-        state0 = (state0, es0)
-    if work is not None:
-        # work params ride as per-lane VMEM blocks like ep; the work
-        # structure wraps outermost, after any env pairing
-        params_b["wk"] = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (keys_f.shape[0],)), wk)
-        state0 = (state0, init_work_state(rmax, keys_f.shape[0]))
-
-    if rng == "slab":
-        def step(carry, stats, p, x):
-            return _engine_event(job, spot, kernel, rmax, layout, carry,
-                                 stats, p["params"], p["k"], x=x, tel=tel,
-                                 ep=p.get("ep"), work=work, wk=p.get("wk"))
-    else:
-        def step(carry, stats, p):
-            return _engine_event(job, spot, kernel, rmax, None, carry,
-                                 stats, p["params"], p["k"], tel=tel,
-                                 ep=p.get("ep"), work=work, wk=p.get("wk"))
-
-    zeros = _with_zeros(WindowStats.zeros(), tel, 1, env=ep is not None,
-                        work=work is not None)
-    epilogue = _rebase_for(ep, work)
-    if executor == "ref":
-        _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, slab=slab,
-            epilogue=epilogue)
-    else:
-        _, stats = batched_events(
-            step, state0, params_b, zeros, plan, slab=slab,
-            tile=tile, interpret=interpret, epilogue=epilogue)
-    if burn_in:
-        stats = jax.tree.map(lambda x: x[:, 1:], stats)
-    return _unflatten_lanes(stats, g, s)
-
-
 def _check_shard(name: str, shard: str, mesh) -> None:
     """Actionable errors for the ``shard=`` axis (every sweep entry point)."""
     if shard not in ("none", "lanes"):
@@ -1078,78 +974,70 @@ def _pad_count(lanes: int, mesh) -> int:
     return -lanes % mesh.size
 
 
-def _sweep_lanes(job, spot, kernel, rmax, n_events, chunk_events, burn_in,
-                 tile, interpret, params_f, k_f, keys_f, *, executor, rng,
-                 tel=None, ep=None, work=None, wk=None):
-    """One shard's worth of flat lanes through the requested executor.
+def _sweep_lanes(loop, n_events, chunk_events, burn_in, tile, interpret,
+                 params_f, cfg_f, k_f, keys_f, *, executor, rng, tel=None,
+                 ep=None, work=None, wk=None):
+    """Flat lanes through the requested executor.
 
-    The per-shard body of the ``shard="lanes"`` dispatch: arguments are
-    already flat lane-leading (grid-major, seed fastest — the
+    Arguments are flat lane-leading (grid-major, seed fastest — the
     :func:`_flat_lane_args` layout; ``keys_f`` are raw uint32 key words),
-    and the returned stats leaves are ``(lanes, windows, ...)``.  The
-    ``"pallas"``/``"ref"`` branches mirror :func:`_run_sweep_pallas_jit`'s
-    body op-for-op, so per-lane trajectories are bitwise the unsharded
-    ones.  The ``"xla"`` branch runs the same per-lane program as
-    :func:`_run_sweep_jit`'s ``one`` but under a single flat vmap —
+    and the returned stats leaves are ``(lanes, windows, ...)``.
+    ``"pallas"`` runs the batched-event kernel: the loop's state laid out
+    lane-last as VMEM blocks, the loop's event step the vmap-ed kernel
+    body, burn-in a leading window whose stats row is dropped, and under
+    ``rng="slab"`` the slab keys an input block per window (no in-kernel
+    PRNG).  ``"ref"`` swaps the kernel for its pure-JAX scan reference on
+    the identical lane layout — the bit-for-bit oracle.  ``"xla"`` (the
+    per-shard body of ``shard="lanes"`` only) runs the same per-lane
+    program as :func:`_run_sweep_jit`'s ``one`` under a single flat vmap —
     materialized lanes instead of broadcast nesting, which keeps integer
     stats bitwise and float sums within ~ulp of the unsharded nested-vmap
     program (the PR-3 layout caveat; see :func:`_flat_lane_args`).
     """
-    layout = _engine_layout(job, spot, kernel) if rng == "slab" else None
+    layout = loop.layout() if rng == "slab" else None
     if executor == "xla":
-        def one(p, kc, key):
-            state = init_engine_state(key, job, spot, rmax, ep=ep)
-            if ep is not None:
-                state = (state, init_env_state(ep))
-            if work is not None:
-                state = (state, init_work_state(rmax))
-            if burn_in:
-                state, _ = run_window(job, spot, kernel, rmax, state, p, kc,
-                                      burn_in, layout=layout, tel=tel, ep=ep,
-                                      work=work, wk=wk)
-                state = _rebase_for(ep, work)(state)
-            _, stats = run_chunked(job, spot, kernel, rmax, state, p, kc,
-                                   n_events, chunk_events, layout=layout,
-                                   tel=tel, ep=ep, work=work, wk=wk)
-            return stats
+        def one(p, c, kc, key):
+            return _lane_run(loop, layout, key, p, c, kc, n_events,
+                             chunk_events, burn_in, tel, ep, work, wk)[1]
 
-        return jax.vmap(one)(params_f, k_f, keys_f)
+        return jax.vmap(one)(params_f, cfg_f, k_f, keys_f)
 
     params_b = {"params": params_f, "k": k_f}
+    if loop.cfg is not None:
+        params_b[loop.cfg] = cfg_f
     state0 = jax.vmap(
-        lambda key: init_engine_state(key, job, spot, rmax, ep=ep))(keys_f)
+        lambda key, c: loop.init(key, c, layout is not None, ep))(keys_f,
+                                                                  cfg_f)
     plan = _window_plan(n_events, chunk_events, burn_in)
     slab = None if layout is None else _lane_slabs(
         state0, plan, layout, compiled=executor == "pallas" and not interpret)
+    lanes = keys_f.shape[0]
     if ep is not None:
-        params_b["ep"], es0 = _env_lane_blocks(ep, keys_f.shape[0])
+        # slabs above walk the bare engine key ladder; only now does the
+        # lane state become the (engine, env-cursor) pair
+        params_b["ep"], es0 = _env_lane_blocks(ep, lanes)
         state0 = (state0, es0)
     if work is not None:
+        # work params ride as per-lane VMEM blocks like ep; the work
+        # structure wraps outermost, after any env pairing
         params_b["wk"] = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (keys_f.shape[0],)), wk)
-        state0 = (state0, init_work_state(rmax, keys_f.shape[0]))
+            lambda a: jnp.broadcast_to(a, (lanes,)), wk)
+        state0 = (state0, init_work_state(loop.slots, lanes))
 
-    if layout is not None:
-        def step(carry, stats, p, x):
-            return _engine_event(job, spot, kernel, rmax, layout, carry,
-                                 stats, p["params"], p["k"], x=x, tel=tel,
-                                 ep=p.get("ep"), work=work, wk=p.get("wk"))
-    else:
-        def step(carry, stats, p):
-            return _engine_event(job, spot, kernel, rmax, None, carry,
-                                 stats, p["params"], p["k"], tel=tel,
-                                 ep=p.get("ep"), work=work, wk=p.get("wk"))
+    def step(carry, stats, p, x=None):
+        return loop.event(layout, carry, stats, p["params"],
+                          p[loop.cfg] if loop.cfg else None, p["k"], x=x,
+                          tel=tel, ep=p.get("ep"), work=work, wk=p.get("wk"))
 
-    zeros = _with_zeros(WindowStats.zeros(), tel, 1, env=ep is not None,
-                        work=work is not None)
-    epilogue = _rebase_for(ep, work)
+    zeros = _window_zeros(loop, tel, ep, work)
     if executor == "ref":
         _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, slab=slab, epilogue=epilogue)
+            step, state0, params_b, zeros, plan, slab=slab,
+            epilogue=_rebase_order)
     else:
         _, stats = batched_events(
             step, state0, params_b, zeros, plan, slab=slab, tile=tile,
-            interpret=interpret, epilogue=epilogue)
+            interpret=interpret, epilogue=_rebase_order)
     if burn_in:
         stats = jax.tree.map(lambda x: x[:, 1:], stats)
     return stats
@@ -1157,45 +1045,108 @@ def _sweep_lanes(job, spot, kernel, rmax, n_events, chunk_events, burn_in,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("job", "spot", "kernel", "rmax", "n_events",
-                     "chunk_events", "burn_in", "tile", "interpret", "mesh",
-                     "executor", "rng", "tel", "work"),
+    static_argnames=("loop", "n_events", "chunk_events", "burn_in", "tile",
+                     "interpret", "mesh", "executor", "rng", "tel", "work"),
 )
-def _run_sweep_sharded_jit(job, spot, kernel, rmax, n_events, chunk_events,
-                           burn_in, tile, interpret, mesh, params, k_cost,
-                           keys, executor="xla", rng="split", tel=None,
-                           ep=None, work=None, wk=None):
-    """The (grid × seeds) fleet lane-partitioned across a 1-D device mesh.
+def _run_lanes_jit(loop, n_events, chunk_events, burn_in, tile, interpret,
+                   mesh, params, cfg, k_cost, keys, executor="pallas",
+                   rng="split", tel=None, ep=None, work=None, wk=None):
+    """The (grid × config × seeds) fleet as flat lanes: flatten
+    (:func:`_flat_lane_args`), :func:`_sweep_lanes`, unflatten.
 
-    Flatten to grid-major lanes, pad to a mesh-size multiple with copies
-    of lane 0 (:func:`repro.distributed.sharding.pad_lanes`), run
-    :func:`_sweep_lanes` per shard under ``shard_map`` (env tables ride
-    replicated), slice the pad lanes off, and unflatten.  No cross-lane
+    With no ``mesh`` the lanes go through the batched-event kernel
+    (``executor="pallas"``) or its reference (``"ref"``) in one call.
+    With a 1-D ``mesh`` the lanes are partitioned across it: padded to a
+    mesh-size multiple with copies of lane 0
+    (:func:`repro.distributed.sharding.pad_lanes`), run per shard under
+    ``shard_map`` through any executor (env and work tables ride
+    replicated), and the pad lanes sliced off.  No cross-lane
     communication exists in the event loop — lane keys are independent in
-    both rng streams — so each shard's trajectories are the unsharded
-    ones by construction; the host-side summaries then reduce int32
-    windows with integer addition (no float reduction-order hazard on the
+    both rng streams — so each shard's trajectories are the unsharded ones
+    by construction; the host-side summaries then reduce int32 windows
+    with integer addition (no float reduction-order hazard on the
     ledger's exact set).
     """
     g, s = k_cost.shape[0], keys.shape[0]
-    (params_f,), k_f, keys_f = _flat_lane_args((params,), k_cost, keys)
+    (params_f, cfg_f), k_f, keys_f = _flat_lane_args((params, cfg), k_cost,
+                                                     keys)
+    run = functools.partial(_sweep_lanes, loop, n_events, chunk_events,
+                            burn_in, tile, interpret, executor=executor,
+                            rng=rng, tel=tel, work=work)
+    if mesh is None:
+        return _unflatten_lanes(run(params_f, cfg_f, k_f, keys_f, ep=ep,
+                                    wk=wk), g, s)
     lanes = g * s
-    params_f, k_f, keys_f = pad_lanes((params_f, k_f, keys_f),
-                                      _pad_count(lanes, mesh))
+    params_f, cfg_f, k_f, keys_f = pad_lanes((params_f, cfg_f, k_f, keys_f),
+                                             _pad_count(lanes, mesh))
     spec, rspec = lane_spec(mesh), jax.sharding.PartitionSpec()
 
-    def local(pf, kf, keysf, ep_, wk_):
-        return _sweep_lanes(job, spot, kernel, rmax, n_events, chunk_events,
-                            burn_in, tile, interpret, pf, kf, keysf,
-                            executor=executor, rng=rng, tel=tel, ep=ep_,
-                            work=work, wk=wk_)
+    def local(pf, cf, kf, keysf, ep_, wk_):
+        return run(pf, cf, kf, keysf, ep=ep_, wk=wk_)
 
     stats = shard_map_1d(local, mesh=mesh,
-                         in_specs=(spec, spec, spec, rspec, rspec),
-                         out_specs=spec)(params_f, k_f, keys_f, ep, wk)
+                         in_specs=(spec, spec, spec, spec, rspec, rspec),
+                         out_specs=spec)(params_f, cfg_f, k_f, keys_f, ep, wk)
     if lanes != keys_f.shape[0]:
         stats = jax.tree.map(lambda x: x[:lanes], stats)
     return _unflatten_lanes(stats, g, s)
+
+
+def _prepare(name: str, kernel, impl: str, rng: str, interpret, telemetry,
+             env, work, n_events: int, burn_in: int, chunk_events,
+             n_locs: int, shard: str | None = None, mesh=None):
+    """Every entry point's argument checks, in one order, and what they
+    resolve: ``(interpret, ep, wk, chunk, mesh)``.  ``shard`` is given by
+    the sweep entry points only; ``shard="lanes"`` without a ``mesh``
+    takes :func:`repro.distributed.sharding.lane_mesh` over every local
+    device."""
+    _check_rng(rng)
+    _check_telemetry(telemetry)
+    _check_env(env)
+    _check_work(work, kernel)
+    if shard is not None:
+        _check_shard(name, shard, mesh)
+    _check_run_shape(name, n_events, burn_in)
+    interp = _resolve_interpret(name, impl, rng, interpret)
+    if shard == "lanes" and mesh is None:
+        mesh = lane_mesh()
+    chunk = n_events if chunk_events is None else min(chunk_events, n_events)
+    return (interp, _env_params(env, n_locs),
+            None if work is None else work.params(), chunk, mesh)
+
+
+def _execute(loop, impl: str, mesh, n_events, chunk_events, burn_in, tile,
+             interpret, rng, tel, work, params, cfg, k, keys, ep=None,
+             wk=None, *, one: bool = False):
+    """The one executor dispatch of every entry point, on ``impl`` and
+    ``mesh`` (also traced inside :func:`_market_sweep_call`).
+
+    A sweep hands flat grid-point ``params``/``cfg``/``k`` and ``keys``
+    (one per seed): ``"xla"`` without a mesh runs the nested-vmap scan
+    (:func:`_run_sweep_jit`); every other pairing runs flat lanes
+    (:func:`_run_lanes_jit`).  A single run (``one``) hands one point and
+    one key: ``"xla"`` scans it unbatched (:func:`_run_sim_jit`), the
+    batched-event executors run it as one lane.
+    """
+    if impl not in ("xla", "pallas", "ref"):
+        raise ValueError(
+            f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
+    axes = dict(tel=tel, ep=ep, work=work, wk=wk)
+    if impl == "xla" and one:
+        _, stats = _run_sim_jit(loop, n_events, chunk_events, burn_in, rng,
+                                params, cfg, jnp.float32(k), keys, **axes)
+        return stats
+    if impl == "xla" and mesh is None:
+        return _run_sweep_jit(loop, n_events, chunk_events, burn_in, rng,
+                              params, cfg, k, keys, **axes)
+    if one:
+        lane = lambda x: jnp.asarray(x)[None]
+        params, cfg = jax.tree.map(lane, params), jax.tree.map(lane, cfg)
+        k, keys = jnp.float32(k)[None], keys[None]
+    stats = _run_lanes_jit(loop, n_events, chunk_events, burn_in, tile,
+                           interpret, mesh, params, cfg, k, _raw_keys(keys),
+                           executor=impl, rng=rng, **axes)
+    return jax.tree.map(lambda x: x[0, 0], stats) if one else stats
 
 
 #: Statistics that count events (int32 window accumulators and their
@@ -1344,33 +1295,14 @@ def run_sim(
     """
     with EntrySpan(f"repro.run_sim[{impl}]") as call:
         params = {} if params is None else params
-        _check_rng(rng)
-        _check_telemetry(telemetry)
-        _check_env(env)
-        _check_work(work, kernel)
-        _check_run_shape("run_sim", n_events, burn_in)
-        interp = _resolve_interpret("run_sim", impl, rng, interpret)
-        ep = _env_params(env, 1)
-        wk = None if work is None else work.params()
-        chunk = (n_events if chunk_events is None
-                 else min(chunk_events, n_events))
+        interp, ep, wk, chunk, _ = _prepare(
+            "run_sim", kernel, impl, rng, interpret, telemetry, env, work,
+            n_events, burn_in, chunk_events, 1)
         call.phase("dispatch")
-        if impl in ("pallas", "ref"):
-            stats = _run_sweep_pallas_jit(
-                job, spot, kernel, rmax, n_events, chunk, burn_in, tile,
-                interp,
-                jax.tree.map(lambda x: jnp.asarray(x)[None], params),
-                jnp.float32(k)[None], _raw_keys(key)[None], executor=impl,
-                rng=rng, tel=telemetry, ep=ep, work=work, wk=wk)
-            stats = jax.tree.map(lambda x: x[0, 0], stats)
-        elif impl == "xla":
-            _, stats = _run_sim_jit(job, spot, kernel, rmax, n_events, chunk,
-                                    burn_in, rng, params, jnp.float32(k),
-                                    key, tel=telemetry, ep=ep, work=work,
-                                    wk=wk)
-        else:
-            raise ValueError(
-                f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
+        stats = _execute(_QueueLoop(job, spot, kernel, rmax), impl, None,
+                         n_events, chunk, burn_in, tile, interp, rng,
+                         telemetry, work, params, None, k, key, ep=ep, wk=wk,
+                         one=True)
         stats = call.to_host(stats)
         return {name: _scalar_or_array(v)
                 for name, v in summarize(stats, telemetry, env=env,
@@ -1437,15 +1369,9 @@ def run_sweep(
     """
     with EntrySpan(f"repro.run_sweep[{impl}]") as call:
         params = {} if params is None else params
-        _check_rng(rng)
-        _check_telemetry(telemetry)
-        _check_env(env)
-        _check_work(work, kernel)
-        _check_shard("run_sweep", shard, mesh)
-        _check_run_shape("run_sweep", n_events, burn_in)
-        interp = _resolve_interpret("run_sweep", impl, rng, interpret)
-        ep = _env_params(env, 1)
-        wk = None if work is None else work.params()
+        interp, ep, wk, chunk, mesh = _prepare(
+            "run_sweep", kernel, impl, rng, interpret, telemetry, env, work,
+            n_events, burn_in, chunk_events, 1, shard, mesh)
         params = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
         k = jnp.asarray(k, jnp.float32)
         grid_shape = jnp.broadcast_shapes(
@@ -1455,32 +1381,11 @@ def run_sweep(
         params_flat = jax.tree.map(flat, params)
         k_flat = flat(k)
         keys = jax.random.split(key, n_seeds)
-        chunk = (n_events if chunk_events is None
-                 else min(chunk_events, n_events))
         call.phase("dispatch")
-        if shard == "lanes":
-            if impl not in ("xla", "pallas", "ref"):
-                raise ValueError(
-                    f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
-            stats = _run_sweep_sharded_jit(
-                job, spot, kernel, rmax, n_events, chunk, burn_in, tile,
-                interp,
-                lane_mesh() if mesh is None else mesh, params_flat, k_flat,
-                _raw_keys(keys), executor=impl, rng=rng, tel=telemetry,
-                ep=ep, work=work, wk=wk)
-        elif impl in ("pallas", "ref"):
-            stats = _run_sweep_pallas_jit(
-                job, spot, kernel, rmax, n_events, chunk, burn_in, tile,
-                interp,
-                params_flat, k_flat, _raw_keys(keys), executor=impl,
-                rng=rng, tel=telemetry, ep=ep, work=work, wk=wk)
-        elif impl == "xla":
-            stats = _run_sweep_jit(job, spot, kernel, rmax, n_events, chunk,
-                                   burn_in, rng, params_flat, k_flat, keys,
-                                   tel=telemetry, ep=ep, work=work, wk=wk)
-        else:
-            raise ValueError(
-                f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
+        stats = _execute(_QueueLoop(job, spot, kernel, rmax), impl, mesh,
+                         n_events, chunk, burn_in, tile, interp, rng,
+                         telemetry, work, params_flat, None, k_flat, keys,
+                         ep=ep, wk=wk)
         stats = call.to_host(stats)
         # values shaped (grid_points, n_seeds)
         out = summarize(stats, telemetry, env=env, work=work)
@@ -2086,315 +1991,47 @@ def _market_event(job: ArrivalProcess, market: SpotMarket, kernel, rmax: int,
     return out_carry, out_stats
 
 
-def _market_layout(job: ArrivalProcess, market: SpotMarket, kernel,
-                   preempt_on: bool) -> SlabLayout:
-    """Slab column map for the market loop: the spot span is the max
-    ``u_dim`` across pools (all pools transform the same shared
-    uniforms)."""
-    return build_slab_layout(
-        kernel, job_udim=process_udim(job),
-        spot_udim=max(process_udim(p.arrival) for p in market.pools),
-        n=market.n_pools, preempt_on=preempt_on, market=True)
+@dataclasses.dataclass(frozen=True)
+class _MarketLoop:
+    """The market loop as the executor layer sees it (cf.
+    :class:`_QueueLoop`): its config tree is the pools' params, ``"mp"``,
+    a per-lane grid axis beside ``params`` and ``k``."""
 
+    job: ArrivalProcess
+    market: SpotMarket
+    kernel: object
+    rmax: int
+    preempt_on: bool
 
-def run_market_window(job: ArrivalProcess, market: SpotMarket, kernel,
-                      rmax: int, preempt_on: bool, state: MarketState,
-                      params, mp: dict, k_cost: jax.Array, n_events: int,
-                      layout: SlabLayout | None = None,
-                      tel: Telemetry | None = None, ep: dict | None = None,
-                      work: WorkModel | None = None, wk: dict | None = None
-                      ) -> tuple[MarketState, MarketWindowStats]:
-    """Run ``n_events`` merged market events; one window of float32 sums."""
-    step = functools.partial(_market_event, job, market, kernel, rmax,
-                             preempt_on, layout, params=params, mp=mp,
-                             k_cost=k_cost, tel=tel, ep=ep, work=work, wk=wk)
-    zeros = _with_zeros(MarketWindowStats.zeros(market.n_pools), tel,
-                        market.n_pools, env=ep is not None,
-                        work=work is not None)
-    if layout is None:
-        return _scan_window(step, zeros, state, n_events)
-    return _scan_window_slab(lambda c, s, x: step(c, s, x=x), zeros, state,
-                             n_events, layout.n_cols,
-                             paired=(ep is not None) or (work is not None))
+    cfg = "mp"
 
+    @property
+    def n_locs(self) -> int:
+        return self.market.n_pools
 
-def run_market_chunked(job: ArrivalProcess, market: SpotMarket, kernel,
-                       rmax: int, preempt_on: bool, state: MarketState,
-                       params, mp: dict, k_cost: jax.Array, n_events: int,
-                       chunk_events: int, layout: SlabLayout | None = None,
-                       tel: Telemetry | None = None, ep: dict | None = None,
-                       work: WorkModel | None = None, wk: dict | None = None
-                       ) -> tuple[MarketState, MarketWindowStats]:
-    step = functools.partial(_market_event, job, market, kernel, rmax,
-                             preempt_on, layout, params=params, mp=mp,
-                             k_cost=k_cost, tel=tel, ep=ep, work=work, wk=wk)
-    zeros = _with_zeros(MarketWindowStats.zeros(market.n_pools), tel,
-                        market.n_pools, env=ep is not None,
-                        work=work is not None)
-    rebase = _rebase_for(ep, work)
-    if layout is None:
-        return _scan_chunked(step, zeros, state, n_events, chunk_events,
-                             rebase=rebase)
-    return _scan_chunked_slab(lambda c, s, x: step(c, s, x=x), zeros, state,
-                              n_events, chunk_events, layout.n_cols,
-                              paired=(ep is not None) or (work is not None),
-                              rebase=rebase)
+    @property
+    def slots(self) -> int:
+        return self.rmax
 
+    def layout(self) -> SlabLayout:
+        """Slab column map: the spot span is the max ``u_dim`` across
+        pools (all pools transform the same shared uniforms)."""
+        return build_slab_layout(
+            self.kernel, job_udim=process_udim(self.job),
+            spot_udim=max(process_udim(p.arrival) for p in self.market.pools),
+            n=self.market.n_pools, preempt_on=self.preempt_on, market=True)
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("job", "market", "kernel", "rmax", "preempt_on",
-                     "n_events", "chunk_events", "burn_in", "rng", "tel",
-                     "work"),
-)
-def _run_market_sim_jit(job, market, kernel, rmax, preempt_on, n_events,
-                        chunk_events, burn_in, rng, params, mp, k_cost, key,
-                        tel=None, ep=None, work=None, wk=None):
-    layout = (_market_layout(job, market, kernel, preempt_on)
-              if rng == "slab" else None)
-    state = init_market_state(key, job, market, rmax, mp, preempt_on,
-                              scalar_preempt=layout is not None, ep=ep)
-    if ep is not None:
-        state = (state, init_env_state(ep))
-    if work is not None:
-        state = (state, init_work_state(rmax))
-    if burn_in:
-        state, _ = run_market_window(job, market, kernel, rmax, preempt_on,
-                                     state, params, mp, k_cost, burn_in,
-                                     layout=layout, tel=tel, ep=ep,
-                                     work=work, wk=wk)
-        state = _rebase_for(ep, work)(state)
-    return run_market_chunked(job, market, kernel, rmax, preempt_on, state,
-                              params, mp, k_cost, n_events, chunk_events,
-                              layout=layout, tel=tel, ep=ep, work=work,
-                              wk=wk)
+    def init(self, key, cfg, slab: bool, ep) -> MarketState:
+        return init_market_state(key, self.job, self.market, self.rmax, cfg,
+                                 self.preempt_on, scalar_preempt=slab, ep=ep)
 
+    def zeros(self) -> MarketWindowStats:
+        return MarketWindowStats.zeros(self.market.n_pools)
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("job", "market", "kernel", "rmax", "preempt_on",
-                     "n_events", "chunk_events", "burn_in", "rng", "tel",
-                     "work"),
-)
-def _run_market_sweep_jit(job, market, kernel, rmax, preempt_on, n_events,
-                          chunk_events, burn_in, rng, params, mp, k_cost,
-                          keys, tel=None, ep=None, work=None, wk=None):
-    """(grid × pools-config × seeds) fleet as one nested-vmap XLA program
-    (broadcast ``in_axes``; see :func:`_flat_lane_args`)."""
-    layout = (_market_layout(job, market, kernel, preempt_on)
-              if rng == "slab" else None)
-
-    def one(p, m, kc, key):
-        state = init_market_state(key, job, market, rmax, m, preempt_on,
-                                  scalar_preempt=layout is not None, ep=ep)
-        if ep is not None:
-            state = (state, init_env_state(ep))
-        if work is not None:
-            state = (state, init_work_state(rmax))
-        if burn_in:
-            state, _ = run_market_window(job, market, kernel, rmax,
-                                         preempt_on, state, p, m, kc,
-                                         burn_in, layout=layout, tel=tel,
-                                         ep=ep, work=work, wk=wk)
-            state = _rebase_for(ep, work)(state)
-        _, stats = run_market_chunked(job, market, kernel, rmax, preempt_on,
-                                      state, p, m, kc, n_events,
-                                      chunk_events, layout=layout, tel=tel,
-                                      ep=ep, work=work, wk=wk)
-        return stats
-
-    per_seeds = jax.vmap(one, in_axes=(None, None, None, 0))
-    return jax.vmap(per_seeds, in_axes=(0, 0, 0, None))(params, mp, k_cost,
-                                                        keys)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("job", "market", "kernel", "rmax", "preempt_on",
-                     "n_events", "chunk_events", "burn_in", "tile",
-                     "interpret", "executor", "rng", "tel", "work"),
-)
-def _run_market_sweep_pallas_jit(job, market, kernel, rmax, preempt_on,
-                                 n_events, chunk_events, burn_in, tile,
-                                 interpret, params, mp, k_cost, keys,
-                                 executor="pallas", rng="split", tel=None,
-                                 ep=None, work=None, wk=None):
-    """The market fleet through the same batched-event kernel family: the
-    per-pool ``next_spot``/``next_preempt`` clock vectors become
-    (tile, n_pools) VMEM blocks and :func:`_market_event` is the vmap-ed
-    kernel body — bit-for-bit the ``executor="ref"`` scan oracle; integer
-    stats bitwise / float sums to ~ulp vs :func:`_run_market_sweep_jit`
-    (see the module docstring).  Under ``rng="slab"`` the slab arrives as
-    a (tile, 1, window_events, n_cols) input block per window and the
-    kernel performs no RNG at all."""
-    g, s = k_cost.shape[0], keys.shape[0]
-    (params_f, mp_f), k_f, keys_f = _flat_lane_args((params, mp), k_cost,
-                                                    keys)
-    params_b = {"params": params_f, "mp": mp_f, "k": k_f}
-    layout = (_market_layout(job, market, kernel, preempt_on)
-              if rng == "slab" else None)
-    state0 = jax.vmap(
-        lambda key, m: init_market_state(
-            key, job, market, rmax, m, preempt_on,
-            scalar_preempt=layout is not None,
-            ep=ep))(keys_f, mp_f)
-    plan = _window_plan(n_events, chunk_events, burn_in)
-
-    if layout is not None:
-        slab = _lane_slabs(state0, plan, layout,
-                           compiled=executor == "pallas" and not interpret)
-    else:
-        slab = None
-    if ep is not None:
-        params_b["ep"], es0 = _env_lane_blocks(ep, keys_f.shape[0])
-        state0 = (state0, es0)
-    if work is not None:
-        params_b["wk"] = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (keys_f.shape[0],)), wk)
-        state0 = (state0, init_work_state(rmax, keys_f.shape[0]))
-
-    if layout is not None:
-        def step(carry, stats, p, x):
-            return _market_event(job, market, kernel, rmax, preempt_on,
-                                 layout, carry, stats, p["params"], p["mp"],
-                                 p["k"], x=x, tel=tel, ep=p.get("ep"),
-                                 work=work, wk=p.get("wk"))
-    else:
-        def step(carry, stats, p):
-            return _market_event(job, market, kernel, rmax, preempt_on,
-                                 None, carry, stats, p["params"], p["mp"],
-                                 p["k"], tel=tel, ep=p.get("ep"),
-                                 work=work, wk=p.get("wk"))
-
-    zeros = _with_zeros(MarketWindowStats.zeros(market.n_pools), tel,
-                        market.n_pools, env=ep is not None,
-                        work=work is not None)
-    epilogue = _rebase_for(ep, work)
-    if executor == "ref":
-        _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, slab=slab,
-            epilogue=epilogue)
-    else:
-        _, stats = batched_events(
-            step, state0, params_b, zeros, plan, slab=slab, tile=tile,
-            interpret=interpret, epilogue=epilogue)
-    if burn_in:
-        stats = jax.tree.map(lambda x: x[:, 1:], stats)
-    return _unflatten_lanes(stats, g, s)
-
-
-def _market_sweep_lanes(job, market, kernel, rmax, preempt_on, n_events,
-                        chunk_events, burn_in, tile, interpret, params_f,
-                        mp_f, k_f, keys_f, *, executor, rng, tel=None,
-                        ep=None, work=None, wk=None):
-    """One shard of flat market lanes through any executor (cf.
-    :func:`_sweep_lanes`; the pools-config tree ``mp_f`` is a per-lane
-    grid axis exactly as in :func:`_run_market_sweep_pallas_jit`)."""
-    layout = (_market_layout(job, market, kernel, preempt_on)
-              if rng == "slab" else None)
-    if executor == "xla":
-        def one(p, m, kc, key):
-            state = init_market_state(key, job, market, rmax, m, preempt_on,
-                                      scalar_preempt=layout is not None,
-                                      ep=ep)
-            if ep is not None:
-                state = (state, init_env_state(ep))
-            if work is not None:
-                state = (state, init_work_state(rmax))
-            if burn_in:
-                state, _ = run_market_window(job, market, kernel, rmax,
-                                             preempt_on, state, p, m, kc,
-                                             burn_in, layout=layout, tel=tel,
-                                             ep=ep, work=work, wk=wk)
-                state = _rebase_for(ep, work)(state)
-            _, stats = run_market_chunked(job, market, kernel, rmax,
-                                          preempt_on, state, p, m, kc,
-                                          n_events, chunk_events,
-                                          layout=layout, tel=tel, ep=ep,
-                                          work=work, wk=wk)
-            return stats
-
-        return jax.vmap(one)(params_f, mp_f, k_f, keys_f)
-
-    params_b = {"params": params_f, "mp": mp_f, "k": k_f}
-    state0 = jax.vmap(
-        lambda key, m: init_market_state(
-            key, job, market, rmax, m, preempt_on,
-            scalar_preempt=layout is not None, ep=ep))(keys_f, mp_f)
-    plan = _window_plan(n_events, chunk_events, burn_in)
-    slab = None if layout is None else _lane_slabs(
-        state0, plan, layout, compiled=executor == "pallas" and not interpret)
-    if ep is not None:
-        params_b["ep"], es0 = _env_lane_blocks(ep, keys_f.shape[0])
-        state0 = (state0, es0)
-    if work is not None:
-        params_b["wk"] = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (keys_f.shape[0],)), wk)
-        state0 = (state0, init_work_state(rmax, keys_f.shape[0]))
-
-    if layout is not None:
-        def step(carry, stats, p, x):
-            return _market_event(job, market, kernel, rmax, preempt_on,
-                                 layout, carry, stats, p["params"], p["mp"],
-                                 p["k"], x=x, tel=tel, ep=p.get("ep"),
-                                 work=work, wk=p.get("wk"))
-    else:
-        def step(carry, stats, p):
-            return _market_event(job, market, kernel, rmax, preempt_on,
-                                 None, carry, stats, p["params"], p["mp"],
-                                 p["k"], tel=tel, ep=p.get("ep"),
-                                 work=work, wk=p.get("wk"))
-
-    zeros = _with_zeros(MarketWindowStats.zeros(market.n_pools), tel,
-                        market.n_pools, env=ep is not None,
-                        work=work is not None)
-    epilogue = _rebase_for(ep, work)
-    if executor == "ref":
-        _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, slab=slab, epilogue=epilogue)
-    else:
-        _, stats = batched_events(
-            step, state0, params_b, zeros, plan, slab=slab, tile=tile,
-            interpret=interpret, epilogue=epilogue)
-    if burn_in:
-        stats = jax.tree.map(lambda x: x[:, 1:], stats)
-    return stats
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("job", "market", "kernel", "rmax", "preempt_on",
-                     "n_events", "chunk_events", "burn_in", "tile",
-                     "interpret", "mesh", "executor", "rng", "tel", "work"),
-)
-def _run_market_sweep_sharded_jit(job, market, kernel, rmax, preempt_on,
-                                  n_events, chunk_events, burn_in, tile,
-                                  interpret, mesh, params, mp, k_cost, keys,
-                                  executor="xla", rng="split", tel=None,
-                                  ep=None, work=None, wk=None):
-    """The market fleet lane-partitioned across a 1-D device mesh (cf.
-    :func:`_run_sweep_sharded_jit`)."""
-    g, s = k_cost.shape[0], keys.shape[0]
-    (params_f, mp_f), k_f, keys_f = _flat_lane_args((params, mp), k_cost,
-                                                    keys)
-    lanes = g * s
-    params_f, mp_f, k_f, keys_f = pad_lanes((params_f, mp_f, k_f, keys_f),
-                                            _pad_count(lanes, mesh))
-    spec, rspec = lane_spec(mesh), jax.sharding.PartitionSpec()
-
-    def local(pf, mf, kf, keysf, ep_, wk_):
-        return _market_sweep_lanes(job, market, kernel, rmax, preempt_on,
-                                   n_events, chunk_events, burn_in, tile,
-                                   interpret, pf, mf, kf, keysf,
-                                   executor=executor, rng=rng, tel=tel,
-                                   ep=ep_, work=work, wk=wk_)
-
-    stats = shard_map_1d(local, mesh=mesh,
-                         in_specs=(spec, spec, spec, spec, rspec, rspec),
-                         out_specs=spec)(params_f, mp_f, k_f, keys_f, ep, wk)
-    if lanes != keys_f.shape[0]:
-        stats = jax.tree.map(lambda x: x[:lanes], stats)
-    return _unflatten_lanes(stats, g, s)
+    def event(self, layout, carry, stats, params, cfg, k_cost, **axes):
+        return _market_event(self.job, self.market, self.kernel, self.rmax,
+                             self.preempt_on, layout, carry, stats, params,
+                             cfg, k_cost, **axes)
 
 
 def summarize_market(stats: MarketWindowStats,
@@ -2486,20 +2123,19 @@ def _broadcast_config_params(n: int, cfg: dict, overrides: dict,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("job", "market", "kernel", "rmax", "preempt_on",
-                     "n_events", "chunk_events", "burn_in", "tile",
+    static_argnames=("loop", "n_events", "chunk_events", "burn_in", "tile",
                      "interpret", "mesh", "impl", "rng", "tel", "work",
                      "grid_shape", "n_seeds"),
 )
-def _market_sweep_call(job, market, kernel, rmax, preempt_on, n_events,
-                       chunk_events, burn_in, tile, interpret, mesh, impl,
-                       rng, tel, work, grid_shape, n_seeds, params, mp, k,
-                       overrides, key, ep=None, wk=None):
+def _market_sweep_call(loop, n_events, chunk_events, burn_in, tile,
+                       interpret, mesh, impl, rng, tel, work, grid_shape,
+                       n_seeds, params, mp, k, overrides, key, ep=None,
+                       wk=None):
     """:func:`run_market_sweep`'s argument prep and executor as ONE
     program, so an answer is one dispatch: the float32 casts, the grid
     broadcast and flattening, the pools-config overrides and the seed-key
     split run under the trace, then the executor that ``impl`` and
-    ``mesh`` select (jitted itself, so inlined).
+    ``mesh`` select (:func:`_execute`; jitted itself, so inlined).
 
     ``mp`` (:meth:`SpotMarket.host_params`) enters as an operand, never
     as a constant: XLA rewrites a division by a constant into a
@@ -2509,25 +2145,12 @@ def _market_sweep_call(job, market, kernel, rmax, preempt_on, n_events,
                                       grid_shape).reshape(-1)
     params_flat = jax.tree.map(flat, params)
     k_flat = flat(k)
-    mp_flat = _broadcast_config_params(market.n_pools, mp, overrides,
+    mp_flat = _broadcast_config_params(loop.n_locs, mp, overrides,
                                        grid_shape)
     keys = jax.random.split(key, n_seeds)
-    if mesh is not None:
-        return _run_market_sweep_sharded_jit(
-            job, market, kernel, rmax, preempt_on, n_events, chunk_events,
-            burn_in, tile, interpret, mesh, params_flat, mp_flat, k_flat,
-            _raw_keys(keys), executor=impl, rng=rng, tel=tel, ep=ep,
-            work=work, wk=wk)
-    if impl == "xla":
-        return _run_market_sweep_jit(
-            job, market, kernel, rmax, preempt_on, n_events, chunk_events,
-            burn_in, rng, params_flat, mp_flat, k_flat, keys, tel=tel, ep=ep,
-            work=work, wk=wk)
-    return _run_market_sweep_pallas_jit(
-        job, market, kernel, rmax, preempt_on, n_events, chunk_events,
-        burn_in, tile, interpret, params_flat, mp_flat, k_flat,
-        _raw_keys(keys), executor=impl, rng=rng, tel=tel, ep=ep, work=work,
-        wk=wk)
+    return _execute(loop, impl, mesh, n_events, chunk_events, burn_in, tile,
+                    interpret, rng, tel, work, params_flat, mp_flat, k_flat,
+                    keys, ep=ep, wk=wk)
 
 
 def run_market_sim(
@@ -2564,38 +2187,14 @@ def run_market_sim(
     with EntrySpan(f"repro.run_market_sim[{impl}]") as call:
         market = as_market(market)
         params = {} if params is None else params
-        _check_rng(rng)
-        _check_telemetry(telemetry)
-        _check_env(env)
-        _check_work(work, kernel)
-        _check_run_shape("run_market_sim", n_events, burn_in)
-        interp = _resolve_interpret("run_market_sim", impl, rng, interpret)
-        mp = market.params()
-        ep = _env_params(env, market.n_pools)
-        wk = None if work is None else work.params()
-        chunk = (n_events if chunk_events is None
-                 else min(chunk_events, n_events))
+        interp, ep, wk, chunk, _ = _prepare(
+            "run_market_sim", kernel, impl, rng, interpret, telemetry, env,
+            work, n_events, burn_in, chunk_events, market.n_pools)
+        loop = _MarketLoop(job, market, kernel, rmax, market.preemptible)
         call.phase("dispatch")
-        if impl in ("pallas", "ref"):
-            stats = _run_market_sweep_pallas_jit(
-                job, market, kernel, rmax, market.preemptible, n_events,
-                chunk, burn_in, tile,
-                interp,
-                jax.tree.map(lambda x: jnp.asarray(x)[None], params),
-                jax.tree.map(lambda x: jnp.asarray(x)[None], mp),
-                jnp.float32(k)[None], _raw_keys(key)[None], executor=impl,
-                rng=rng, tel=telemetry, ep=ep, work=work, wk=wk)
-            stats = jax.tree.map(lambda x: x[0, 0], stats)
-        elif impl == "xla":
-            _, stats = _run_market_sim_jit(job, market, kernel, rmax,
-                                           market.preemptible, n_events,
-                                           chunk, burn_in, rng, params, mp,
-                                           jnp.float32(k), key,
-                                           tel=telemetry, ep=ep, work=work,
-                                           wk=wk)
-        else:
-            raise ValueError(
-                f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
+        stats = _execute(loop, impl, None, n_events, chunk, burn_in, tile,
+                         interp, rng, telemetry, work, params,
+                         market.params(), k, key, ep=ep, wk=wk, one=True)
         stats = call.to_host(stats)
         return {name: _scalar_or_array(v)
                 for name, v in summarize_market(stats, telemetry, env=env,
@@ -2654,21 +2253,12 @@ def run_market_sweep(
         market = as_market(market)
         n = market.n_pools
         params = {} if params is None else params
-        _check_rng(rng)
-        _check_telemetry(telemetry)
-        _check_env(env)
-        _check_work(work, kernel)
-        _check_shard("run_market_sweep", shard, mesh)
-        _check_run_shape("run_market_sweep", n_events, burn_in)
-        interp = _resolve_interpret("run_market_sweep", impl, rng, interpret)
+        interp, ep, wk, chunk, mesh = _prepare(
+            "run_market_sweep", kernel, impl, rng, interpret, telemetry, env,
+            work, n_events, burn_in, chunk_events, n, shard, mesh)
         _check_loc_overrides("run_market_sweep", n, "pool", prices=prices,
                              hazards=hazards, notices=notices,
                              spot_scales=spot_scales)
-        if impl not in ("xla", "pallas", "ref"):
-            raise ValueError(
-                f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
-        ep = _env_params(env, n)
-        wk = None if work is None else work.params()
         overrides = {"price": prices, "hazard": hazards, "notice": notices,
                      "spot_scale": spot_scales}
         # shapes only: np.shape builds no device array
@@ -2677,17 +2267,13 @@ def run_market_sweep(
         grid_shape = np.broadcast_shapes(
             np.shape(k), *(np.shape(x) for x in jax.tree.leaves(params)),
             *override_shapes)
-        preempt_on = market.preemptible or hazards is not None
-        chunk = (n_events if chunk_events is None
-                 else min(chunk_events, n_events))
-        if shard == "lanes" and mesh is None:
-            mesh = lane_mesh()
+        loop = _MarketLoop(job, market, kernel, rmax,
+                           market.preemptible or hazards is not None)
         call.phase("dispatch")
         stats = _market_sweep_call(
-            job, market, kernel, rmax, preempt_on, n_events, chunk, burn_in,
-            tile, interp, mesh, impl, rng, telemetry, work, grid_shape,
-            n_seeds, params, market.host_params(), k, overrides, key, ep=ep,
-            wk=wk)
+            loop, n_events, chunk, burn_in, tile, interp, mesh, impl, rng,
+            telemetry, work, grid_shape, n_seeds, params,
+            market.host_params(), k, overrides, key, ep=ep, wk=wk)
         stats = call.to_host(stats)
         out = summarize_market(stats, telemetry, env=env, work=work)
         return _reshape_sweep(out, grid_shape, n_seeds)
@@ -3322,311 +2908,46 @@ def _region_event(topo: RegionTopology, kernel, preempt_on: bool,
     return out_carry, out_stats
 
 
-def _region_layout(topo: RegionTopology, kernel,
-                   preempt_on: bool) -> SlabLayout:
-    """Slab column map for the region loop: job/spot spans are the max
-    ``u_dim`` across regions (shared uniforms, see
-    :func:`_slab_spot_clocks`)."""
-    return build_slab_layout(
-        kernel, job_udim=max(process_udim(r.job) for r in topo.regions),
-        spot_udim=max(process_udim(r.spot) for r in topo.regions),
-        n=topo.n_regions, preempt_on=preempt_on,
-        has_route=hasattr(kernel, "route"), market=True)
+@dataclasses.dataclass(frozen=True)
+class _RegionLoop:
+    """The region loop as the executor layer sees it (cf.
+    :class:`_QueueLoop`): its config tree is the regions' params,
+    ``"rp"``; its slots are the packed per-region partitions."""
 
+    topo: RegionTopology
+    kernel: object
+    preempt_on: bool
 
-def run_region_window(topo: RegionTopology, kernel, preempt_on: bool,
-                      state: RegionState, params, rp: dict,
-                      k_cost: jax.Array, n_events: int,
-                      layout: SlabLayout | None = None,
-                      tel: Telemetry | None = None, ep: dict | None = None,
-                      work: WorkModel | None = None, wk: dict | None = None
-                      ) -> tuple[RegionState, RegionWindowStats]:
-    """Run ``n_events`` merged region events; one window of float32 sums."""
-    step = functools.partial(_region_event, topo, kernel, preempt_on, layout,
-                             params=params, rp=rp, k_cost=k_cost, tel=tel,
-                             ep=ep, work=work, wk=wk)
-    zeros = _with_zeros(RegionWindowStats.zeros(topo.n_regions), tel,
-                        topo.n_regions, env=ep is not None,
-                        work=work is not None)
-    if layout is None:
-        return _scan_window(step, zeros, state, n_events)
-    return _scan_window_slab(lambda c, s, x: step(c, s, x=x), zeros, state,
-                             n_events, layout.n_cols,
-                             paired=(ep is not None) or (work is not None))
+    cfg = "rp"
 
+    @property
+    def n_locs(self) -> int:
+        return self.topo.n_regions
 
-def run_region_chunked(topo: RegionTopology, kernel, preempt_on: bool,
-                       state: RegionState, params, rp: dict,
-                       k_cost: jax.Array, n_events: int, chunk_events: int,
-                       layout: SlabLayout | None = None,
-                       tel: Telemetry | None = None, ep: dict | None = None,
-                       work: WorkModel | None = None, wk: dict | None = None
-                       ) -> tuple[RegionState, RegionWindowStats]:
-    step = functools.partial(_region_event, topo, kernel, preempt_on, layout,
-                             params=params, rp=rp, k_cost=k_cost, tel=tel,
-                             ep=ep, work=work, wk=wk)
-    zeros = _with_zeros(RegionWindowStats.zeros(topo.n_regions), tel,
-                        topo.n_regions, env=ep is not None,
-                        work=work is not None)
-    rebase = _rebase_for(ep, work)
-    if layout is None:
-        return _scan_chunked(step, zeros, state, n_events, chunk_events,
-                             rebase=rebase)
-    return _scan_chunked_slab(lambda c, s, x: step(c, s, x=x), zeros, state,
-                              n_events, chunk_events, layout.n_cols,
-                              paired=(ep is not None) or (work is not None),
-                              rebase=rebase)
+    @property
+    def slots(self) -> int:
+        return self.topo.total_slots
 
+    def layout(self) -> SlabLayout:
+        """Slab column map: job/spot spans are the max ``u_dim`` across
+        regions (shared uniforms, see :func:`_slab_spot_clocks`)."""
+        regions = self.topo.regions
+        return build_slab_layout(
+            self.kernel, job_udim=max(process_udim(r.job) for r in regions),
+            spot_udim=max(process_udim(r.spot) for r in regions),
+            n=self.topo.n_regions, preempt_on=self.preempt_on,
+            has_route=hasattr(self.kernel, "route"), market=True)
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("topo", "kernel", "preempt_on", "n_events",
-                     "chunk_events", "burn_in", "rng", "tel", "work"),
-)
-def _run_region_sim_jit(topo, kernel, preempt_on, n_events, chunk_events,
-                        burn_in, rng, params, rp, k_cost, key, tel=None,
-                        ep=None, work=None, wk=None):
-    layout = (_region_layout(topo, kernel, preempt_on)
-              if rng == "slab" else None)
-    state = init_region_state(key, topo, rp, preempt_on,
-                              scalar_preempt=layout is not None, ep=ep)
-    if ep is not None:
-        state = (state, init_env_state(ep))
-    if work is not None:
-        state = (state, init_work_state(topo.total_slots))
-    if burn_in:
-        state, _ = run_region_window(topo, kernel, preempt_on, state, params,
-                                     rp, k_cost, burn_in, layout=layout,
-                                     tel=tel, ep=ep, work=work, wk=wk)
-        state = _rebase_for(ep, work)(state)
-    return run_region_chunked(topo, kernel, preempt_on, state, params, rp,
-                              k_cost, n_events, chunk_events, layout=layout,
-                              tel=tel, ep=ep, work=work, wk=wk)
+    def init(self, key, cfg, slab: bool, ep) -> RegionState:
+        return init_region_state(key, self.topo, cfg, self.preempt_on,
+                                 scalar_preempt=slab, ep=ep)
 
+    def zeros(self) -> RegionWindowStats:
+        return RegionWindowStats.zeros(self.topo.n_regions)
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("topo", "kernel", "preempt_on", "n_events",
-                     "chunk_events", "burn_in", "rng", "tel", "work"),
-)
-def _run_region_sweep_jit(topo, kernel, preempt_on, n_events, chunk_events,
-                          burn_in, rng, params, rp, k_cost, keys, tel=None,
-                          ep=None, work=None, wk=None):
-    """(grid × regions-config × seeds) fleet as one nested-vmap XLA program
-    (broadcast ``in_axes``; see :func:`_flat_lane_args`)."""
-    layout = (_region_layout(topo, kernel, preempt_on)
-              if rng == "slab" else None)
-
-    def one(p, r, kc, key):
-        state = init_region_state(key, topo, r, preempt_on,
-                                  scalar_preempt=layout is not None, ep=ep)
-        if ep is not None:
-            state = (state, init_env_state(ep))
-        if work is not None:
-            state = (state, init_work_state(topo.total_slots))
-        if burn_in:
-            state, _ = run_region_window(topo, kernel, preempt_on, state, p,
-                                         r, kc, burn_in, layout=layout,
-                                         tel=tel, ep=ep, work=work, wk=wk)
-            state = _rebase_for(ep, work)(state)
-        _, stats = run_region_chunked(topo, kernel, preempt_on, state, p, r,
-                                      kc, n_events, chunk_events,
-                                      layout=layout, tel=tel, ep=ep,
-                                      work=work, wk=wk)
-        return stats
-
-    per_seeds = jax.vmap(one, in_axes=(None, None, None, 0))
-    return jax.vmap(per_seeds, in_axes=(0, 0, 0, None))(params, rp, k_cost,
-                                                        keys)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("topo", "kernel", "preempt_on", "n_events",
-                     "chunk_events", "burn_in", "tile", "interpret",
-                     "executor", "rng", "tel", "work"),
-)
-def _run_region_sweep_pallas_jit(topo, kernel, preempt_on, n_events,
-                                 chunk_events, burn_in, tile, interpret,
-                                 params, rp, k_cost, keys,
-                                 executor="pallas", rng="split", tel=None,
-                                 ep=None, work=None, wk=None):
-    """The region fleet through the same batched-event kernel family: the
-    engine-state blocks grow a region axis — (tile, R) clock vectors,
-    (tile, sum rmax_r) packed slot arrays — and :func:`_region_event` is
-    the vmap-ed kernel body.  Bit-for-bit the ``executor="ref"`` scan
-    oracle; integer stats bitwise / float sums to ~ulp vs
-    :func:`_run_region_sweep_jit` (see the module docstring).  Under
-    ``rng="slab"`` the slab is a per-window input block and the kernel
-    performs no RNG at all."""
-    g, s = k_cost.shape[0], keys.shape[0]
-    (params_f, rp_f), k_f, keys_f = _flat_lane_args((params, rp), k_cost,
-                                                    keys)
-    params_b = {"params": params_f, "rp": rp_f, "k": k_f}
-    layout = (_region_layout(topo, kernel, preempt_on)
-              if rng == "slab" else None)
-    state0 = jax.vmap(
-        lambda key, r: init_region_state(
-            key, topo, r, preempt_on,
-            scalar_preempt=layout is not None, ep=ep))(keys_f, rp_f)
-    plan = _window_plan(n_events, chunk_events, burn_in)
-
-    if layout is not None:
-        slab = _lane_slabs(state0, plan, layout,
-                           compiled=executor == "pallas" and not interpret)
-    else:
-        slab = None
-    if ep is not None:
-        params_b["ep"], es0 = _env_lane_blocks(ep, keys_f.shape[0])
-        state0 = (state0, es0)
-    if work is not None:
-        params_b["wk"] = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (keys_f.shape[0],)), wk)
-        state0 = (state0, init_work_state(topo.total_slots,
-                                          keys_f.shape[0]))
-
-    if layout is not None:
-        def step(carry, stats, p, x):
-            return _region_event(topo, kernel, preempt_on, layout, carry,
-                                 stats, p["params"], p["rp"], p["k"], x=x,
-                                 tel=tel, ep=p.get("ep"), work=work,
-                                 wk=p.get("wk"))
-    else:
-        def step(carry, stats, p):
-            return _region_event(topo, kernel, preempt_on, None, carry,
-                                 stats, p["params"], p["rp"], p["k"],
-                                 tel=tel, ep=p.get("ep"), work=work,
-                                 wk=p.get("wk"))
-
-    zeros = _with_zeros(RegionWindowStats.zeros(topo.n_regions), tel,
-                        topo.n_regions, env=ep is not None,
-                        work=work is not None)
-    epilogue = _rebase_for(ep, work)
-    if executor == "ref":
-        _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, slab=slab,
-            epilogue=epilogue)
-    else:
-        _, stats = batched_events(
-            step, state0, params_b, zeros, plan, slab=slab, tile=tile,
-            interpret=interpret, epilogue=epilogue)
-    if burn_in:
-        stats = jax.tree.map(lambda x: x[:, 1:], stats)
-    return _unflatten_lanes(stats, g, s)
-
-
-def _region_sweep_lanes(topo, kernel, preempt_on, n_events, chunk_events,
-                        burn_in, tile, interpret, params_f, rp_f, k_f,
-                        keys_f, *, executor, rng, tel=None, ep=None,
-                        work=None, wk=None):
-    """One shard of flat region lanes through any executor (cf.
-    :func:`_sweep_lanes`; the regions-config tree ``rp_f`` is a per-lane
-    grid axis exactly as in :func:`_run_region_sweep_pallas_jit`)."""
-    layout = (_region_layout(topo, kernel, preempt_on)
-              if rng == "slab" else None)
-    if executor == "xla":
-        def one(p, r, kc, key):
-            state = init_region_state(key, topo, r, preempt_on,
-                                      scalar_preempt=layout is not None,
-                                      ep=ep)
-            if ep is not None:
-                state = (state, init_env_state(ep))
-            if work is not None:
-                state = (state, init_work_state(topo.total_slots))
-            if burn_in:
-                state, _ = run_region_window(topo, kernel, preempt_on, state,
-                                             p, r, kc, burn_in, layout=layout,
-                                             tel=tel, ep=ep, work=work,
-                                             wk=wk)
-                state = _rebase_for(ep, work)(state)
-            _, stats = run_region_chunked(topo, kernel, preempt_on, state, p,
-                                          r, kc, n_events, chunk_events,
-                                          layout=layout, tel=tel, ep=ep,
-                                          work=work, wk=wk)
-            return stats
-
-        return jax.vmap(one)(params_f, rp_f, k_f, keys_f)
-
-    params_b = {"params": params_f, "rp": rp_f, "k": k_f}
-    state0 = jax.vmap(
-        lambda key, r: init_region_state(
-            key, topo, r, preempt_on,
-            scalar_preempt=layout is not None, ep=ep))(keys_f, rp_f)
-    plan = _window_plan(n_events, chunk_events, burn_in)
-    slab = None if layout is None else _lane_slabs(
-        state0, plan, layout, compiled=executor == "pallas" and not interpret)
-    if ep is not None:
-        params_b["ep"], es0 = _env_lane_blocks(ep, keys_f.shape[0])
-        state0 = (state0, es0)
-    if work is not None:
-        params_b["wk"] = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (keys_f.shape[0],)), wk)
-        state0 = (state0, init_work_state(topo.total_slots,
-                                          keys_f.shape[0]))
-
-    if layout is not None:
-        def step(carry, stats, p, x):
-            return _region_event(topo, kernel, preempt_on, layout, carry,
-                                 stats, p["params"], p["rp"], p["k"], x=x,
-                                 tel=tel, ep=p.get("ep"), work=work,
-                                 wk=p.get("wk"))
-    else:
-        def step(carry, stats, p):
-            return _region_event(topo, kernel, preempt_on, None, carry,
-                                 stats, p["params"], p["rp"], p["k"],
-                                 tel=tel, ep=p.get("ep"), work=work,
-                                 wk=p.get("wk"))
-
-    zeros = _with_zeros(RegionWindowStats.zeros(topo.n_regions), tel,
-                        topo.n_regions, env=ep is not None,
-                        work=work is not None)
-    epilogue = _rebase_for(ep, work)
-    if executor == "ref":
-        _, stats = batched_event_windows_ref(
-            step, state0, params_b, zeros, plan, slab=slab, epilogue=epilogue)
-    else:
-        _, stats = batched_events(
-            step, state0, params_b, zeros, plan, slab=slab, tile=tile,
-            interpret=interpret, epilogue=epilogue)
-    if burn_in:
-        stats = jax.tree.map(lambda x: x[:, 1:], stats)
-    return stats
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("topo", "kernel", "preempt_on", "n_events",
-                     "chunk_events", "burn_in", "tile", "interpret", "mesh",
-                     "executor", "rng", "tel", "work"),
-)
-def _run_region_sweep_sharded_jit(topo, kernel, preempt_on, n_events,
-                                  chunk_events, burn_in, tile, interpret,
-                                  mesh, params, rp, k_cost, keys,
-                                  executor="xla", rng="split", tel=None,
-                                  ep=None, work=None, wk=None):
-    """The region fleet lane-partitioned across a 1-D device mesh (cf.
-    :func:`_run_sweep_sharded_jit`)."""
-    g, s = k_cost.shape[0], keys.shape[0]
-    (params_f, rp_f), k_f, keys_f = _flat_lane_args((params, rp), k_cost,
-                                                    keys)
-    lanes = g * s
-    params_f, rp_f, k_f, keys_f = pad_lanes((params_f, rp_f, k_f, keys_f),
-                                            _pad_count(lanes, mesh))
-    spec, rspec = lane_spec(mesh), jax.sharding.PartitionSpec()
-
-    def local(pf, rf, kf, keysf, ep_, wk_):
-        return _region_sweep_lanes(topo, kernel, preempt_on, n_events,
-                                   chunk_events, burn_in, tile, interpret,
-                                   pf, rf, kf, keysf, executor=executor,
-                                   rng=rng, tel=tel, ep=ep_, work=work,
-                                   wk=wk_)
-
-    stats = shard_map_1d(local, mesh=mesh,
-                         in_specs=(spec, spec, spec, spec, rspec, rspec),
-                         out_specs=spec)(params_f, rp_f, k_f, keys_f, ep, wk)
-    if lanes != keys_f.shape[0]:
-        stats = jax.tree.map(lambda x: x[:lanes], stats)
-    return _unflatten_lanes(stats, g, s)
+    def event(self, layout, carry, stats, params, cfg, k_cost, **axes):
+        return _region_event(self.topo, self.kernel, self.preempt_on, layout,
+                             carry, stats, params, cfg, k_cost, **axes)
 
 
 def summarize_region(stats: RegionWindowStats,
@@ -3736,38 +3057,14 @@ def run_region_sim(
     with EntrySpan(f"repro.run_region_sim[{impl}]") as call:
         topology = as_topology(topology)
         params = {} if params is None else params
-        _check_rng(rng)
-        _check_telemetry(telemetry)
-        _check_env(env)
-        _check_work(work, kernel)
-        _check_run_shape("run_region_sim", n_events, burn_in)
-        interp = _resolve_interpret("run_region_sim", impl, rng, interpret)
-        rp = topology.params()
-        ep = _env_params(env, topology.n_regions)
-        wk = None if work is None else work.params()
-        chunk = (n_events if chunk_events is None
-                 else min(chunk_events, n_events))
+        interp, ep, wk, chunk, _ = _prepare(
+            "run_region_sim", kernel, impl, rng, interpret, telemetry, env,
+            work, n_events, burn_in, chunk_events, topology.n_regions)
+        loop = _RegionLoop(topology, kernel, topology.preemptible)
         call.phase("dispatch")
-        if impl in ("pallas", "ref"):
-            stats = _run_region_sweep_pallas_jit(
-                topology, kernel, topology.preemptible, n_events, chunk,
-                burn_in, tile,
-                interp,
-                jax.tree.map(lambda x: jnp.asarray(x)[None], params),
-                jax.tree.map(lambda x: jnp.asarray(x)[None], rp),
-                jnp.float32(k)[None], _raw_keys(key)[None], executor=impl,
-                rng=rng, tel=telemetry, ep=ep, work=work, wk=wk)
-            stats = jax.tree.map(lambda x: x[0, 0], stats)
-        elif impl == "xla":
-            _, stats = _run_region_sim_jit(topology, kernel,
-                                           topology.preemptible, n_events,
-                                           chunk, burn_in, rng, params, rp,
-                                           jnp.float32(k), key,
-                                           tel=telemetry, ep=ep, work=work,
-                                           wk=wk)
-        else:
-            raise ValueError(
-                f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
+        stats = _execute(loop, impl, None, n_events, chunk, burn_in, tile,
+                         interp, rng, telemetry, work, params,
+                         topology.params(), k, key, ep=ep, wk=wk, one=True)
         stats = call.to_host(stats)
         return {name: _scalar_or_array(v)
                 for name, v in summarize_region(stats, telemetry, env=env,
@@ -3835,18 +3132,12 @@ def run_region_sweep(
         topology = as_topology(topology)
         n = topology.n_regions
         params = {} if params is None else params
-        _check_rng(rng)
-        _check_telemetry(telemetry)
-        _check_env(env)
-        _check_work(work, kernel)
-        _check_shard("run_region_sweep", shard, mesh)
-        _check_run_shape("run_region_sweep", n_events, burn_in)
-        interp = _resolve_interpret("run_region_sweep", impl, rng, interpret)
+        interp, ep, wk, chunk, mesh = _prepare(
+            "run_region_sweep", kernel, impl, rng, interpret, telemetry, env,
+            work, n_events, burn_in, chunk_events, n, shard, mesh)
         _check_loc_overrides("run_region_sweep", n, "region", prices=prices,
                              hazards=hazards, notices=notices,
                              spot_scales=spot_scales, job_scales=job_scales)
-        ep = _env_params(env, n)
-        wk = None if work is None else work.params()
         params = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
         vparams = {} if vector_params is None else jax.tree.map(
             lambda x: jnp.asarray(x, jnp.float32), dict(vector_params))
@@ -3872,36 +3163,13 @@ def run_region_sweep(
         k_flat = flat(k)
         rp_flat = _broadcast_config_params(n, topology.params(), overrides,
                                            grid_shape)
-        preempt_on = topology.preemptible or hazards is not None
+        loop = _RegionLoop(topology, kernel,
+                           topology.preemptible or hazards is not None)
         keys = jax.random.split(key, n_seeds)
-        chunk = (n_events if chunk_events is None
-                 else min(chunk_events, n_events))
         call.phase("dispatch")
-        if shard == "lanes":
-            if impl not in ("xla", "pallas", "ref"):
-                raise ValueError(
-                    f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
-            stats = _run_region_sweep_sharded_jit(
-                topology, kernel, preempt_on, n_events, chunk, burn_in, tile,
-                interp,
-                lane_mesh() if mesh is None else mesh, params_flat, rp_flat,
-                k_flat, _raw_keys(keys), executor=impl, rng=rng,
-                tel=telemetry, ep=ep, work=work, wk=wk)
-        elif impl in ("pallas", "ref"):
-            stats = _run_region_sweep_pallas_jit(
-                topology, kernel, preempt_on, n_events, chunk, burn_in, tile,
-                interp,
-                params_flat, rp_flat, k_flat, _raw_keys(keys), executor=impl,
-                rng=rng, tel=telemetry, ep=ep, work=work, wk=wk)
-        elif impl == "xla":
-            stats = _run_region_sweep_jit(topology, kernel, preempt_on,
-                                          n_events, chunk, burn_in, rng,
-                                          params_flat, rp_flat, k_flat, keys,
-                                          tel=telemetry, ep=ep, work=work,
-                                          wk=wk)
-        else:
-            raise ValueError(
-                f"unknown impl {impl!r} (expected 'xla'|'pallas'|'ref')")
+        stats = _execute(loop, impl, mesh, n_events, chunk, burn_in, tile,
+                         interp, rng, telemetry, work, params_flat, rp_flat,
+                         k_flat, keys, ep=ep, wk=wk)
         stats = call.to_host(stats)
         out = summarize_region(stats, telemetry, env=env, work=work)
         return _reshape_sweep(out, grid_shape, n_seeds)

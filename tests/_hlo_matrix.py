@@ -89,13 +89,20 @@ def _topo() -> RegionTopology:
 
 
 def lowering_digests() -> dict:
-    """sha256 of the lowered text for every (loop × executor × rng) cell."""
-    job, spot = Exponential(1.2), Exponential(0.9)
-    kern = ThreePhaseKernel()
+    """sha256 of the lowered text for every cell of the matrix."""
     mkern = NoticeAwareKernel(checkpoint_time=0.05)
-    rkern = RoutingKernel(base=mkern, choice="cheapest")
     market, topo = _market(), _topo()
     mp, rp = market.params(), topo.params()
+    # (name prefix, loop, its config tree at one point, across 3 points)
+    loops = (
+        ("", engine._QueueLoop(Exponential(1.2), Exponential(0.9),
+                               ThreePhaseKernel(), 4), None, None),
+        ("market_", engine._MarketLoop(Exponential(1.2), market, mkern, 4,
+                                       True), mp, _points(mp)),
+        ("region_", engine._RegionLoop(
+            topo, RoutingKernel(base=mkern, choice="cheapest"), True), rp,
+         _points(rp)),
+    )
     params = {"r": jnp.float32(2.0)}
     k = jnp.float32(12.0)
     key = jax.random.key(0)
@@ -103,74 +110,40 @@ def lowering_digests() -> dict:
     rkeys = jax.random.key_data(keys)
     pflat = {"r": jnp.full((3,), 2.0, jnp.float32)}
     kflat = jnp.full((3,), 12.0, jnp.float32)
-    mp_f = jax.tree.map(lambda a: jnp.broadcast_to(a, (3,) + a.shape), mp)
-    rp_f = jax.tree.map(lambda a: jnp.broadcast_to(a, (3,) + a.shape), rp)
+    mesh = lane_mesh()
 
     out = {}
-    for rng in ("split", "slab"):
-        out[f"sim/{rng}"] = _digest(engine._run_sim_jit.lower(
-            job, spot, kern, 4, _N_EVENTS, _CHUNK, 0, rng, params, k, key))
-        out[f"sweep/{rng}"] = _digest(engine._run_sweep_jit.lower(
-            job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, rng, pflat, kflat,
-            keys))
-        out[f"market_sim/{rng}"] = _digest(engine._run_market_sim_jit.lower(
-            job, market, mkern, 4, True, _N_EVENTS, _CHUNK, 0, rng, params,
-            mp, k, key))
-        out[f"market_sweep/{rng}"] = _digest(
-            engine._run_market_sweep_jit.lower(
-                job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN, rng,
-                pflat, mp_f, kflat, keys))
-        out[f"region_sim/{rng}"] = _digest(engine._run_region_sim_jit.lower(
-            topo, rkern, True, _N_EVENTS, _CHUNK, 0, rng, params, rp, k, key))
-        out[f"region_sweep/{rng}"] = _digest(
-            engine._run_region_sweep_jit.lower(
-                topo, rkern, True, _N_EVENTS, _CHUNK, _BURN, rng, pflat,
-                rp_f, kflat, keys))
-        for ex in ("pallas", "ref"):
-            out[f"sweep_{ex}/{rng}"] = _digest(
-                engine._run_sweep_pallas_jit.lower(
-                    job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, 2, True,
-                    pflat, kflat, rkeys, executor=ex, rng=rng))
-            out[f"market_sweep_{ex}/{rng}"] = _digest(
-                engine._run_market_sweep_pallas_jit.lower(
-                    job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN, 2,
-                    True, pflat, mp_f, kflat, rkeys, executor=ex, rng=rng))
-            out[f"region_sweep_{ex}/{rng}"] = _digest(
-                engine._run_region_sweep_pallas_jit.lower(
-                    topo, rkern, True, _N_EVENTS, _CHUNK, _BURN, 2, True,
-                    pflat, rp_f, kflat, rkeys, executor=ex, rng=rng))
-    out.update(_sharded_digests(job, spot, kern, market, mkern, topo, rkern,
-                                pflat, kflat, rkeys, mp_f, rp_f))
-    out.update(_front_digests(job, market, mkern))
-    out.update(_axes_digests(job, spot, kern, market, mkern, topo, rkern,
-                             params, mp, rp, k, key, pflat, kflat, keys,
-                             rkeys, mp_f, rp_f))
+    for name, loop, cfg, cfg_f in loops:
+        lanes = (pflat, cfg_f, kflat, rkeys)
+        for rng in ("split", "slab"):
+            out[f"{name}sim/{rng}"] = _digest(engine._run_sim_jit.lower(
+                loop, _N_EVENTS, _CHUNK, 0, rng, params, cfg, k, key))
+            out[f"{name}sweep/{rng}"] = _digest(engine._run_sweep_jit.lower(
+                loop, _N_EVENTS, _CHUNK, _BURN, rng, pflat, cfg_f, kflat,
+                keys))
+            for ex in ("pallas", "ref"):
+                out[f"{name}sweep_{ex}/{rng}"] = _digest(
+                    engine._run_lanes_jit.lower(
+                        loop, _N_EVENTS, _CHUNK, _BURN, 2, True, None,
+                        *lanes, executor=ex, rng=rng))
+            # the shard="lanes" programs over a one-device mesh
+            for ex in ("xla", "pallas"):
+                out[f"{name}sweep_sharded_{ex}/{rng}"] = _digest(
+                    engine._run_lanes_jit.lower(
+                        loop, _N_EVENTS, _CHUNK, _BURN, 2, True, mesh,
+                        *lanes, executor=ex, rng=rng))
+        out.update(_axes_digests(name, loop, cfg, params, k, key, keys,
+                                 lanes, mesh))
+    out.update(_front_digests(loops[1][1]))
     return out
 
 
-def _sharded_digests(job, spot, kern, market, mkern, topo, rkern, pflat,
-                     kflat, rkeys, mp_f, rp_f) -> dict:
-    """The three ``shard="lanes"`` programs over a one-device mesh."""
-    mesh, out = lane_mesh(), {}
-    for rng in ("split", "slab"):
-        for ex in ("xla", "pallas"):
-            kw = dict(executor=ex, rng=rng)
-            out[f"sweep_sharded_{ex}/{rng}"] = _digest(
-                engine._run_sweep_sharded_jit.lower(
-                    job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, 2, True,
-                    mesh, pflat, kflat, rkeys, **kw))
-            out[f"market_sweep_sharded_{ex}/{rng}"] = _digest(
-                engine._run_market_sweep_sharded_jit.lower(
-                    job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN,
-                    2, True, mesh, pflat, mp_f, kflat, rkeys, **kw))
-            out[f"region_sweep_sharded_{ex}/{rng}"] = _digest(
-                engine._run_region_sweep_sharded_jit.lower(
-                    topo, rkern, True, _N_EVENTS, _CHUNK, _BURN, 2, True,
-                    mesh, pflat, rp_f, kflat, rkeys, **kw))
-    return out
+def _points(cfg: dict) -> dict:
+    """A config tree at 3 grid points."""
+    return jax.tree.map(lambda a: jnp.broadcast_to(a, (3,) + a.shape), cfg)
 
 
-def _front_digests(job, market, mkern) -> dict:
+def _front_digests(loop) -> dict:
     """``run_market_sweep``'s one program (prep and executor), at the
     arguments the entry point hands it."""
     params = {"r": np.linspace(0.5, 2.0, 3).astype(np.float32)}
@@ -183,59 +156,39 @@ def _front_digests(job, market, mkern) -> dict:
             name = impl if mesh is None else f"sharded_{impl}"
             out[f"market_call_{name}/{rng}"] = _digest(
                 engine._market_sweep_call.lower(
-                    job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN, 2,
-                    True, mesh, impl, rng, None, None, (3,), 2, params,
-                    market.host_params(), 12.0, overrides, key))
+                    loop, _N_EVENTS, _CHUNK, _BURN, 2, True, mesh, impl, rng,
+                    None, None, (3,), 2, params, loop.market.host_params(),
+                    12.0, overrides, key))
     return out
 
 
-def _axes_digests(job, spot, kern, market, mkern, topo, rkern, params, mp,
-                  rp, k, key, pflat, kflat, keys, rkeys, mp_f, rp_f) -> dict:
-    """Every loop's sim, sweep and lane executors with telemetry, a
-    storm timeline and a work model on, on the slab stream."""
-    tel = Telemetry()
+def _axes_digests(name, loop, cfg, params, k, key, keys, lanes,
+                  mesh) -> dict:
+    """A loop's sim, sweep and lane executors with telemetry, a storm
+    timeline and a work model on, on the slab stream."""
     env = inject_storm(EnvTimeline.constant(), 50.0, 150.0)
     work = WorkModel(total_work=2.0, restart_overhead=0.25, ckpt="notice",
                      ckpt_time=0.05)
-    wk, mesh = work.params(), lane_mesh()
-    ax = lambda n: dict(tel=tel, ep=env.params(n), work=work, wk=wk)
-    n_m, n_r = market.n_pools, topo.n_regions
-    lanes = dict(executor="pallas", rng="slab")
-    return {
-        "sim+axes/slab": _digest(engine._run_sim_jit.lower(
-            job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, "slab", params, k,
-            key, **ax(1))),
-        "sweep+axes/slab": _digest(engine._run_sweep_jit.lower(
-            job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, "slab", pflat,
-            kflat, keys, **ax(1))),
-        "sweep_pallas+axes/slab": _digest(engine._run_sweep_pallas_jit.lower(
-            job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, 2, True, pflat,
-            kflat, rkeys, **lanes, **ax(1))),
-        "sweep_sharded_xla+axes/slab": _digest(
-            engine._run_sweep_sharded_jit.lower(
-                job, spot, kern, 4, _N_EVENTS, _CHUNK, _BURN, 2, True, mesh,
-                pflat, kflat, rkeys, executor="xla", rng="slab", **ax(1))),
-        "market_sim+axes/slab": _digest(engine._run_market_sim_jit.lower(
-            job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN, "slab",
-            params, mp, k, key, **ax(n_m))),
-        "market_sweep+axes/slab": _digest(engine._run_market_sweep_jit.lower(
-            job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN, "slab",
-            pflat, mp_f, kflat, keys, **ax(n_m))),
-        "market_sweep_pallas+axes/slab": _digest(
-            engine._run_market_sweep_pallas_jit.lower(
-                job, market, mkern, 4, True, _N_EVENTS, _CHUNK, _BURN, 2,
-                True, pflat, mp_f, kflat, rkeys, **lanes, **ax(n_m))),
-        "region_sim+axes/slab": _digest(engine._run_region_sim_jit.lower(
-            topo, rkern, True, _N_EVENTS, _CHUNK, _BURN, "slab", params, rp,
-            k, key, **ax(n_r))),
-        "region_sweep+axes/slab": _digest(engine._run_region_sweep_jit.lower(
-            topo, rkern, True, _N_EVENTS, _CHUNK, _BURN, "slab", pflat, rp_f,
-            kflat, keys, **ax(n_r))),
-        "region_sweep_pallas+axes/slab": _digest(
-            engine._run_region_sweep_pallas_jit.lower(
-                topo, rkern, True, _N_EVENTS, _CHUNK, _BURN, 2, True, pflat,
-                rp_f, kflat, rkeys, **lanes, **ax(n_r))),
+    ax = dict(tel=Telemetry(), ep=env.params(loop.n_locs), work=work,
+              wk=work.params())
+    pflat, cfg_f, kflat, _ = lanes
+    out = {
+        f"{name}sim+axes/slab": _digest(engine._run_sim_jit.lower(
+            loop, _N_EVENTS, _CHUNK, _BURN, "slab", params, cfg, k, key,
+            **ax)),
+        f"{name}sweep+axes/slab": _digest(engine._run_sweep_jit.lower(
+            loop, _N_EVENTS, _CHUNK, _BURN, "slab", pflat, cfg_f, kflat,
+            keys, **ax)),
+        f"{name}sweep_pallas+axes/slab": _digest(engine._run_lanes_jit.lower(
+            loop, _N_EVENTS, _CHUNK, _BURN, 2, True, None, *lanes,
+            executor="pallas", rng="slab", **ax)),
     }
+    if not name:
+        out["sweep_sharded_xla+axes/slab"] = _digest(
+            engine._run_lanes_jit.lower(
+                loop, _N_EVENTS, _CHUNK, _BURN, 2, True, mesh, *lanes,
+                executor="xla", rng="slab", **ax))
+    return out
 
 
 def environment_tag() -> dict:

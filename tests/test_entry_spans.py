@@ -159,12 +159,15 @@ def test_lowered_executors_carry_the_kernel_and_glue_names():
     keys = jax.random.key_data(jax.random.split(jax.random.key(0), 2))
     kw = dict(executor="pallas", rng="slab")
     lowered = {
-        "single": E._run_sweep_pallas_jit.lower(
-            Exponential(LAM), Exponential(MU), ThreePhaseKernel(), 8, 300,
-            100, 50, 4, True, RS, k, keys, **kw),
-        "market": E._run_market_sweep_pallas_jit.lower(
-            Exponential(LAM), MARKET, NoticeAwareKernel(checkpoint_time=0.05),
-            8, MARKET.preemptible, 300, 100, 50, 4, True, RS,
+        "single": E._run_lanes_jit.lower(
+            E._QueueLoop(Exponential(LAM), Exponential(MU),
+                         ThreePhaseKernel(), 8),
+            300, 100, 50, 4, True, None, RS, None, k, keys, **kw),
+        "market": E._run_lanes_jit.lower(
+            E._MarketLoop(Exponential(LAM), MARKET,
+                          NoticeAwareKernel(checkpoint_time=0.05), 8,
+                          MARKET.preemptible),
+            300, 100, 50, 4, True, None, RS,
             jax.tree.map(lambda a: jnp.broadcast_to(a, (3,) + np.shape(a)),
                          MARKET.params()), k, keys, **kw),
     }

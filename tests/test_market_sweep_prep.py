@@ -57,16 +57,15 @@ def _eager_sweep(params, *, k, n_events, key, n_seeds, impl, rng,
     keys = jax.random.split(key, n_seeds)
     chunk = min(chunk_events, n_events)
     interp = E._resolve_interpret("eager", impl, rng, interpret)
+    loop = E._MarketLoop(Exponential(LAM), MARKET, KERNEL, rmax, preempt_on)
     if impl == "xla":
-        stats = E._run_market_sweep_jit(
-            Exponential(LAM), MARKET, KERNEL, rmax, preempt_on, n_events,
-            chunk, 0, rng, params_flat, mp_flat, k_flat, keys,
-            tel=telemetry)
+        stats = E._run_sweep_jit(loop, n_events, chunk, 0, rng, params_flat,
+                                 mp_flat, k_flat, keys, tel=telemetry)
     else:
-        stats = E._run_market_sweep_pallas_jit(
-            Exponential(LAM), MARKET, KERNEL, rmax, preempt_on, n_events,
-            chunk, 0, tile, interp, params_flat, mp_flat, k_flat,
-            E._raw_keys(keys), executor=impl, rng=rng, tel=telemetry)
+        stats = E._run_lanes_jit(loop, n_events, chunk, 0, tile, interp,
+                                 None, params_flat, mp_flat, k_flat,
+                                 E._raw_keys(keys), executor=impl, rng=rng,
+                                 tel=telemetry)
     out = E.summarize_market(jax.device_get(stats), telemetry)
     return E._reshape_sweep(out, grid_shape, n_seeds)
 

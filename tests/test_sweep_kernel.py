@@ -250,7 +250,7 @@ def test_order_rebase_prevents_int32_wrap():
     the per-window rebase the counter wraps negative within a few chunks
     and the FIFO argmin serves newest-first; with it the shifted run is
     bitwise the run that started at zero."""
-    from repro.core.engine import (WindowStats, _rebase_order,
+    from repro.core.engine import (WindowStats, _QueueLoop, _rebase_order,
                                    init_engine_state, run_chunked)
 
     job, spot, kernel = Exponential(1.0), Exponential(1.0), ThreePhaseKernel()
@@ -263,8 +263,8 @@ def test_order_rebase_prevents_int32_wrap():
         state = state._replace(
             order=state.order + offset * state.occ.astype(jnp.int32),
             next_seq=state.next_seq + offset)
-        return run_chunked(job, spot, kernel, rmax, state, params,
-                           jnp.float32(10.0), n_events, chunk)
+        return run_chunked(_QueueLoop(job, spot, kernel, rmax), state,
+                           params, None, jnp.float32(10.0), n_events, chunk)
 
     offset = jnp.int32(2**31 - 10_000)  # wraps within ~chunks without rebase
     s_hi, stats_hi = run_from(offset)

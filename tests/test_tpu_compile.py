@@ -103,29 +103,27 @@ def _per_point(tree, sharding, g=G):
 def _pallas_lowering(loop, sh):
     r, k = {"r": _spec(sh, (G,))}, _spec(sh, (G,))
     keys = _spec(sh, (S, 2), jnp.uint32)
-    kw = dict(executor="pallas", rng="slab")
     if loop == "single":
-        return E._run_sweep_pallas_jit.lower(
-            Exponential(LAM), Exponential(MU), ThreePhaseKernel(), RMAX,
-            N_EVENTS, CHUNK, BURN_IN, TILE, False, r, k, keys, **kw)
-    if loop in ("market", "one_pool_market"):
+        desc, cfg = E._QueueLoop(Exponential(LAM), Exponential(MU),
+                                 ThreePhaseKernel(), RMAX), None
+    elif loop in ("market", "one_pool_market"):
         market = MARKET if loop == "market" else ONE_POOL
-        return E._run_market_sweep_pallas_jit.lower(
-            Exponential(LAM), market,
-            NoticeAwareKernel(checkpoint_time=0.05), RMAX,
-            market.preemptible, N_EVENTS, CHUNK, BURN_IN, TILE, False,
-            r, _per_point(market.params(), sh), k, keys, **kw)
-    if loop == "region_preempt":
+        desc = E._MarketLoop(Exponential(LAM), market,
+                             NoticeAwareKernel(checkpoint_time=0.05), RMAX,
+                             market.preemptible)
+        cfg = _per_point(market.params(), sh)
+    elif loop == "region_preempt":
         kernel = RoutingKernel(NoticeAwareKernel(checkpoint_time=0.025),
                                choice="least_loaded")
-        return E._run_region_sweep_pallas_jit.lower(
-            PREEMPT_TOPOLOGY, kernel, True, N_EVENTS, CHUNK, BURN_IN,
-            TILE, False, r, _per_point(PREEMPT_TOPOLOGY.params(), sh), k,
-            keys, **kw)
-    return E._run_region_sweep_pallas_jit.lower(
-        TOPOLOGY, RoutingKernel(ThreePhaseKernel(), choice="least_loaded"),
-        TOPOLOGY.preemptible, N_EVENTS, CHUNK, BURN_IN, TILE, False, r,
-        _per_point(TOPOLOGY.params(), sh), k, keys, **kw)
+        desc = E._RegionLoop(PREEMPT_TOPOLOGY, kernel, True)
+        cfg = _per_point(PREEMPT_TOPOLOGY.params(), sh)
+    else:
+        kernel = RoutingKernel(ThreePhaseKernel(), choice="least_loaded")
+        desc = E._RegionLoop(TOPOLOGY, kernel, TOPOLOGY.preemptible)
+        cfg = _per_point(TOPOLOGY.params(), sh)
+    return E._run_lanes_jit.lower(desc, N_EVENTS, CHUNK, BURN_IN, TILE, False,
+                                  None, r, cfg, k, keys, executor="pallas",
+                                  rng="slab")
 
 
 @pytest.mark.parametrize("loop", ["single", "market", "one_pool_market",
@@ -143,9 +141,10 @@ def test_pallas_executor_compiles_to_mosaic(loop, one_chip,
 def test_xla_executor_compiles(one_chip, no_compile_cache):
     keys = _spec(one_chip, (S,), jax.random.key(0).dtype)
     compiled = E._run_sweep_jit.lower(
-        Exponential(LAM), Exponential(MU), ThreePhaseKernel(), RMAX,
+        E._QueueLoop(Exponential(LAM), Exponential(MU), ThreePhaseKernel(),
+                     RMAX),
         N_EVENTS, CHUNK, BURN_IN, "slab", {"r": _spec(one_chip, (G,))},
-        _spec(one_chip, (G,)), keys).compile()
+        None, _spec(one_chip, (G,)), keys).compile()
     assert "tpu_custom_call" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
@@ -153,10 +152,11 @@ def test_xla_executor_compiles(one_chip, no_compile_cache):
 def test_sharded_pallas_compiles_for_four_chips(topo, no_compile_cache):
     mesh = Mesh(np.array(topo.devices), ("lanes",))
     rep = NamedSharding(mesh, PartitionSpec())
-    compiled = E._run_sweep_sharded_jit.lower(
-        Exponential(LAM), Exponential(MU), ThreePhaseKernel(), RMAX,
+    compiled = E._run_lanes_jit.lower(
+        E._QueueLoop(Exponential(LAM), Exponential(MU), ThreePhaseKernel(),
+                     RMAX),
         1 << 18, CHUNK, BURN_IN, TILE, False, mesh,
-        {"r": _spec(rep, (4 * G,))}, _spec(rep, (4 * G,)),
+        {"r": _spec(rep, (4 * G,))}, None, _spec(rep, (4 * G,)),
         _spec(rep, (S, 2), jnp.uint32), executor="pallas",
         rng="slab").compile()
     assert "tpu_custom_call" in compiled.as_text()
